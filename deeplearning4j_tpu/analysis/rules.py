@@ -672,21 +672,24 @@ class BareExceptRule(Rule):
 
 @register
 class PallasInterpretRule(Rule):
-    """PL01 — ``pallas_call`` without an ``interpret`` fallback.
+    """PL01 — ``pallas_call`` without an ``interpret`` keyword.
 
     The kernel tier's contract (DESIGN.md §14) is that every Pallas
     kernel runs its REAL body in tier-1 CPU tests via interpret mode —
     a ``pl.pallas_call`` with no ``interpret=`` keyword can only ever
     execute on a TPU, so its kernel body is dead code to the test suite
     and every bug in it ships untested.  Wrappers must thread an
-    ``interpret`` flag (auto-selected off-TPU) down to the call.
+    ``interpret`` flag down to the call and resolve ``None`` through
+    ``ops.pallas.registry.resolve_interpret``: compiled on a TPU backend,
+    interpreted on the CPU backend, an error on any other — never
+    interpreted on the chip path unless the caller asked for it.
 
     Blind spot: a call aliased through a variable
     (``f = pl.pallas_call; f(...)``) is not seen; none exist in-tree.
     """
 
     id = "PL01"
-    title = "pallas_call without interpret fallback"
+    title = "pallas_call without interpret keyword"
 
     def check(self, module: ModuleInfo) -> Iterator[Finding]:
         for node in ast.walk(module.tree):
@@ -701,8 +704,8 @@ class PallasInterpretRule(Rule):
                 module, node,
                 "`pallas_call` without an `interpret=` keyword compiles "
                 "only on TPU — CPU tier-1 tests can never execute the "
-                "kernel body; thread an interpret flag (auto-selected "
-                "off-TPU) through the wrapper")
+                "kernel body; thread an interpret flag through the "
+                "wrapper, resolved by registry.resolve_interpret")
 
 
 #: identifier fragments naming an optimizer-state tree

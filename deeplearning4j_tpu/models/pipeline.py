@@ -35,20 +35,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax spells the flag check_rep
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _sm_old
-
-    @wraps(_sm_old)
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_rep=check_vma)
 
 from ..parallel.mesh import DP, PP, SP, TP
 from .transformer import (

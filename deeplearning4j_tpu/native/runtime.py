@@ -2,19 +2,18 @@
 
 ``lib()`` returns the loaded shared library or None; call sites check and
 fall back to pure Python.  The library is built on demand at most once per
-process (cheap g++ compile, cached on disk).
+process (cheap g++ compile, cached on disk under a name that carries the
+hash of its source — see ``build.lib_path``).
 """
 
 from __future__ import annotations
 
 import ctypes
-from pathlib import Path
 
 import numpy as np
 
 _LIB: ctypes.CDLL | None = None
 _TRIED = False
-_LIB_PATH = Path(__file__).parent / "libdl4jtpu_host.so"
 
 
 def lib() -> ctypes.CDLL | None:
@@ -22,15 +21,12 @@ def lib() -> ctypes.CDLL | None:
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    src = Path(__file__).parent / "src" / "host_runtime.cpp"
-    stale = (_LIB_PATH.exists() and src.exists()
-             and src.stat().st_mtime > _LIB_PATH.stat().st_mtime)
-    if not _LIB_PATH.exists() or stale:
-        from .build import build
-        if build(verbose=False) is None and not _LIB_PATH.exists():
-            return None
+    from .build import build, lib_path
+    path = lib_path()
+    if not path.exists() and build(verbose=False) is None:
+        return None
     try:
-        l = ctypes.CDLL(str(_LIB_PATH))
+        l = ctypes.CDLL(str(path))
     except OSError:
         return None
     l.drt_count_tokens.restype = ctypes.c_void_p

@@ -80,7 +80,7 @@ class MultiLayerNetwork:
         # runs ahead of the device if nothing forces a device->host read.
         # The final iteration always syncs, so ``score()`` stays correct.
         self.score_every = max(1, int(score_every))
-        setup_compile_cache()  # persistent XLA cache (env-gated no-op)
+        setup_compile_cache()  # persistent XLA cache (compile_cache.py)
 
     # ------------------------------------------------------------------ init
     def init(self, key=None) -> Params:

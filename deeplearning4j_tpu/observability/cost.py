@@ -11,10 +11,12 @@ result publishes live utilization gauges:
     ``{prefix}.mbu``  = bytes_accessed / (seconds * peak_bytes_per_s)
 
 the same accounting bench.py reports, so artifact and ``/metrics.prom``
-agree.  Caveats (see DESIGN.md §18): some backends return no
-``cost_analysis`` or report ``flops <= 0`` ("unknown"); ``capture`` then
-falls back to a caller-supplied analytic FLOPs estimate, or returns None —
-callers must treat None as "no utilization numbers", never an error.
+agree.  Peaks come from :data:`PEAKS`, the repo's one table, keyed by the
+exact ``device_kind``; an unknown kind publishes no utilization gauge.
+Caveats (see DESIGN.md §18): some backends return no ``cost_analysis`` or
+report ``flops <= 0`` ("unknown"); ``capture`` then falls back to a
+caller-supplied analytic FLOPs estimate, or returns None — callers must
+treat None as "no utilization numbers", never an error.
 
 Capturing is safe before a donating call: ``fn.lower(*args)`` reads only
 shapes/dtypes and does not consume donated buffers.
@@ -30,38 +32,31 @@ from typing import Any
 from . import core
 from .metrics import METRICS
 
-# Nominal peak numbers keyed by substring of ``device_kind.lower()``.
-# The TPU rows mirror bench.py's PEAK_FLOPS table (v5e bf16); the CPU rows
-# are nominal single-socket figures so CPU test runs produce finite, small
-# MFU values rather than NaN.
-PEAK_FLOPS: dict[str, float] = {
-    "tpu v5 lite": 197e12,
-    "tpu v5": 197e12,
-    "tpu": 197e12,
-    "cpu": 5e10,
+
+@dataclass(frozen=True)
+class DevicePeak:
+    """Published per-chip peaks of one accelerator."""
+    flops: float          # dense bf16 FLOP/s
+    bytes_per_s: float    # HBM bandwidth
+    hbm_bytes: float      # HBM capacity
+    source: str
+
+
+#: The repo's ONE peak table, keyed by the exact ``device_kind`` JAX
+#: reports (``jax.devices()[0].device_kind``).  A kind that is not here has
+#: no utilization: the library publishes no ``*.mfu``/``*.mbu`` gauge for
+#: it, and ``bench.py``/``chip_smoke.py`` treat it as an error.
+PEAKS: dict[str, DevicePeak] = {
+    "TPU v5 lite": DevicePeak(
+        flops=197e12, bytes_per_s=819e9, hbm_bytes=16e9,
+        source='Google Cloud documentation, "TPU v5e"'),
 }
-PEAK_BYTES_PER_S: dict[str, float] = {
-    "tpu v5 lite": 819e9,   # v5e HBM bandwidth
-    "tpu v5": 819e9,
-    "tpu": 819e9,
-    "cpu": 2e10,
-}
 
 
-def _lookup(table: dict[str, float], kind: str) -> float | None:
-    kind = kind.lower()
-    for key in sorted(table, key=len, reverse=True):
-        if key in kind:
-            return table[key]
-    return None
-
-
-def _device_kind() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+def device_peak() -> DevicePeak | None:
+    """Peak row of the default device, or None for an unknown kind."""
+    import jax
+    return PEAKS.get(jax.devices()[0].device_kind)
 
 
 @dataclass(frozen=True)
@@ -166,34 +161,29 @@ class CostModel:
         with self._lock:
             return self._by_key.get(key)
 
-    # ------------------------------------------------------------- peaks
-    def peak_flops(self) -> float | None:
-        return _lookup(PEAK_FLOPS, _device_kind())
-
-    def peak_bytes_per_s(self) -> float | None:
-        return _lookup(PEAK_BYTES_PER_S, _device_kind())
-
     # ------------------------------------------------------------- publish
     def publish_utilization(self, info: CostInfo | None, seconds: float,
                             mfu_gauge: str, mbu_gauge: str | None = None,
                             registry=None) -> float | None:
         """Gauge ``mfu_gauge`` (and ``mbu_gauge`` when bytes are known)
         from one execution's cost and measured wall seconds.  Returns the
-        MFU value, or None when nothing could be published."""
+        MFU value, or None when nothing could be published — which
+        includes every device whose kind is not in :data:`PEAKS`."""
         if info is None or not (seconds > 0) or not core.enabled():
+            return None
+        peak = device_peak()
+        if peak is None:
             return None
         reg = registry if registry is not None else METRICS
         mfu = None
-        peak_f = self.peak_flops()
-        if peak_f and info.flops > 0:
-            mfu = info.flops / (seconds * peak_f)
+        if info.flops > 0:
+            mfu = info.flops / (seconds * peak.flops)
             if math.isfinite(mfu):
                 reg.gauge(mfu_gauge, mfu)
             else:
                 mfu = None
-        peak_b = self.peak_bytes_per_s()
-        if mbu_gauge and peak_b and info.bytes_accessed > 0:
-            mbu = info.bytes_accessed / (seconds * peak_b)
+        if mbu_gauge and info.bytes_accessed > 0:
+            mbu = info.bytes_accessed / (seconds * peak.bytes_per_s)
             if math.isfinite(mbu):
                 reg.gauge(mbu_gauge, mbu)
         return mfu

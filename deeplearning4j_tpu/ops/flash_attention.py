@@ -18,8 +18,10 @@ Design (standard flash attention v2 schedule):
   ``lax.scan`` over key blocks — O(T) memory in the backward too, no
   hand-written backward kernel to maintain.
 
-The op runs in Pallas interpret mode automatically on CPU (tests), and as
-a compiled Mosaic kernel on TPU.  It is OPT-IN via
+``interpret=None`` follows the tier's one rule
+(``ops.pallas.registry.resolve_interpret``): compiled Mosaic kernel on a TPU
+backend, interpret mode on the CPU backend (tests), an error anywhere else.
+It is OPT-IN via
 ``TransformerConfig(attention="flash")`` until a real-chip benchmark
 validates it end-to-end.
 """
@@ -32,13 +34,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces are unavailable on CPU-only jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+#: every kernel of this tier stages its blocks through VMEM
+vmem_spec = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
 _NEG_INF = -1e30
 
@@ -89,21 +88,20 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
 
     kernel = functools.partial(_fwd_kernel, causal=causal, block_k=block_k,
                                seq_len=t, scale=scale)
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, t // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0), **mem),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0), **mem),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0), **mem),
+            vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
+            vmem_spec((1, t, d), lambda b, i: (b, 0, 0)),
+            vmem_spec((1, t, d), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0), **mem),
+            vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
             # lse carries a trailing singleton: Mosaic requires the last two
             # block dims divisible by (8, 128) or equal to the array dims, so
             # a (1, block_q) block is unlowerable while (1, block_q, 1) is.
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0), **mem),
+            vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
@@ -176,11 +174,10 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, interpret: bool | None = None):
     """Fused attention for (B, T, H, D) tensors (the transformer's layout).
 
-    ``interpret=None`` auto-selects Pallas interpret mode off-TPU so the
-    same call works in CPU tests and compiles to Mosaic on the chip.
+    ``interpret=None`` resolves through ``registry.resolve_interpret``:
+    compiled on a TPU backend, interpreted on the CPU backend (tests).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _kernel_registry.resolve_interpret(interpret)
     b, t, h, d = q.shape
 
     def to_bhtd(x):

@@ -6,8 +6,11 @@ no kernel is ever adopted on faith:
 
 - every kernel lives here as a *registered candidate* (``registry.py``)
   next to a pure-jnp reference implementation;
-- every kernel threads an ``interpret`` flag (auto-selected off-TPU) so
-  tier-1 CPU tests execute the real kernel body, not a stand-in;
+- every kernel threads an ``interpret`` flag through
+  ``registry.resolve_interpret`` (explicit value obeyed; ``None`` compiles
+  on a TPU backend, interprets on the CPU backend, raises elsewhere) so
+  tier-1 CPU tests execute the real kernel body, not a stand-in, and a
+  process on the chip never runs a kernel interpreted by accident;
 - production adoption happens only through ``registry.autopick`` fed by
   TUNE battery rows: a correctness gate at documented tolerances plus a
   >2% throughput margin over the incumbent, with every dropped candidate
@@ -28,7 +31,7 @@ from .registry import (  # noqa: F401
     autopick,
     candidates,
     get,
-    import_errors,
     kinds,
     register,
+    resolve_interpret,
 )

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from ..flash_attention import _blockwise_bwd, _VMEM
+from ..flash_attention import _blockwise_bwd, vmem_spec
 
 from . import registry
 
@@ -98,20 +98,19 @@ def _fused_fwd(q, k, v, causal, block_q, block_k, interpret):
 
     kernel = functools.partial(_fwd_kernel, causal=causal, block_k=block_k,
                                seq_len=t, scale=scale)
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, t // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0), **mem),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0), **mem),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0), **mem),
+            vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
+            vmem_spec((1, t, d), lambda b, i: (b, 0, 0)),
+            vmem_spec((1, t, d), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0), **mem),
+            vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
             # trailing singleton: same Mosaic last-two-dims constraint as
             # the flash kernel's lse output
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0), **mem),
+            vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
@@ -146,10 +145,9 @@ def fused_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
     """Block-skipping fused attention for (B, T, H, D) tensors.
 
     Public API mirrors :func:`ops.flash_attention.flash_attention`;
-    ``interpret=None`` auto-selects Pallas interpret mode off-TPU.
+    ``interpret=None`` resolves through ``registry.resolve_interpret``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = registry.resolve_interpret(interpret)
     b, t, h, d = q.shape
 
     def to_bhtd(x):

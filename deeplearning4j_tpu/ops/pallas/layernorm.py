@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from ..flash_attention import _VMEM
+from ..flash_attention import vmem_spec
 from . import registry
 
 
@@ -70,20 +70,19 @@ def _fused_call(x2, r2, m2, scale, bias, eps, block_rows, interpret):
         x2 = jnp.concatenate([x2, jnp.zeros((pad, d), x2.dtype)])
         r2 = jnp.concatenate([r2, jnp.zeros((pad, d), r2.dtype)])
         m2 = jnp.concatenate([m2, jnp.zeros((pad, 1), m2.dtype)])
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
     y, h = pl.pallas_call(
         functools.partial(_kernel, eps=eps),
         grid=((n + pad) // br,),
         in_specs=[
-            pl.BlockSpec((br, d), lambda i: (i, 0), **mem),
-            pl.BlockSpec((br, d), lambda i: (i, 0), **mem),
-            pl.BlockSpec((br, 1), lambda i: (i, 0), **mem),
-            pl.BlockSpec((1, d), lambda i: (0, 0), **mem),
-            pl.BlockSpec((1, d), lambda i: (0, 0), **mem),
+            vmem_spec((br, d), lambda i: (i, 0)),
+            vmem_spec((br, d), lambda i: (i, 0)),
+            vmem_spec((br, 1), lambda i: (i, 0)),
+            vmem_spec((1, d), lambda i: (0, 0)),
+            vmem_spec((1, d), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((br, d), lambda i: (i, 0), **mem),
-            pl.BlockSpec((br, d), lambda i: (i, 0), **mem),
+            vmem_spec((br, d), lambda i: (i, 0)),
+            vmem_spec((br, d), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n + pad, d), x2.dtype),
@@ -137,11 +136,10 @@ def fused_residual_layernorm(x, r, scale, bias, *, mask=None,
 
     Returns ``(y, h)`` in x.dtype.  ``mask`` (broadcastable to x's row
     shape) is a dropout keep-mask (pre-scaled, e.g. bernoulli/keep_prob);
-    None means no masking.  ``interpret=None`` auto-selects Pallas
-    interpret mode off-TPU.
+    None means no masking.  ``interpret=None`` resolves through
+    ``registry.resolve_interpret``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = registry.resolve_interpret(interpret)
     *lead, d = x.shape
     n = 1
     for s in lead:

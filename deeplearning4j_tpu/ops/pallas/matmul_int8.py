@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from ..flash_attention import _VMEM
+from ..flash_attention import vmem_spec
 from . import registry
 
 
@@ -82,16 +82,15 @@ def _int8_call(x2, q, s2, block_n, interpret):
     m, k = x2.shape
     n = q.shape[1]
     bn = _largest_divisor(n, block_n)
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
     return pl.pallas_call(
         _kernel,
         grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((m, k), lambda j: (0, 0), **mem),
-            pl.BlockSpec((k, bn), lambda j: (0, j), **mem),
-            pl.BlockSpec((1, bn), lambda j: (0, j), **mem),
+            vmem_spec((m, k), lambda j: (0, 0)),
+            vmem_spec((k, bn), lambda j: (0, j)),
+            vmem_spec((1, bn), lambda j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((m, bn), lambda j: (0, j), **mem),
+        out_specs=vmem_spec((m, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
     )(x2, q, s2)
@@ -122,9 +121,9 @@ def int8_matmul(x, qw: QuantizedLinear, *, block_n: int = 512,
                 interpret: bool | None = None):
     """``x @ (q * scale)`` on (..., K) activations, f32 out (callers cast
     — the decode head wants f32 logits, the FFN re-casts to the compute
-    dtype).  ``interpret=None`` auto-selects interpret mode off-TPU."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    dtype).  ``interpret=None`` resolves through
+    ``registry.resolve_interpret``."""
+    interpret = registry.resolve_interpret(interpret)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     out = _int8_mm(x2, qw.q, qw.scale.reshape(1, -1), block_n, interpret)

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from ..flash_attention import _VMEM, pltpu
+from ..flash_attention import pltpu, vmem_spec
 
 from . import registry
 
@@ -151,9 +151,9 @@ def _paged_int8_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     j = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale                 # (H, Dh)
     # dequantize THIS page inside the streamed read: one broadcast
-    # multiply by its (Kv,) per-head scale row, DMA'd beside the page
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0][None, :, None]
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0][None, :, None]
+    # multiply by its (Kv, 1) per-head scale column, DMA'd beside the page
+    k = k_ref[0].astype(jnp.float32) * ks_ref[0][None]
+    v = v_ref[0].astype(jnp.float32) * vs_ref[0][None]
     _accumulate_page(b, j, q, k, v, len_ref, o_ref, acc_ref, m_ref, l_ref,
                      page_size=page_size, n_pages=n_pages)
 
@@ -163,9 +163,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """Pallas paged decode attention; same signature/result contract as
     :func:`reference_paged_attention` (within the registered tolerance —
     running softmax reassociates the reduction, so NOT bitwise).
-    ``interpret=None`` auto-selects Pallas interpret mode off-TPU."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    ``interpret=None`` resolves through ``registry.resolve_interpret``."""
+    interpret = registry.resolve_interpret(interpret)
     B, H, Dh = q.shape
     ps = k_pages.shape[1]
     Kv = k_pages.shape[2]
@@ -173,21 +172,19 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     scale = Dh ** -0.5
     kernel = functools.partial(_paged_kernel, page_size=ps, n_pages=n_pages,
                                scale=scale)
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0), **mem),
+            vmem_spec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0)),
             # the paged read itself: this program's K/V block is whatever
             # physical page the scalar-prefetched table names
-            pl.BlockSpec((1, ps, Kv, Dh),
-                         lambda b, j, bt, ln: (bt[b, j], 0, 0, 0), **mem),
-            pl.BlockSpec((1, ps, Kv, Dh),
-                         lambda b, j, bt, ln: (bt[b, j], 0, 0, 0), **mem),
+            vmem_spec((1, ps, Kv, Dh),
+                      lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
+            vmem_spec((1, ps, Kv, Dh),
+                      lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0),
-                               **mem),
+        out_specs=vmem_spec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, Dh), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -224,8 +221,7 @@ def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     :func:`reference_paged_attention_int8` within the registered
     tolerance.  The dequantize happens in-kernel on each streamed
     page block — the full-precision pool is never materialized."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = registry.resolve_interpret(interpret)
     B, H, Dh = q.shape
     ps = k_pages.shape[1]
     Kv = k_pages.shape[2]
@@ -233,22 +229,24 @@ def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     scale = Dh ** -0.5
     kernel = functools.partial(_paged_int8_kernel, page_size=ps,
                                n_pages=n_pages, scale=scale)
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
-    page_spec = pl.BlockSpec((1, ps, Kv, Dh),
-                             lambda b, j, bt, ln: (bt[b, j], 0, 0, 0), **mem)
-    # each page's (Kv,) scale row rides the same block-table index as
-    # the page it scales
-    scale_spec = pl.BlockSpec((1, Kv),
-                              lambda b, j, bt, ln: (bt[b, j], 0), **mem)
+    page_spec = vmem_spec((1, ps, Kv, Dh),
+                          lambda b, j, bt, ln: (bt[b, j], 0, 0, 0))
+    # each page's per-head scales ride the same block-table index as the
+    # page they scale.  They enter as (P, Kv, 1): a (1, Kv) block of a
+    # (P, Kv) array breaks Mosaic's rule that the last two block dims be
+    # (8, 128)-divisible or equal to the array's, a (1, Kv, 1) block of
+    # (P, Kv, 1) meets it — and Kv lands on sublanes, where the page's
+    # own (Kv, Dh) tiles have it, so the multiply is a lane broadcast.
+    scale_spec = vmem_spec((1, Kv, 1),
+                           lambda b, j, bt, ln: (bt[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0), **mem),
+            vmem_spec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0)),
             page_spec, page_spec, scale_spec, scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0),
-                               **mem),
+        out_specs=vmem_spec((1, H, Dh), lambda b, j, bt, ln: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, Dh), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -261,8 +259,8 @@ def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages, k_scale.astype(jnp.float32),
-      v_scale.astype(jnp.float32))
+      q, k_pages, v_pages, k_scale.astype(jnp.float32)[..., None],
+      v_scale.astype(jnp.float32)[..., None])
 
 
 registry.register(registry.KernelCandidate(

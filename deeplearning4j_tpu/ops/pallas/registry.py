@@ -30,9 +30,9 @@ import importlib
 from typing import Any, Callable, Iterable, Mapping
 
 #: kernel modules pulled in lazily so importing the registry never drags
-#: jax.experimental.pallas in (and a broken/missing pallas degrades to
-#: "candidate absent", recorded in _IMPORT_ERRORS, instead of an
-#: ImportError at package import)
+#: jax.experimental.pallas in; a module that fails to import raises at
+#: the first lookup — on the installed stack that is a bug, not a
+#: degraded wheel
 _KERNEL_MODULES = (
     "deeplearning4j_tpu.ops.pallas.attention",
     "deeplearning4j_tpu.ops.pallas.layernorm",
@@ -72,8 +72,27 @@ class Pick:
 
 
 _REGISTRY: dict[tuple[str, str], KernelCandidate] = {}
-_IMPORT_ERRORS: dict[str, str] = {}
 _LOADED = False
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one rule behind every kernel's ``interpret`` flag.  An explicit
+    bool is obeyed.  ``None`` compiles on a TPU backend, interprets on the
+    CPU backend (the test suite), and raises on any other backend — so a
+    process on the chip either compiles a kernel or fails, and never runs
+    it interpreted without the caller having asked for that."""
+    if interpret is not None:
+        return interpret
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and interpret on CPU; backend "
+        f"{backend!r} is neither — pass interpret= explicitly")
 
 
 def register(candidate: KernelCandidate) -> KernelCandidate:
@@ -93,18 +112,9 @@ def _ensure_loaded() -> None:
     global _LOADED
     if _LOADED:
         return
-    _LOADED = True
     for mod in _KERNEL_MODULES:
-        try:
-            importlib.import_module(mod)
-        except Exception as e:  # degraded wheel: candidate absent, recorded
-            _IMPORT_ERRORS[mod] = repr(e)[:200]
-
-
-def import_errors() -> dict:
-    """Kernel modules that failed to import (empty on a healthy wheel)."""
-    _ensure_loaded()
-    return dict(_IMPORT_ERRORS)
+        importlib.import_module(mod)
+    _LOADED = True
 
 
 def kinds() -> list[str]:

@@ -34,7 +34,7 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-from ..flash_attention import _VMEM
+from ..flash_attention import vmem_spec
 from . import registry
 
 _NEG_INF = -1e30
@@ -92,21 +92,20 @@ def _xent_fwd(h2, head, t2, w2, block_t, block_v, interpret):
     n, d = h2.shape
     v = head.shape[1]
     n_v = -(-v // block_v)
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
     kernel = functools.partial(_kernel, block_v=block_v, v_real=v)
     outs = pl.pallas_call(
         kernel,
         grid=(n // block_t, n_v),
         in_specs=[
-            pl.BlockSpec((block_t, d), lambda i, j: (i, 0), **mem),
-            pl.BlockSpec((d, block_v), lambda i, j: (0, j), **mem),
-            pl.BlockSpec((block_t, 1), lambda i, j: (i, 0), **mem),
-            pl.BlockSpec((block_t, 1), lambda i, j: (i, 0), **mem),
+            vmem_spec((block_t, d), lambda i, j: (i, 0)),
+            vmem_spec((d, block_v), lambda i, j: (0, j)),
+            vmem_spec((block_t, 1), lambda i, j: (i, 0)),
+            vmem_spec((block_t, 1), lambda i, j: (i, 0)),
         ],
         # running (max, denom, gold) live in revisited output blocks —
         # the same accumulate-across-the-inner-grid-axis pattern as a
         # blocked matmul; loss/lse are written on the final vocab tile
-        out_specs=[pl.BlockSpec((block_t, 1), lambda i, j: (i, 0), **mem)
+        out_specs=[vmem_spec((block_t, 1), lambda i, j: (i, 0))
                    for _ in range(5)],
         out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.float32)
                    for _ in range(5)],
@@ -174,10 +173,10 @@ def blocked_cross_entropy(h, head, targets, weights=None, *,
     Mirrors ``lm_head_loss``'s ``token_xent`` contract (the caller
     divides by the real token count).  Any N and V work: N pads
     internally with zero-weight rows, the tail V tile is masked
-    in-kernel.  ``interpret=None`` auto-selects interpret mode off-TPU.
+    in-kernel.  ``interpret=None`` resolves through
+    ``registry.resolve_interpret``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = registry.resolve_interpret(interpret)
     n, d = h.shape
     block_t = min(block_t, n)
     block_v = min(block_v, head.shape[1])

@@ -1,88 +1,66 @@
-"""Persistent XLA compilation cache wiring.
+"""Persistent XLA compilation cache: one policy, placed from outside.
 
-Large jitted programs (the sharded BERT step, the bucketed trainer steps)
-pay tens of seconds of trace+lower+compile on first call.  JAX ships a
-persistent on-disk compilation cache that skips that cost across process
-restarts; this module is the single place the repo turns it on, so the
-trainer, ``MultiLayerNetwork``, and ``bench.py`` all share one policy.
+Large jitted programs (the BERT-base train step, every serving prefill
+bucket) pay tens of seconds of trace+lower+compile on first call.  JAX
+ships a persistent on-disk compilation cache that skips that cost across
+process restarts; this module is the single place the repo turns it on, so
+the trainer, ``InferenceEngine``, ``MultiLayerNetwork``, ``bench.py`` and
+``chip_smoke.py`` all share one policy:
 
-Opt-in by design: the cache writes files, and a library must not scribble
-on disk because it was imported.  The directory comes from (highest wins)
+- ``JAX_COMPILATION_CACHE_DIR`` is set: JAX itself reads it, the cache
+  lives there, and this module sets no directory at all.
+- It is not set: the cache lives at the fixed ``<checkout>/.cache/xla``
+  (gitignored), derived from this package's own location.  The path is
+  part of the cache key, so it never depends on a pid, a clock or a
+  temporary name.
+- JAX's own switch (``jax_enable_compilation_cache`` /
+  ``JAX_ENABLE_COMPILATION_CACHE=false``) turns the whole thing off; the
+  test suite uses it so that tests never write into the checkout's cache.
 
-1. an explicit ``cache_dir`` argument (``bench.py`` passes a repo-local
-   ``.cache/xla``),
-2. the ``DL4J_TPU_COMPILE_CACHE_DIR`` environment variable,
-
-and when neither is set — or ``DL4J_TPU_COMPILE_CACHE=0`` — setup is a
-no-op.  Configuration is idempotent and process-global (first directory
-wins, matching jax's own semantics: the config is global state).
+Either way the thresholds are lowered so small programs are cached too.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+from pathlib import Path
+
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = str(Path(__file__).resolve().parents[2] / ".cache" / "xla")
 
 _lock = threading.Lock()
-_configured_dir: str | None = None
-
-ENV_DIR = "DL4J_TPU_COMPILE_CACHE_DIR"
-ENV_ENABLE = "DL4J_TPU_COMPILE_CACHE"
+_configured = False
 
 
-def setup_compile_cache(cache_dir: str | None = None, *,
-                        min_compile_time_s: float = 0.0) -> str | None:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+def setup_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache under the module's
+    policy.  Returns the cache directory, or ``None`` when JAX's own
+    switch has the cache disabled.  Idempotent and process-global: safe to
+    call from every trainer/engine/network constructor."""
+    global _configured
+    import jax
 
-    Returns the configured directory, or ``None`` when disabled/unset.
-    Safe to call from every trainer/network constructor: after the first
-    successful configuration, later calls return the configured directory
-    without touching jax config again (even if they pass a different dir —
-    the jax cache is process-global, so repointing it mid-process would
-    only split the cache).
-
-    ``min_compile_time_s`` keeps trivial programs out of the cache; 0
-    caches everything (jax's own min-entry-size floor still applies).
-    """
-    global _configured_dir
-    if os.environ.get(ENV_ENABLE, "1") == "0":
+    if not jax.config.jax_enable_compilation_cache:
         return None
     with _lock:
-        if _configured_dir is not None:
-            return _configured_dir
-        target = cache_dir or os.environ.get(ENV_DIR)
-        if not target:
-            return None
-        import jax
+        if not _configured:
+            if not os.environ.get(ENV_DIR):
+                from jax.experimental.compilation_cache import (
+                    compilation_cache as jax_cc)
 
-        os.makedirs(target, exist_ok=True)
-        jax.config.update("jax_enable_compilation_cache", True)
-        jax.config.update("jax_compilation_cache_dir", target)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_s))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        try:
-            # jax initializes the persistent cache lazily on the FIRST
-            # compile and latches the result: if anything compiled before
-            # this call (warmup jits, another library), the new dir would
-            # silently never take effect — reset so the next compile
-            # re-initializes against the directory we just configured.
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:  # pragma: no cover - internal API drift
-            pass
-        _configured_dir = target
-        return _configured_dir
-
-
-def configured_dir() -> str | None:
-    """The directory the process-global cache points at (None if unset)."""
-    return _configured_dir
+                jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+                # a compile that ran under another directory latched it
+                jax_cc.reset_cache()
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              0.0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+            _configured = True
+        return jax.config.jax_compilation_cache_dir
 
 
 def _reset_for_tests() -> None:
-    """Forget the process-global configuration (jax config is untouched)."""
-    global _configured_dir
+    """Forget that this process was configured (jax config is untouched)."""
+    global _configured
     with _lock:
-        _configured_dir = None
+        _configured = False

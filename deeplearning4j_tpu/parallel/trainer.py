@@ -48,16 +48,8 @@ from typing import Any, Callable, Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        # the experimental API spells the flag check_rep
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
 
 from ..analysis.runtime import allow_transfers, hot_loop_guard
 from ..analysis.shardguard import SHARDGUARD
@@ -184,7 +176,7 @@ class DataParallelTrainer:
         # XLA cost of the most recent bucket's dispatch (captured at first
         # compile) — feeds the live train.mfu gauge at resolution fences
         self._step_cost = None
-        setup_compile_cache()  # persistent XLA cache (env-gated no-op)
+        setup_compile_cache()  # persistent XLA cache (compile_cache.py)
 
     # ------------------------------------------------------------------ state
     def init_state(self, params, key=None) -> TrainState:

@@ -240,11 +240,10 @@ class InferenceEngine:
 
     def __init__(self, model, params=None, checkpoint=None,
                  cfg: ServingConfig = ServingConfig(),
-                 compile_cache_dir: str | None = None,
                  draft_model=None, draft_params=None):
-        # PR-2 warmup integration: with a persistent cache dir configured
-        # (env or explicit), the warmup compiles below hit disk
-        setup_compile_cache(compile_cache_dir)
+        # with the persistent cache on, the warmup compiles hit disk on
+        # a restart (policy and placement: parallel/compile_cache.py)
+        setup_compile_cache()
         self.model = model
         self.cfg = cfg
         if cfg.prefix_cache and not cfg.paged:

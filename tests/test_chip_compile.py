@@ -1,0 +1,139 @@
+"""Every Pallas kernel of the main path compiles for a TPU v5e at BERT-base
+widths — checked here, with no chip attached, by the TPU compiler that is
+installed next to JAX (on-chip-measurement guide §2).
+
+Interpret-mode tests cannot see what Mosaic refuses (a block that breaks
+the (8, 128) tiling rule, a kernel that wants too much VMEM); these can,
+in a second or two each.  A compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+describing it loads libtpu, which only one process may hold, so nothing
+here may touch it while a module is being imported or collected.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops.flash_attention import flash_attention
+from deeplearning4j_tpu.ops.pallas.attention import fused_attention
+from deeplearning4j_tpu.ops.pallas.layernorm import fused_residual_layernorm
+from deeplearning4j_tpu.ops.pallas.matmul_int8 import (QuantizedLinear,
+                                                       int8_matmul)
+from deeplearning4j_tpu.ops.pallas.paged_attention import (
+    paged_attention, paged_attention_int8)
+from deeplearning4j_tpu.ops.pallas.xent import blocked_cross_entropy
+
+# BERT-base widths (__graft_entry__._flagship_cfg) at the training batch
+# and at the serving engine's default page geometry
+B, T, H, DH, D, F, V = 64, 512, 12, 64, 768, 3072, 32768
+SLOTS, PAGE, N_PAGES = 8, 16, T // 16
+POOL = SLOTS * N_PAGES + 1              # + the engine's trash page
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without the chip: keep it off around these
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Compile ``fn`` for the described chip from shapes alone and return
+    the optimized HLO text."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _fwd_bwd(fn):
+    """``fn`` plus its gradient wrt every float argument, as one program."""
+    def run(*args):
+        def scalar(*a):
+            out = fn(*a)
+            leaves = jax.tree_util.tree_leaves(out)
+            return sum(l.astype(jnp.float32).sum() for l in leaves)
+        idx = tuple(i for i, a in enumerate(args)
+                    if jnp.issubdtype(a.dtype, jnp.floating))
+        return jax.value_and_grad(scalar, argnums=idx)(*args)
+    return run
+
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+QKV = [((B, T, H, DH), BF16)] * 3
+
+
+@pytest.mark.parametrize("kernel,causal", [
+    (flash_attention, False), (fused_attention, True)],
+    ids=["flash-bidirectional", "fused-causal"])
+def test_attention_fwd_bwd_compiles(one_chip, kernel, causal):
+    hlo = _compile(
+        _fwd_bwd(lambda q, k, v: kernel(q, k, v, causal=causal,
+                                        interpret=False)),
+        one_chip, *QKV)
+    assert "tpu_custom_call" in hlo
+
+
+def test_fused_residual_layernorm_fwd_bwd_compiles(one_chip):
+    hlo = _compile(
+        _fwd_bwd(lambda x, r, s, b: fused_residual_layernorm(
+            x, r, s, b, interpret=False)),
+        one_chip, ((B, T, D), BF16), ((B, T, D), BF16),
+        ((D,), F32), ((D,), F32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_blocked_cross_entropy_fwd_bwd_compiles(one_chip):
+    hlo = _compile(
+        _fwd_bwd(lambda h, head, t: blocked_cross_entropy(
+            h, head, t, interpret=False)),
+        one_chip, ((B * T, D), BF16), ((D, V), BF16), ((B * T,), I32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("kv,dtype", [(H, BF16), (H, F32), (4, BF16)],
+                         ids=["bf16", "f32", "gqa-kv4"])
+def test_paged_attention_compiles(one_chip, kv, dtype):
+    hlo = _compile(
+        lambda q, kp, vp, bt, ln: paged_attention(q, kp, vp, bt, ln,
+                                                  interpret=False),
+        one_chip, ((SLOTS, H, DH), dtype),
+        ((POOL, PAGE, kv, DH), dtype), ((POOL, PAGE, kv, DH), dtype),
+        ((SLOTS, N_PAGES), I32), ((SLOTS,), I32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("kv", [H, 4], ids=["mha", "gqa-kv4"])
+def test_paged_attention_int8_compiles(one_chip, kv):
+    hlo = _compile(
+        lambda q, kp, vp, ks, vs, bt, ln: paged_attention_int8(
+            q, kp, vp, ks, vs, bt, ln, interpret=False),
+        one_chip, ((SLOTS, H, DH), BF16),
+        ((POOL, PAGE, kv, DH), I8), ((POOL, PAGE, kv, DH), I8),
+        ((POOL, kv), F32), ((POOL, kv), F32),
+        ((SLOTS, N_PAGES), I32), ((SLOTS,), I32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("k,n", [(D, F), (F, D), (D, V)],
+                         ids=["ffn-up", "ffn-down", "lm-head"])
+def test_int8_matmul_compiles(one_chip, k, n):
+    hlo = _compile(
+        lambda x, q, s: int8_matmul(x, QuantizedLinear(q=q, scale=s),
+                                    interpret=False),
+        one_chip, ((SLOTS, k), BF16), ((k, n), I8), ((n,), F32))
+    assert "tpu_custom_call" in hlo

@@ -88,7 +88,7 @@ def test_capture_is_noop_while_disabled():
     assert model.get("toy.step") is None
 
 
-def test_publish_utilization_gauges_finite_mfu():
+def test_publish_utilization_gauges_finite_mfu(cpu_peak_row):
     model = CostModel()
     info = model.capture("toy.step", _toy_step, *_toy_args())
     mfu = model.publish_utilization(info, 1e-3, "toy.mfu", "toy.mbu")
@@ -102,7 +102,7 @@ def test_publish_utilization_gauges_finite_mfu():
     assert "x.mfu" not in METRICS.snapshot()["gauges"]
 
 
-def test_trainer_publishes_train_mfu_on_cpu():
+def test_trainer_publishes_train_mfu_on_cpu(cpu_peak_row):
     """Acceptance: a CPU fit publishes finite train.mfu/train.mbu from
     cost_analysis of the actual compiled step."""
     from deeplearning4j_tpu.optimize import transforms as T

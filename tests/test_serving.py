@@ -562,7 +562,7 @@ def test_request_trace_chain_over_http(lm):
             assert e["ts"] + e["dur"] <= t1 + 1e3
 
 
-def test_untraced_request_mints_trace_and_decode_mfu_lands(lm):
+def test_untraced_request_mints_trace_and_decode_mfu_lands(lm, cpu_peak_row):
     """Without a caller span the engine mints a fresh trace id at
     admission; the decode loop publishes serving.decode_mfu either way."""
     from deeplearning4j_tpu.observability import METRICS, TRACER

@@ -22,6 +22,14 @@ def _median(ts):
     return ts[len(ts) // 2]
 
 
+def _peak_flops():
+    """The chip's row of the repo's one peak table (KeyError off-table)."""
+    import jax
+    from deeplearning4j_tpu.observability.cost import PEAKS
+
+    return PEAKS[jax.devices()[0].device_kind].flops
+
+
 def bert_variant(batch, seq, attention, remat=False, iters=8):
     import jax
     import jax.numpy as jnp
@@ -54,7 +62,7 @@ def bert_variant(batch, seq, attention, remat=False, iters=8):
     return {"batch": batch, "seq": seq, "attention": attention,
             "remat": remat, "median_ms": round(med * 1e3, 2),
             "tokens_per_sec": round(batch * seq / med, 1),
-            "mfu": round(flops / (med * 197e12), 4)}
+            "mfu": round(flops / (med * _peak_flops()), 4)}
 
 
 def resnet_variant(batch, iters=8, bn_fold=False):
@@ -95,7 +103,7 @@ def resnet_variant(batch, iters=8, bn_fold=False):
     return {"batch": batch, "bn_fold": bn_fold,
             "median_ms": round(med * 1e3, 2),
             "images_per_sec": round(batch / med, 1),
-            "mfu": round(flops / (med * 197e12), 4)}
+            "mfu": round(flops / (med * _peak_flops()), 4)}
 
 
 def bert_ablate(batch=64, seq=512, iters=8):
