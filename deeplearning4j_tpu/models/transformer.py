@@ -224,7 +224,14 @@ reduce_from_tp.defvjp(_reduce_fwd, _reduce_bwd)
 
 
 # --------------------------------------------------------------------------- math
+#
+# ``jax.named_scope`` names the sublayers in the functions training and
+# decode share, so a profiler trace names device time by part of the model
+# (DESIGN.md §9): embed, layernorm, qkv_proj, attention, attn_out, ffn,
+# lm_head_loss (lm_head where only logits are made), kv_gather, kv_scatter.
+# Scopes are HLO metadata: the compiled program does not change.
 
+@jax.named_scope("layernorm")
 def _layernorm(x, scale, bias, eps=1e-5):
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
@@ -299,6 +306,7 @@ def ring_attention(q, k, v, *, n_sp: int, sp_axis: str | None, causal: bool,
     return out.astype(q.dtype)
 
 
+@jax.named_scope("qkv_proj")
 def _qkv_proj(lp, h, dt):
     """Project normed activations ``h`` (..., D) to ``(q, k, v)`` heads.
 
@@ -326,6 +334,7 @@ def repeat_kv_heads(x, n_rep: int):
     return jnp.repeat(x, n_rep, axis=-2)
 
 
+@jax.named_scope("ffn")
 def _ffn(lp, h, dt):
     """The FFN sublayer body on (..., D) activations — shared verbatim by
     the training ``_block`` and the incremental ``decode_step`` so the two
@@ -352,45 +361,56 @@ def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
     if tp_axis:
         h = copy_to_tp(h, tp_axis)
     q, k, v = _qkv_proj(params, h, dt)
-    if k.shape[-2] != q.shape[-2]:
-        # GQA head-group broadcast before attention; under tp the local
-        # query heads would need a shard-offset-aware group map — not
-        # implemented, train GQA models without a tp axis
-        assert tp_axis is None, "GQA (n_kv_heads < n_heads) does not shard over tp"
-        k = repeat_kv_heads(k, q.shape[-2] // k.shape[-2])
-        v = repeat_kv_heads(v, q.shape[-2] // v.shape[-2])
-    if cfg.attention != "ring" and n_sp == 1 and t_local % 128 == 0:
-        # any registered ops/pallas attention candidate ("flash", "fused",
-        # ...) resolves through the kernel registry; ring keeps its direct
-        # path because it is the sp-aware collective, not a candidate here
-        from ..ops.pallas import registry as kernel_registry
-        attn = kernel_registry.get("attention", cfg.attention).fn(
-            q, k, v, causal=cfg.causal)
-    else:
-        attn = ring_attention(q, k, v, n_sp=n_sp, sp_axis=sp_axis,
-                              causal=cfg.causal, t_local=t_local)
-    proj = jnp.einsum("bthe,hed->btd", attn.astype(dt), params["wo"].astype(dt))
-    if tp_axis:
-        proj = reduce_from_tp(proj, tp_axis)  # partial sums over local heads
-    if cfg.fused_ln and not tp_axis:
-        # one VMEM pass for the mid-block residual-add + LayerNorm seam
-        # (bench-gated opt-in; under tp the unfused path keeps the
-        # copy_to_tp placement below untouched)
+    with jax.named_scope("attention"):
+        if k.shape[-2] != q.shape[-2]:
+            # GQA head-group broadcast before attention; under tp the local
+            # query heads would need a shard-offset-aware group map — not
+            # implemented, train GQA models without a tp axis
+            assert tp_axis is None, (
+                "GQA (n_kv_heads < n_heads) does not shard over tp")
+            k = repeat_kv_heads(k, q.shape[-2] // k.shape[-2])
+            v = repeat_kv_heads(v, q.shape[-2] // v.shape[-2])
+        if cfg.attention != "ring" and n_sp == 1 and t_local % 128 == 0:
+            # any registered ops/pallas attention candidate ("flash",
+            # "fused", ...) resolves through the kernel registry; ring keeps
+            # its direct path because it is the sp-aware collective, not a
+            # candidate here
+            from ..ops.pallas import registry as kernel_registry
+            attn = kernel_registry.get("attention", cfg.attention).fn(
+                q, k, v, causal=cfg.causal)
+        else:
+            attn = ring_attention(q, k, v, n_sp=n_sp, sp_axis=sp_axis,
+                                  causal=cfg.causal, t_local=t_local)
+    # one VMEM pass for the mid-block residual-add + LayerNorm seam
+    # (bench-gated opt-in; under tp the unfused path keeps the copy_to_tp
+    # placement below untouched)
+    fuse_ln = cfg.fused_ln and not tp_axis
+    with jax.named_scope("attn_out"):
+        proj = jnp.einsum("bthe,hed->btd", attn.astype(dt),
+                          params["wo"].astype(dt))
+        if tp_axis:
+            proj = reduce_from_tp(proj, tp_axis)  # partial sums over local heads
+        if not fuse_ln:
+            x = x + proj.astype(x.dtype)
+    if fuse_ln:
         from ..ops.pallas.layernorm import fused_residual_layernorm
-        x, h2 = fused_residual_layernorm(
-            x, proj.astype(x.dtype), params["ln2_scale"], params["ln2_bias"])
+        with jax.named_scope("layernorm"):
+            x, h2 = fused_residual_layernorm(
+                x, proj.astype(x.dtype), params["ln2_scale"],
+                params["ln2_bias"])
     else:
-        x = x + proj.astype(x.dtype)
         h2 = _layernorm(x, params["ln2_scale"], params["ln2_bias"])
     if tp_axis:
         h2 = copy_to_tp(h2, tp_axis)
     down = _ffn(params, h2, dt)
     if tp_axis:
         down = reduce_from_tp(down, tp_axis)
-    down = down + params["b2"].astype(dt)
-    return x + down.astype(x.dtype)
+    with jax.named_scope("ffn"):
+        down = down + params["b2"].astype(dt)
+        return x + down.astype(x.dtype)
 
 
+@jax.named_scope("embed")
 def embed_local(params, tokens, cfg: TransformerConfig,
                 sp_axis: str | None = None) -> jnp.ndarray:
     """Token + position embedding for the local (sp-offset) token shard —
@@ -403,6 +423,7 @@ def embed_local(params, tokens, cfg: TransformerConfig,
     return (x + pos[None]).astype(cfg.dtype)
 
 
+@jax.named_scope("lm_head_loss")
 def lm_head_loss(params, h, targets, cfg: TransformerConfig) -> jnp.ndarray:
     """Mean token cross entropy of final hidden states against targets
     (tied or separate head) — shared by the plain and pipelined paths.
@@ -476,6 +497,27 @@ def init_decode_cache(cfg: TransformerConfig, batch: int = 1) -> list:
             for _ in range(cfg.n_layers)]
 
 
+@jax.named_scope("embed")
+def _embed_at(params, tokens, pos, dt):
+    """Token + position embedding of ``tokens`` (N,) at positions ``pos``
+    (N,) — the decode paths' twin of :func:`embed_local`."""
+    return (jnp.take(params["tok_embed"], tokens, axis=0)
+            + jnp.take(params["pos_embed"], pos, axis=0)).astype(dt)
+
+
+@jax.named_scope("lm_head")
+def _lm_head_logits(params, h, cfg: TransformerConfig):
+    """f32 vocabulary logits of final hidden states ``h`` (..., D)."""
+    dt = cfg.dtype
+    if "head_q" in params:
+        # int8-quantized serving tree (quantize_params_for_decode): the
+        # LM head streams as int8 + per-channel scales, logits f32
+        from ..ops.pallas.matmul_int8 import int8_matmul
+        return int8_matmul(h.astype(dt), params["head_q"])
+    head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
+    return (h.astype(dt) @ head.astype(dt)).astype(jnp.float32)
+
+
 def _decode_attend(params, x, valid, write_kv, cfg: TransformerConfig,
                    attend=None):
     """Shared per-row decode arithmetic over already-embedded queries
@@ -495,31 +537,29 @@ def _decode_attend(params, x, valid, write_kv, cfg: TransformerConfig,
         h = _layernorm(x, lp["ln1_scale"], lp["ln1_bias"])
         q, k, v = _qkv_proj(lp, h, dt)                          # (N, H|Kv, Dh)
         ck, cv = write_kv(li, k, v)
-        if attend is not None:
-            att = attend(li, q)
-        else:
-            # GQA: broadcast the cached heads up to the query heads at the
-            # READ — the cache (and its bytes) stay at n_kv_heads
-            ck, cv = repeat_kv_heads(ck, n_rep), repeat_kv_heads(cv, n_rep)
-            s = jnp.einsum("bhd,bthd->bht", q, ck,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid[:, None, :], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            att = jnp.einsum("bht,bthd->bhd", p.astype(dt), cv,
-                             preferred_element_type=jnp.float32).astype(dt)
-        proj = jnp.einsum("bhe,hed->bd", att, lp["wo"].astype(dt))
-        x = x + proj.astype(x.dtype)
+        with jax.named_scope("attention"):
+            if attend is not None:
+                att = attend(li, q)
+            else:
+                # GQA: broadcast the cached heads up to the query heads at
+                # the READ — the cache (and its bytes) stay at n_kv_heads
+                ck, cv = repeat_kv_heads(ck, n_rep), repeat_kv_heads(cv, n_rep)
+                s = jnp.einsum("bhd,bthd->bht", q, ck,
+                               preferred_element_type=jnp.float32) * scale
+                s = jnp.where(valid[:, None, :], s, -jnp.inf)
+                p = jax.nn.softmax(s, axis=-1)
+                att = jnp.einsum("bht,bthd->bhd", p.astype(dt), cv,
+                                 preferred_element_type=jnp.float32).astype(dt)
+        with jax.named_scope("attn_out"):
+            proj = jnp.einsum("bhe,hed->bd", att, lp["wo"].astype(dt))
+            x = x + proj.astype(x.dtype)
         h2 = _layernorm(x, lp["ln2_scale"], lp["ln2_bias"])
-        down = _ffn(lp, h2, dt) + lp["b2"].astype(dt)
-        x = x + down.astype(x.dtype)
+        down = _ffn(lp, h2, dt)
+        with jax.named_scope("ffn"):
+            down = down + lp["b2"].astype(dt)
+            x = x + down.astype(x.dtype)
     h = _layernorm(x, params["final_ln_scale"], params["final_ln_bias"])
-    if "head_q" in params:
-        # int8-quantized serving tree (quantize_params_for_decode): the
-        # LM head streams as int8 + per-channel scales, logits f32
-        from ..ops.pallas.matmul_int8 import int8_matmul
-        return int8_matmul(h.astype(dt), params["head_q"])
-    head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
-    return (h.astype(dt) @ head.astype(dt)).astype(jnp.float32)
+    return _lm_head_logits(params, h, cfg)
 
 
 def decode_step(params, cache, tokens, pos, cfg: TransformerConfig):
@@ -536,8 +576,7 @@ def decode_step(params, cache, tokens, pos, cfg: TransformerConfig):
     row updates), so the two cannot diverge numerically."""
     dt = cfg.dtype
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape)  # (B,)
-    x = (jnp.take(params["tok_embed"], tokens, axis=0)
-         + jnp.take(params["pos_embed"], pos_b, axis=0)).astype(dt)  # (B, D)
+    x = _embed_at(params, tokens, pos_b, dt)                         # (B, D)
     valid = jnp.arange(cfg.max_len)[None, :] <= pos_b[:, None]       # (B, T)
     # per-row cache write: row b's K/V lands at its OWN position pos_b[b]
     upd = jax.vmap(
@@ -545,8 +584,9 @@ def decode_step(params, cache, tokens, pos, cfg: TransformerConfig):
     new_cache: list = []
 
     def write_kv(li, k, v):
-        ck = upd(cache[li]["k"], k, pos_b)
-        cv = upd(cache[li]["v"], v, pos_b)
+        with jax.named_scope("kv_scatter"):
+            ck = upd(cache[li]["k"], k, pos_b)
+            cv = upd(cache[li]["v"], v, pos_b)
         new_cache.append({"k": ck, "v": cv})
         return ck, cv
 
@@ -630,6 +670,7 @@ def gather_paged_kv(c, block_table, max_len: int):
     return c.reshape((-1,) + c.shape[2:])[flat]
 
 
+@jax.named_scope("kv_gather")
 def gather_paged_layer(c, block_table, max_len: int, dtype):
     """Logical ``(B, max_len, Kv, Dh)`` k and v views of ONE layer's page
     pool dict ``c`` — quant-transparent: a float pool gathers exactly as
@@ -646,6 +687,7 @@ def gather_paged_layer(c, block_table, max_len: int, dtype):
             gather_paged_kv(vf, block_table, max_len))
 
 
+@jax.named_scope("kv_scatter")
 def scatter_paged_layer(c, flat, k, v) -> dict:
     """Commit token K/V rows ``k``/``v`` (N, Kv, Dh) at flat pool indices
     ``flat`` (N,) into one layer's pool dict ``c`` (out-of-range indices
@@ -693,8 +735,7 @@ def decode_step_paged(params, pages, block_tables, tokens, pos,
     dt = cfg.dtype
     ps = pages[0]["k"].shape[1]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape)  # (B,)
-    x = (jnp.take(params["tok_embed"], tokens, axis=0)
-         + jnp.take(params["pos_embed"], pos_b, axis=0)).astype(dt)
+    x = _embed_at(params, tokens, pos_b, dt)
     valid = jnp.arange(cfg.max_len)[None, :] <= pos_b[:, None]
     flat = paged_flat_index(block_tables, pos_b[:, None], ps)[:, 0]      # (B,)
     new_pages: list = []
@@ -742,8 +783,7 @@ def decode_window(params, cache, tokens, pos, cfg: TransformerConfig):
     ok = wpos < T
     pos2 = jnp.minimum(wpos, T - 1).reshape(B * W)
     tok2 = tokens.reshape(B * W)
-    x = (jnp.take(params["tok_embed"], tok2, axis=0)
-         + jnp.take(params["pos_embed"], pos2, axis=0)).astype(dt)
+    x = _embed_at(params, tok2, pos2, dt)
     valid = jnp.arange(T)[None, :] <= pos2[:, None]                   # (N, T)
     row = jnp.arange(B, dtype=jnp.int32)[:, None]
     flat = jnp.where(ok, row * T + wpos, B * T).reshape(B * W)        # drop OOB
@@ -784,8 +824,7 @@ def decode_window_paged(params, pages, block_tables, tokens, pos,
     ok = wpos < T
     pos2 = jnp.minimum(wpos, T - 1).reshape(B * W)
     tok2 = tokens.reshape(B * W)
-    x = (jnp.take(params["tok_embed"], tok2, axis=0)
-         + jnp.take(params["pos_embed"], pos2, axis=0)).astype(dt)
+    x = _embed_at(params, tok2, pos2, dt)
     valid = jnp.arange(T)[None, :] <= pos2[:, None]
     flat = jnp.where(ok, paged_flat_index(block_tables, wpos, ps),
                      n_phys * ps).reshape(B * W)                      # drop OOB
@@ -829,8 +868,10 @@ def forward_local(params, tokens, cfg: TransformerConfig, *,
     x = encode_local(params, tokens, cfg, n_sp=n_sp, sp_axis=sp_axis,
                      tp_axis=tp_axis)
     head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("btd,dv->btv", x.astype(cfg.dtype), head.astype(cfg.dtype))
-    return logits.astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("btd,dv->btv", x.astype(cfg.dtype),
+                            head.astype(cfg.dtype))
+        return logits.astype(jnp.float32)
 
 
 def lm_loss_local(params, tokens, targets, cfg: TransformerConfig, **axes):

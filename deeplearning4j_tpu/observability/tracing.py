@@ -19,8 +19,16 @@ span with *explicit* start/duration for code (like the serving engine's
 single serve thread) that multiplexes many logical requests and cannot
 use ``with``-nesting.
 
+Profiler clock: an open ``Span`` also holds a
+``jax.profiler.TraceAnnotation`` of the same name, so whenever a profiler
+session is running (``profiler_trace`` below, or any ``jax.profiler``
+trace) every program span is written into the profiler's own trace, on the
+clock of the device operations; with no session it costs one inactive
+TraceMe.  ``record_span`` spans are given after the fact and stay host-only.
+
 Zero-overhead contract: when observability is disabled, ``span()`` returns
-the shared no-op context manager (no allocation); see ``core``.
+the shared no-op context manager (no allocation, no annotation); see
+``core``.
 """
 
 from __future__ import annotations
@@ -51,6 +59,7 @@ _trace_ctx: contextvars.ContextVar[tuple[str, str] | None] = (
     contextvars.ContextVar("dl4j_tpu_trace_ctx", default=None))
 
 _process_index: int | None = None
+_annotation_cls: Any = None
 
 # getrandbits is GIL-atomic and ~10x cheaper than os.urandom for ids that
 # only need uniqueness, not cryptographic strength.
@@ -133,11 +142,25 @@ def _pid() -> int:
     return _process_index
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` (jax imported lazily,
+    as ``_pid`` does); the shared no-op where jax is not installed, so tools
+    that only read spans keep working."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation_cls = TraceAnnotation
+        except ImportError:
+            _annotation_cls = lambda name: core.NOOP_SPAN  # noqa: E731
+    return _annotation_cls(name)
+
+
 class Span:
     """One nestable timed region.  Use via ``tracer.span(...)``."""
 
     __slots__ = ("tracer", "name", "attrs", "parent", "depth",
-                 "t0_us", "tid", "_token",
+                 "t0_us", "tid", "_token", "_annotation",
                  "trace_id", "span_id", "parent_id")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict[str, Any]):
@@ -169,11 +192,14 @@ class Span:
         self.span_id = new_span_id()
         self._token = _current.set(self)
         self.tid = threading.get_ident()
+        self._annotation = _annotation(self.name)
+        self._annotation.__enter__()
         self.t0_us = (time.perf_counter() - _EPOCH) * 1e6
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur_us = (time.perf_counter() - _EPOCH) * 1e6 - self.t0_us
+        self._annotation.__exit__(exc_type, exc, tb)
         _current.reset(self._token)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
@@ -228,7 +254,9 @@ class Tracer:
         """Record a span with explicit ``time.perf_counter()`` start and
         duration (seconds).  For code that times many interleaved logical
         requests on one thread and cannot use ``with``-nesting.  Returns
-        the span id (for parenting children), or None when disabled."""
+        the span id (for parenting children), or None when disabled.
+        Host-only: a profiler annotation cannot be written after the fact,
+        so these spans are not in a ``jax.profiler`` trace."""
         if not core.enabled():
             return None
         sid = span_id or new_span_id()

@@ -26,6 +26,7 @@ no silent caps.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 from typing import Any, Callable, Iterable, Mapping
 
@@ -72,6 +73,7 @@ class Pick:
 
 
 _REGISTRY: dict[tuple[str, str], KernelCandidate] = {}
+_SCOPED: dict[tuple[str, str], KernelCandidate] = {}    # what get() returns
 _LOADED = False
 
 
@@ -102,10 +104,29 @@ def register(candidate: KernelCandidate) -> KernelCandidate:
     programming error."""
     key = (candidate.kind, candidate.name)
     prev = _REGISTRY.get(key)
-    if prev is not None and prev.fn is not candidate.fn:
+    fn = getattr(candidate.fn, "unscoped", candidate.fn)   # from get()
+    if prev is not None and prev.fn is not fn:
         raise ValueError(f"kernel candidate {key} already registered")
-    _REGISTRY[key] = candidate
+    _REGISTRY[key] = dataclasses.replace(candidate, fn=fn)
+    _SCOPED.pop(key, None)
     return candidate
+
+
+def _scoped(cand: KernelCandidate) -> KernelCandidate:
+    """``cand`` with its entry point under ``jax.named_scope("<kind>.<name>")``,
+    so a profiler trace names the kernel's device time by its registered
+    name (metadata only: the compiled program does not change)."""
+    scope = f"{cand.kind}.{cand.name}"
+
+    @functools.wraps(cand.fn)
+    def fn(*args, **kwargs):
+        import jax
+
+        with jax.named_scope(scope):
+            return cand.fn(*args, **kwargs)
+
+    fn.unscoped = cand.fn
+    return dataclasses.replace(cand, fn=fn)
 
 
 def _ensure_loaded() -> None:
@@ -128,14 +149,18 @@ def candidates(kind: str) -> list[KernelCandidate]:
 
 
 def get(kind: str, name: str) -> KernelCandidate:
+    """The candidate production code runs: its ``fn`` executes under a named
+    scope of its registered name (``candidates`` lists the bare entries)."""
     _ensure_loaded()
-    try:
-        return _REGISTRY[(kind, name)]
-    except KeyError:
-        avail = [c.name for c in candidates(kind)]
-        raise KeyError(
-            f"no kernel candidate {name!r} of kind {kind!r} "
-            f"(registered: {avail})") from None
+    key = (kind, name)
+    if key not in _SCOPED:
+        if key not in _REGISTRY:
+            avail = [c.name for c in candidates(kind)]
+            raise KeyError(
+                f"no kernel candidate {name!r} of kind {kind!r} "
+                f"(registered: {avail})")
+        _SCOPED[key] = _scoped(_REGISTRY[key])
+    return _SCOPED[key]
 
 
 # --------------------------------------------------------------- adoption gate

@@ -17,7 +17,16 @@ the trainer, ``InferenceEngine``, ``MultiLayerNetwork``, ``bench.py`` and
   ``JAX_ENABLE_COMPILATION_CACHE=false``) turns the whole thing off; the
   test suite uses it so that tests never write into the checkout's cache.
 
-Either way the thresholds are lowered so small programs are cached too.
+Either way the thresholds are lowered so small programs are cached too, and
+the cache key includes the programs' metadata (source locations and
+``jax.named_scope`` paths).  JAX leaves it out by default, so that an edit
+which only moves lines still hits; but an executable read back under such a
+key carries the metadata of whichever program was compiled first, and a
+profiler trace then shows stale names, or none, for the sublayers
+(DESIGN.md §9; measured in PERF.md §6, PR 25: the parent commit's run read
+this commit's executable, scopes and all).  Names in a trace are what the
+benchmark's per-sublayer metrics are read from, so they have to be this
+program's own: an edit that moves lines costs one compile.
 """
 
 from __future__ import annotations
@@ -55,6 +64,8 @@ def setup_compile_cache() -> str | None:
             jax.config.update("jax_persistent_cache_min_compile_time_secs",
                               0.0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key", True)
             _configured = True
         return jax.config.jax_compilation_cache_dir
 

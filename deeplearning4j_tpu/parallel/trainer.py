@@ -70,6 +70,14 @@ def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+def _jit_step(fn, **jit_kwargs):
+    """``jax.jit(fn)`` under the one program name every trainer step has in
+    a profiler trace, ``jit_step``: the benchmark's readers find the step
+    program by that prefix whichever builder made it."""
+    fn.__name__ = fn.__qualname__ = "step"
+    return jax.jit(fn, **jit_kwargs)
+
+
 class LazyLoss:
     """Lazy handle to a device-resident loss.
 
@@ -314,8 +322,10 @@ class DataParallelTrainer:
             mask = jnp.arange(x.shape[0]) < n_valid
             loss, grads = jax.value_and_grad(masked)(
                 params, x, y, key, mask, n_valid)
-            updates, tstate = self.transform.update(grads, tstate, params, iteration)
-            params = tfm.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, tstate = self.transform.update(
+                    grads, tstate, params, iteration)
+                params = tfm.apply_updates(params, updates)
             return params, tstate, loss
 
         # shardguard (off by default: one flag check per dispatch) diffs
@@ -324,7 +334,7 @@ class DataParallelTrainer:
         # on every step instead of failing loudly
         return SHARDGUARD.wrap(
             "train.sync_step",
-            jax.jit(
+            _jit_step(
                 step,
                 in_shardings=(rep, rep, batch_sh, batch_sh, rep, rep, rep),
                 out_shardings=(rep, rep, rep),
@@ -361,8 +371,10 @@ class DataParallelTrainer:
 
         def local(params, tstate, x, y, key, iteration, n_valid):
             if stage >= 3:
-                flat_full = jax.tree_util.tree_map(
-                    lambda c: clv.all_gather_or_identity(c, DP, n_dp), params)
+                with jax.named_scope("grad_sync"):
+                    flat_full = jax.tree_util.tree_map(
+                        lambda c: clv.all_gather_or_identity(c, DP, n_dp),
+                        params)
                 nat = z.unflatten_like(flat_full, z.natural_params)
             else:
                 nat = params
@@ -385,13 +397,15 @@ class DataParallelTrainer:
             (grads,) = vjp_fn(jnp.ones((), lsum.dtype) / denom)
             loss = clv.psum(lsum, DP) / denom
             gflat = z.flatten_tree(grads)
-            if stage == 1:
-                gfull = jax.tree_util.tree_map(
-                    lambda g: clv.psum(g, DP), gflat)
-                gchunk = z.chunk_tree(gfull, idx, z.natural_params)
-            else:
-                gchunk = jax.tree_util.tree_map(
-                    lambda g: clv.reduce_scatter_or_psum(g, DP, n_dp), gflat)
+            with jax.named_scope("grad_sync"):
+                if stage == 1:
+                    gfull = jax.tree_util.tree_map(
+                        lambda g: clv.psum(g, DP), gflat)
+                    gchunk = z.chunk_tree(gfull, idx, z.natural_params)
+                else:
+                    gchunk = jax.tree_util.tree_map(
+                        lambda g: clv.reduce_scatter_or_psum(g, DP, n_dp),
+                        gflat)
             if stage >= 3:
                 pchunk = params  # already this chip's chunks
             else:
@@ -399,14 +413,16 @@ class DataParallelTrainer:
                                       z.natural_params)
             # decay classification must come from the NATURAL shapes — on
             # 1-D chunks the ndim >= 2 heuristic would decay nothing
-            with tfm.decay_mask_override(z.decay_mask):
+            with tfm.decay_mask_override(z.decay_mask), \
+                    jax.named_scope("optimizer"):
                 updates, tstate = self.transform.update(
                     gchunk, tstate, pchunk, iteration)
-            pchunk = tfm.apply_updates(pchunk, updates)
+                pchunk = tfm.apply_updates(pchunk, updates)
             if stage >= 3:
                 return pchunk, tstate, loss
-            pfull = jax.tree_util.tree_map(
-                lambda c: clv.all_gather_or_identity(c, DP, n_dp), pchunk)
+            with jax.named_scope("grad_sync"):
+                pfull = jax.tree_util.tree_map(
+                    lambda c: clv.all_gather_or_identity(c, DP, n_dp), pchunk)
             return z.unflatten_like(pfull, z.natural_params), tstate, loss
 
         param_spec = P(DP) if stage >= 3 else P()
@@ -420,7 +436,7 @@ class DataParallelTrainer:
         # param spec), so the first dispatch captures them and later drift
         # — not the initial layout — is the violation
         return SHARDGUARD.wrap(
-            "train.zero_step", jax.jit(smapped, donate_argnums=(0, 1)))
+            "train.zero_step", _jit_step(smapped, donate_argnums=(0, 1)))
 
     def _build_local_step(self):
         """HogWild-approx local step: runs independently per dp shard."""
@@ -438,8 +454,10 @@ class DataParallelTrainer:
             denom = jnp.maximum(jnp.sum(mask), 1)  # all-pad shard guard
             loss, grads = jax.value_and_grad(masked)(
                 params, x, y, key, mask, denom)
-            updates, tstate = self.transform.update(grads, tstate, params, iteration[0])
-            params = tfm.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, tstate = self.transform.update(
+                    grads, tstate, params, iteration[0])
+                params = tfm.apply_updates(params, updates)
             expand = lambda a: a[None] if isinstance(a, jnp.ndarray) else a
             return (jax.tree_util.tree_map(expand, params),
                     jax.tree_util.tree_map(expand, tstate), loss[None])
@@ -450,7 +468,7 @@ class DataParallelTrainer:
             out_specs=(P(DP), P(DP), P(DP)),
             check_vma=False,
         )
-        return jax.jit(smapped, donate_argnums=(0, 1))
+        return _jit_step(smapped, donate_argnums=(0, 1))
 
     def _build_average(self):
         """Periodic parameter averaging: one pmean inside shard_map."""
@@ -574,32 +592,22 @@ class DataParallelTrainer:
             return []
         entries, self._pending = self._pending, []
         obs = _obs_enabled()
-        wait0 = time.perf_counter() if obs else 0.0
-        # one fence suffices: device programs execute in dispatch order, so
-        # the last loss being ready implies the whole window has executed
-        entries[-1][0].block()
-        vals = [lazy.value() for lazy, _n, _s in entries]
-        if obs:
-            now = time.perf_counter()
-            METRICS.observe_time("train_step.resolve_wait", now - wait0)
-            METRICS.increment("train_step.losses_resolved", len(vals))
-            METRICS.gauge("train_step.loss", vals[-1])
-            t0 = self._window_t0
-            if t0 is not None and now > t0:
-                window = now - t0
-                n_samples = sum(n for _, n, _s in entries)
-                METRICS.gauge("train_step.samples_per_sec", n_samples / window)
-                # amortized per-step execution time over the async window —
-                # the steady-state throughput histogram (dispatch times in
-                # `train_step` no longer measure execution)
-                METRICS.observe_many(
-                    "train_step.execute", [window / len(entries)] * len(entries))
-                # live MFU/MBU from the same cost_analysis() accounting
-                # bench reports: one dispatch's flops over the amortized
-                # per-step execution time
-                COSTS.publish_utilization(
-                    self._step_cost, window / len(entries),
-                    "train.mfu", "train.mbu")
+        with trace.span("trainer.fence", n=len(entries)):
+            wait0 = time.perf_counter() if obs else 0.0
+            # Device programs execute in dispatch order, so the losses become
+            # ready in order and the last one's wait is the fence.  Each is
+            # waited for under a span of its own: a profiler trace keeps a
+            # span only if it starts and ends inside the traced slice, and
+            # one wait for a whole window (seconds) seldom does.  fence.wait:
+            # the device is still working; fence.read (values to floats,
+            # metrics published): it has nothing queued
+            for lazy, _n, _s in entries:
+                with trace.span("trainer.fence.wait"):
+                    lazy.block()
+            with trace.span("trainer.fence.read"):
+                vals = [lazy.value() for lazy, _n, _s in entries]
+                if obs:
+                    self._publish_window(entries, vals, wait0)
         self._window_t0 = None
         if self._nan_guard:
             # divergence detection lives at the resolution point — the one
@@ -609,6 +617,29 @@ class DataParallelTrainer:
                     METRICS.increment("resilience.nan_detected")
                     raise DivergenceError(s, v)
         return vals
+
+    def _publish_window(self, entries, vals, wait0: float) -> None:
+        """The resolved window's gauges and histograms, at the fence."""
+        now = time.perf_counter()
+        METRICS.observe_time("train_step.resolve_wait", now - wait0)
+        METRICS.increment("train_step.losses_resolved", len(vals))
+        METRICS.gauge("train_step.loss", vals[-1])
+        t0 = self._window_t0
+        if t0 is not None and now > t0:
+            window = now - t0
+            n_samples = sum(n for _, n, _s in entries)
+            METRICS.gauge("train_step.samples_per_sec", n_samples / window)
+            # amortized per-step execution time over the async window —
+            # the steady-state throughput histogram (dispatch times in
+            # `train_step` no longer measure execution)
+            METRICS.observe_many(
+                "train_step.execute", [window / len(entries)] * len(entries))
+            # live MFU/MBU from the same cost_analysis() accounting
+            # bench reports: one dispatch's flops over the amortized
+            # per-step execution time
+            COSTS.publish_utilization(
+                self._step_cost, window / len(entries),
+                "train.mfu", "train.mbu")
 
     def abort(self) -> None:
         """Drop the pending-loss ring without resolving — the supervisor's
@@ -718,10 +749,11 @@ class DataParallelTrainer:
                 while True:
                     if goodput is not None:
                         t_fetch = time.perf_counter()
-                    try:
-                        x, y, n_valid, bucket = next(stream)
-                    except StopIteration:
+                    with trace.span("trainer.data_wait"):
+                        item = next(stream, None)
+                    if item is None:
                         break
+                    x, y, n_valid, bucket = item
                     if goodput is not None:
                         goodput.data_wait(t_fetch, time.perf_counter())
                     state, lazy = self._dispatch(state, x, y, n_valid, bucket)

@@ -127,6 +127,13 @@ class ServingConfig:
     #                                 migration unit is a KV page.
 
 
+def _named(name: str, fn: Callable) -> Callable:
+    """``fn`` renamed, so that ``jax.jit`` calls its program ``jit_<name>``
+    and a profiler trace shows the engine's programs by what they do."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def kv_page_bytes(mcfg, page_size: int, kv_quant: str | None = None) -> int:
     """Device bytes one KV page costs under the given storage mode — the
     accounting behind ``serving.kv_bytes*`` and the capacity planning in
@@ -338,7 +345,7 @@ class InferenceEngine:
         self._step_fn = SHARDGUARD.wrap(
             "serving.decode_step",
             jax.jit(
-                self._build_step(),
+                _named("decode_step", self._build_step()),
                 donate_argnums=(2,) if cfg.speculative else (1,)))
         # brownout seam (DESIGN.md §26): a speculative engine also carries
         # the PLAIN step, compiled at warmup alongside the spec one, so
@@ -348,7 +355,8 @@ class InferenceEngine:
         self._spec_enabled = cfg.speculative         # guarded-by: self._lock
         self._plain_step_fn = (SHARDGUARD.wrap(
             "serving.decode_step_plain",
-            jax.jit(self._build_plain_step(), donate_argnums=(1,)))
+            jax.jit(_named("decode_step", self._build_plain_step()),
+                    donate_argnums=(1,)))
             if cfg.speculative else None)
         self._max_new_cap: int | None = None         # guarded-by: self._lock
         self._admission_hook = None                  # guarded-by: self._lock
@@ -469,13 +477,15 @@ class InferenceEngine:
                 kv_update = {"cache": cache}
             # per-slot RNG, exactly Transformer.sample's kv stream: split
             # the slot key, carry the first half, draw from the second
-            pair = jax.vmap(jax.random.split)(state["keys"])    # (S, 2) keys
-            carry, sub = pair[:, 0], pair[:, 1]
-            safe_t = jnp.where(temp > 0, temp, 1.0)
-            drawn = jax.vmap(jax.random.categorical)(
-                sub, logits / safe_t[:, None])
-            pick = jnp.where(temp > 0, drawn.astype(jnp.int32),
-                             jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            with jax.named_scope("sample"):
+                pair = jax.vmap(jax.random.split)(state["keys"])  # (S, 2) keys
+                carry, sub = pair[:, 0], pair[:, 1]
+                safe_t = jnp.where(temp > 0, temp, 1.0)
+                drawn = jax.vmap(jax.random.categorical)(
+                    sub, logits / safe_t[:, None])
+                pick = jnp.where(
+                    temp > 0, drawn.astype(jnp.int32),
+                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
             can = active & (pos < limit) & (pos + 1 < cfg.max_len)
             emitted = jnp.where(can, pick, -1)
             new_pos = jnp.where(can, pos + 1, pos)
@@ -550,13 +560,14 @@ class InferenceEngine:
             picks = []
             kcur = state["keys"]
             for i in range(W):
-                pair = jax.vmap(jax.random.split)(kcur)
-                kcur, sub = pair[:, 0], pair[:, 1]
-                drawn = jax.vmap(jax.random.categorical)(
-                    sub, logits[:, i] / safe_t[:, None])
-                pick = jnp.where(
-                    temp > 0, drawn.astype(jnp.int32),
-                    jnp.argmax(logits[:, i], axis=-1).astype(jnp.int32))
+                with jax.named_scope("sample"):
+                    pair = jax.vmap(jax.random.split)(kcur)
+                    kcur, sub = pair[:, 0], pair[:, 1]
+                    drawn = jax.vmap(jax.random.categorical)(
+                        sub, logits[:, i] / safe_t[:, None])
+                    pick = jnp.where(
+                        temp > 0, drawn.astype(jnp.int32),
+                        jnp.argmax(logits[:, i], axis=-1).astype(jnp.int32))
                 picks.append(pick)
                 key_stack.append(jax.random.key_data(kcur))
             picks = jnp.stack(picks, axis=1)                     # (S, W)
@@ -696,7 +707,8 @@ class InferenceEngine:
                 **kv_update,
             )
 
-        prefill = jax.jit(admit, donate_argnums=(2,))
+        prefill = jax.jit(_named(f"prefill_b{bucket}", admit),
+                          donate_argnums=(2,))
         with self._lock:
             self._admit_fns[bucket] = prefill
         METRICS.increment("serving.prefill.recompile")
@@ -952,11 +964,13 @@ class InferenceEngine:
         aliased page — ``PagePool.decref`` only returns dead ones)."""
         if not freed or not self.cfg.paged:
             return
-        mask = np.zeros((self._num_pages + 1,), bool)
-        mask[freed] = True
-        self._state = dict(
-            self._state,
-            pages=reset_cache_pages(self._state["pages"], jnp.asarray(mask)))
+        with trace.span("serving.wipe", pages=len(freed)):
+            mask = np.zeros((self._num_pages + 1,), bool)
+            mask[freed] = True
+            self._state = dict(
+                self._state,
+                pages=reset_cache_pages(self._state["pages"],
+                                        jnp.asarray(mask)))
 
     def _serve_loop(self) -> None:
         while not self._stop.is_set():
@@ -1032,7 +1046,7 @@ class InferenceEngine:
                              len(self._slots) / self.cfg.slots,
                              buckets=FILL_BUCKETS)
         t0 = time.perf_counter()
-        with hot_loop_guard():
+        with hot_loop_guard(), trace.span("serving.decode_segment"):
             pending = self._decode_segment()
         with allow_transfers(), trace.span("serving.resolve"):
             self._resolve(pending, t0)
@@ -1110,7 +1124,8 @@ class InferenceEngine:
                     COSTS.capture(f"serving.prefill.b{bucket}", admit_fn,
                                   *args)
                 t_pre = time.perf_counter()
-                self._state = admit_fn(*args)
+                with trace.span("serving.prefill_dispatch", bucket=bucket):
+                    self._state = admit_fn(*args)
                 if req.trace_id:
                     trace.record_span(
                         "serving.prefill", t_pre,
@@ -1245,11 +1260,12 @@ class InferenceEngine:
                 padded[:len(prompt)] = prompt
                 admit_fn = self._admit_for(bucket)
                 dparams = self._draft_params if self.cfg.speculative else {}
-                self._state = admit_fn(
-                    params, dparams, self._state, jnp.asarray(padded),
-                    jnp.int32(len(prompt)), jnp.int32(cached_len),
-                    jnp.int32(slot), jax.random.key(int(seed)),
-                    jnp.float32(temperature), jnp.int32(max_new_tokens))
+                with trace.span("serving.prefill_dispatch", bucket=bucket):
+                    self._state = admit_fn(
+                        params, dparams, self._state, jnp.asarray(padded),
+                        jnp.int32(len(prompt)), jnp.int32(cached_len),
+                        jnp.int32(slot), jax.random.key(int(seed)),
+                        jnp.float32(temperature), jnp.int32(max_new_tokens))
                 if self.cfg.prefix_cache:
                     with self._lock:
                         if self._params is params:

@@ -23,7 +23,8 @@ def cache_on(monkeypatch):
     saved = {n: getattr(jax.config, n) for n in (
         "jax_enable_compilation_cache", "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes")}
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_compilation_cache_include_metadata_in_key")}
     monkeypatch.delenv(cc.ENV_DIR, raising=False)
     cc._reset_for_tests()
     real_update = jax.config.update
@@ -59,6 +60,14 @@ def test_env_unset_means_fixed_in_checkout_path(cache_on):
     assert cc.setup_compile_cache() == cc.DEFAULT_DIR
     assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_DIR
     assert jax.config.jax_enable_compilation_cache is True
+
+
+def test_cache_key_includes_the_metadata(cache_on):
+    """An executable read back from the cache must carry THIS program's
+    ``jax.named_scope`` paths, or a profiler trace shows another's names."""
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    cc.setup_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_idempotent(cache_on):

@@ -351,11 +351,102 @@ def test_pad_batch_counter():
     assert snap["counters"]["train_step.padded_samples"] == 2 * (8 - 15 % 8)
 
 
-def test_disabled_mode_records_nothing():
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts constructions
+    and records enter/exit by name."""
+
+    made: list = []
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+        _CountingAnnotation.made.append(name)
+
+    def __enter__(self):
+        _CountingAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _CountingAnnotation.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def counted_annotations(monkeypatch):
+    from deeplearning4j_tpu.observability import tracing
+    monkeypatch.setattr(_CountingAnnotation, "made", [])
+    monkeypatch.setattr(_CountingAnnotation, "log", [])
+    monkeypatch.setattr(tracing, "_annotation_cls", _CountingAnnotation)
+    return _CountingAnnotation
+
+
+def test_span_enters_and_leaves_one_annotation(counted_annotations):
+    t = Tracer()
+    with t.span("outer", k=1):
+        with t.span("inner"):
+            pass
+    with pytest.raises(KeyError):
+        with t.span("fails"):
+            raise KeyError("x")
+    assert counted_annotations.made == ["outer", "inner", "fails"]
+    assert counted_annotations.log == [
+        ("enter", "outer"), ("enter", "inner"), ("exit", "inner"),
+        ("exit", "outer"), ("enter", "fails"), ("exit", "fails")]
+    # record_span is given after the fact: host-only, no annotation
+    t.record_span("later", 0.0, 1.0)
+    assert counted_annotations.made == ["outer", "inner", "fails"]
+    assert [e["name"] for e in t.events] == ["inner", "outer", "fails", "later"]
+
+
+def test_spans_are_written_into_a_running_profiler_trace(tmp_path):
+    """The real ``TraceAnnotation``: a span opened while a profiler session
+    runs is an event of the same name on the host plane of its trace."""
+    from jax.profiler import ProfileData
+
+    with obs.profiler_trace(str(tmp_path)):
+        with trace.span("trainer.fence", n=1):
+            with trace.span("trainer.fence.wait"):
+                jnp.ones(8).block_until_ready()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    found = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("trainer.")}
+    assert set(found) == {"trainer.fence", "trainer.fence.wait"}
+    (o0, o1), (i0, i1) = found["trainer.fence"], found["trainer.fence.wait"]
+    assert o0 <= i0 < i1 <= o1
+
+
+def test_fit_spans_name_the_host_phases():
+    obs.TRACER.clear()
+    state, losses = _tiny_fit(n_batches=3, epochs=1)
+    by_name = {}
+    for e in obs.TRACER.to_chrome_trace()["traceEvents"]:
+        by_name.setdefault(e["name"], []).append(e["args"]["parent"])
+    # one data_wait per batch and one for the end of the stream
+    assert by_name["trainer.data_wait"] == ["trainer.fit"] * 4
+    assert set(by_name["trainer.fence"]) == {"trainer.fit"}
+    n_fences = len(by_name["trainer.fence"])
+    # one wait per loss (a short span is whole inside a traced slice where a
+    # window's whole wait is not), one read per fence
+    assert by_name["trainer.fence.wait"] == ["trainer.fence"] * len(losses)
+    assert by_name["trainer.fence.read"] == ["trainer.fence"] * n_fences
+
+
+def test_disabled_mode_records_nothing(counted_annotations, monkeypatch):
+    from deeplearning4j_tpu.observability import tracing
+    spans_made = []
+    real_init = tracing.Span.__init__
+    monkeypatch.setattr(
+        tracing.Span, "__init__",
+        lambda self, *a, **k: (spans_made.append(a[1]), real_init(self, *a, **k))[1])
     obs.disable()
     try:
         state, losses = _tiny_fit(n_batches=2, epochs=1)
         assert len(losses) == 2          # training itself still works
+        # no Span and no TraceAnnotation on the dispatch path
+        assert spans_made == [] and counted_annotations.made == []
         snap = METRICS.snapshot()
         assert snap["counters"] == {} and snap["timers"] == {}
         assert snap["gauges"] == {}
