@@ -35,6 +35,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability import METRICS
 from ..parallel.mesh import DP, SP, TP
 
 Params = Any
@@ -53,11 +54,14 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16        # MXU compute dtype
     param_dtype: Any = jnp.float32
     remat: bool = True               # jax.checkpoint each block (HBM for FLOPs)
-    attention: str = "ring"          # "ring" (default) | any registered
-    #                                  ops/pallas attention candidate
-    #                                  ("flash", "fused") — Pallas kernels
-    #                                  are single-shard only and opt-in
-    #                                  until the bench auto-pick adopts them
+    attention: str = "auto"          # "auto" (default): the fused Pallas
+    #                                  kernel on a TPU for the shapes it
+    #                                  takes, the XLA ring path otherwise
+    #                                  (_attention_candidate) | "ring":
+    #                                  always the XLA path | a registered
+    #                                  ops/pallas candidate ("fused",
+    #                                  "flash"): that kernel, single-shard
+    #                                  only — how a parity test forces a side
     fused_ln: bool = False           # fuse the mid-block residual+LN seam
     #                                  through ops/pallas/layernorm — one
     #                                  VMEM pass instead of two HBM
@@ -307,13 +311,28 @@ def ring_attention(q, k, v, *, n_sp: int, sp_axis: str | None, causal: bool,
 
 
 @jax.named_scope("qkv_proj")
-def _qkv_proj(lp, h, dt):
+def _qkv_proj(lp, h, dt, merged: bool = False):
     """Project normed activations ``h`` (..., D) to ``(q, k, v)`` heads.
 
     Classic trees carry the packed ``wqkv`` and run the exact einsum the
     pre-GQA code always did (the bitwise-parity path); GQA trees carry
     ``wq``/``wkv`` and produce k/v with ``n_kv_heads`` heads.  The key
-    check is static at trace time (same idiom as ``w1_q`` in ``_ffn``)."""
+    check is static at trace time (same idiom as ``w1_q`` in ``_ffn``).
+
+    ``merged`` (classic trees) runs one matmul each for q, k and v against
+    the weight with its head axes merged, and splits the heads afterwards:
+    the same numbers, for the fused attention kernel, which merges them
+    again.  XLA cancels the two reshapes and lays the matmul's result out
+    the way the kernel reads it; a ``(..., H, Dh)`` result of its own it
+    lays out otherwise (a 64-wide minor dimension pads to 128) and pays a
+    transposing copy per operand, eight a block with the gradients."""
+    if merged and "wkv" not in lp:
+        w = lp["wqkv"].astype(dt)
+        return tuple(
+            jnp.einsum("...d,df->...f", h.astype(dt),
+                       w[:, i].reshape(w.shape[0], -1)
+                       ).reshape(*h.shape[:-1], *w.shape[2:])
+            for i in range(3))
     if "wkv" in lp:
         q = jnp.einsum("...d,dhe->...he", h.astype(dt), lp["wq"].astype(dt))
         kv = jnp.einsum("...d,dshe->...she", h.astype(dt),
@@ -354,13 +373,32 @@ def _ffn(lp, h, dt):
     return jnp.einsum("...f,fd->...d", u, lp["w2"].astype(dt))
 
 
+def _attention_candidate(cfg: TransformerConfig, n_sp: int, t_local: int,
+                         n_heads_local: int) -> str | None:
+    """The registered ops/pallas attention candidate ``_block`` runs, from
+    what it can observe, or ``None`` for ``ring_attention``: the sp ring is
+    the collective and never a candidate, and by default the kernel runs
+    where it compiles (a TPU backend) on the shapes it is built for."""
+    if cfg.attention == "ring" or n_sp != 1:
+        return None
+    if cfg.attention == "auto":
+        from ..ops.pallas.attention import kernel_takes
+        on = (jax.default_backend() == "tpu"
+              and cfg.kv_heads == cfg.n_heads
+              and kernel_takes(t_local, n_heads_local, cfg.head_dim))
+        return "fused" if on else None
+    return cfg.attention if t_local % 128 == 0 else None
+
+
 def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
     """One transformer block, tp/sp-aware (runs inside shard_map)."""
     dt = cfg.dtype
     h = _layernorm(x, params["ln1_scale"], params["ln1_bias"])
     if tp_axis:
         h = copy_to_tp(h, tp_axis)
-    q, k, v = _qkv_proj(params, h, dt)
+    name = _attention_candidate(cfg, n_sp, t_local, params["wo"].shape[0])
+    merged = name is not None       # a kernel reads the heads merged
+    q, k, v = _qkv_proj(params, h, dt, merged=merged)
     with jax.named_scope("attention"):
         if k.shape[-2] != q.shape[-2]:
             # GQA head-group broadcast before attention; under tp the local
@@ -370,13 +408,13 @@ def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
                 "GQA (n_kv_heads < n_heads) does not shard over tp")
             k = repeat_kv_heads(k, q.shape[-2] // k.shape[-2])
             v = repeat_kv_heads(v, q.shape[-2] // v.shape[-2])
-        if cfg.attention != "ring" and n_sp == 1 and t_local % 128 == 0:
-            # any registered ops/pallas attention candidate ("flash",
-            # "fused", ...) resolves through the kernel registry; ring keeps
-            # its direct path because it is the sp-aware collective, not a
-            # candidate here
+        # counted while tracing, once per block and compilation: tells a
+        # step on the kernel from one that fell back
+        METRICS.increment(
+            "attention.path.kernel" if name else "attention.path.xla")
+        if name:
             from ..ops.pallas import registry as kernel_registry
-            attn = kernel_registry.get("attention", cfg.attention).fn(
+            attn = kernel_registry.get("attention", name).fn(
                 q, k, v, causal=cfg.causal)
         else:
             attn = ring_attention(q, k, v, n_sp=n_sp, sp_axis=sp_axis,
@@ -386,8 +424,13 @@ def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
     # placement below untouched)
     fuse_ln = cfg.fused_ln and not tp_axis
     with jax.named_scope("attn_out"):
-        proj = jnp.einsum("bthe,hed->btd", attn.astype(dt),
-                          params["wo"].astype(dt))
+        wo = params["wo"].astype(dt)
+        if merged:  # on both sides of the kernel, as in _qkv_proj
+            proj = jnp.einsum("btf,fd->btd",
+                              attn.astype(dt).reshape(*attn.shape[:2], -1),
+                              wo.reshape(-1, wo.shape[-1]))
+        else:
+            proj = jnp.einsum("bthe,hed->btd", attn.astype(dt), wo)
         if tp_axis:
             proj = reduce_from_tp(proj, tp_axis)  # partial sums over local heads
         if not fuse_ln:

@@ -21,9 +21,15 @@ Design (standard flash attention v2 schedule):
 ``interpret=None`` follows the tier's one rule
 (``ops.pallas.registry.resolve_interpret``): compiled Mosaic kernel on a TPU
 backend, interpret mode on the CPU backend (tests), an error anywhere else.
-It is OPT-IN via
-``TransformerConfig(attention="flash")`` until a real-chip benchmark
-validates it end-to-end.
+
+Status since PR 27: this candidate is the DUPLICATE.  The training default
+on a TPU is ``ops/pallas/attention.py`` ("fused": bf16 MXU operands, a
+Pallas backward, no transposes around the kernel), which the chip measured
+at 2.5 ms a BERT-base layer forward + backward against 10.5 ms for the XLA
+path (PERF.md §6).  This one still casts q, k and v to f32 in its forward
+and runs ``_blockwise_bwd``, which writes its per-block scores to HBM; it is
+reached only through ``TransformerConfig(attention="flash")`` and leaves
+with the registry's autopick chain (ROADMAP D1).
 """
 
 from __future__ import annotations

@@ -74,18 +74,67 @@ def _fwd_bwd(fn):
 
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
-QKV = [((B, T, H, DH), BF16)] * 3
 
 
-@pytest.mark.parametrize("kernel,causal", [
-    (flash_attention, False), (fused_attention, True)],
-    ids=["flash-bidirectional", "fused-causal"])
-def test_attention_fwd_bwd_compiles(one_chip, kernel, causal):
+def _per_example(kernel):
+    """The trainer's form: ``vmap`` over examples of batch 1."""
+    return lambda q, k, v, **kw: jax.vmap(
+        lambda a, b, c: kernel(a[None], b[None], c[None], **kw)[0])(q, k, v)
+
+
+@pytest.mark.parametrize("kernel,causal,shape", [
+    (flash_attention, False, (B, T, H, DH)),
+    (fused_attention, True, (B, T, H, DH)),
+    (fused_attention, False, (B, T, H, DH)),
+    (fused_attention, True, (8, 1024, 16, DH)),
+    (_per_example(fused_attention), False, (B, T, H, DH)),
+    (fused_attention, True, (8, 2048, 8, 128)),
+], ids=["flash-bidirectional", "fused-causal", "fused-bidirectional",
+        "fused-causal-gpt2-medium", "fused-per-example-vmap",
+        "fused-causal-2048-width-128"])
+def test_attention_fwd_bwd_compiles(one_chip, kernel, causal, shape):
     hlo = _compile(
         _fwd_bwd(lambda q, k, v: kernel(q, k, v, causal=causal,
                                         interpret=False)),
-        one_chip, *QKV)
+        one_chip, *[(shape, BF16)] * 3)
     assert "tpu_custom_call" in hlo
+
+
+def test_train_step_holds_no_score_matrix(one_chip, monkeypatch):
+    """``value_and_grad(lm_loss_local)`` of two BERT-base-width blocks,
+    compiled for the chip with the kernel ``_block`` takes there: no array
+    whose last two dimensions are both the sequence length (the scores are
+    gone, forward and backward), no copy or transpose beside the kernels
+    (XLA lays q, k, v and the gradients out the way they read and write),
+    and two custom calls a block: forward, and one backward for dq, dk, dv."""
+    import re
+
+    from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                       init_params,
+                                                       lm_loss_local)
+    from deeplearning4j_tpu.ops.pallas import registry
+
+    # code that asks for the backend still sees the CPU here: steer it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(registry, "resolve_interpret",
+                        lambda interpret: bool(interpret))
+    cfg = TransformerConfig(vocab_size=2048, d_model=D, n_heads=H,
+                            n_layers=2, d_ff=F, max_len=T, causal=False,
+                            remat=False)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(jax.random.key(0), cfg)))
+    tokens = jax.ShapeDtypeStruct((8, T), I32, sharding=one_chip)
+    hlo = jax.jit(jax.value_and_grad(
+        lambda p, x, y: lm_loss_local(p, x, y, cfg))).lower(
+            params, tokens, tokens).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * 2
+    assert not re.findall(rf"(?:f32|bf16)\[[0-9,]*{T},{T}\]", hlo)
+    beside = [line for line in hlo.splitlines()
+              if re.search(r" (copy|transpose)\(", line)
+              and re.search(r'op_name="[^"]*(attention|qkv_proj|attn_out)',
+                            line)]
+    assert not beside, beside[:3]
 
 
 def test_fused_residual_layernorm_fwd_bwd_compiles(one_chip):
