@@ -1,0 +1,427 @@
+"""Models whose layers are (mixer, ffn) pairs chosen per layer (DESIGN D5).
+
+``models/transformer.py`` hard-codes one block (learned positions, biased
+LayerNorm, GELU FFN, equal heads).  Here a layer is ``x + mixer(norm(x))``
+then ``h + ffn(norm(h))`` with RMSNorm, no biases and no position table, and
+the two halves are specs looked up in ``MIXERS`` / ``FFNS``: a spec is a
+frozen dataclass that knows how to make its parameters and how to run.  The
+trunk (embedding, ``lm_head_loss`` with its chunked cross entropy, dtypes,
+``remat``) is the dense model's: ``HybridConfig.base`` is a
+``TransformerConfig`` and the head's code is shared, so a change to the head
+or to the ``attention`` registry's kernels moves both families.
+
+What is here: the ZAYA1 layer (``CCA`` mixer, arXiv:2510.04476; ``MoE`` ffn,
+arXiv:2511.17127).  The plain reference is ``models/reference/zaya.py``; the
+parameter tree below is the one it reads.
+
+- ``CCA``: attention in a compressed latent — ``H`` query heads and ``G`` KV
+  heads of width ``d`` projected straight from the hidden size, two causal
+  convolutions over time on q and k, a q-k mean, L2-normed q and k with a
+  learned temperature per KV head, rotary positions on part of each head,
+  half of the value taken from the previous token.  The scores run through
+  the ``attention`` registry's kernel where it takes the shape.
+- ``MoE``: a router MLP over ALL ``n_experts``, top-1, no dropped tokens, a
+  grouped matmul (``lax.ragged_dot`` over tokens sorted by expert) over the
+  ``held`` experts this chip owns.  A token routed to an expert that lives
+  elsewhere gets zero from this chip: on one chip the layer runs without its
+  exchange, and nothing stands in for the absent chips.
+
+Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix``
+inside), ``attention``, ``attn_out``, ``ffn`` (with ``moe.router``,
+``moe.dispatch``, ``moe.experts`` inside), ``embed``, ``lm_head_loss``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..observability import METRICS, trace
+from .transformer import TransformerConfig, lm_head_loss
+
+Params = Any
+
+
+def _normal(key, shape, scale, dtype):
+    return (scale * jax.random.normal(key, shape)).astype(dtype)
+
+
+# --------------------------------------------------------------------------- mixer
+
+@dataclasses.dataclass(frozen=True)
+class CCA:
+    """Compressed convolutional attention."""
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    head_dim: int = 128
+    conv_kernels: tuple[int, int] = (2, 2)   # depthwise, then grouped by head
+    rope_theta: float = 5_000_000.0
+    rotary_factor: float = 0.5
+
+    def init(self, key, d_model: int, dtype) -> Params:
+        h, g, d = self.n_heads, self.n_kv_heads, self.head_dim
+        assert g == 2 and h % g == 0, "the value halves are KV heads 0 and 1"
+        k0, k1 = self.conv_kernels
+        ks = jax.random.split(key, 7)
+        return {
+            "wq": _normal(ks[0], (d_model, h * d), d_model ** -0.5, dtype),
+            "wk": _normal(ks[1], (d_model, g * d), d_model ** -0.5, dtype),
+            "wv1": _normal(ks[2], (d_model, d), d_model ** -0.5, dtype),
+            "wv2": _normal(ks[3], (d_model, d), d_model ** -0.5, dtype),
+            "conv0": _normal(ks[4], (k0, (h + g) * d), k0 ** -0.5, dtype),
+            "conv1": _normal(ks[5], (k1, h + g, d, d), (k1 * d) ** -0.5, dtype),
+            "temp": jnp.ones((g,), dtype),
+            "wo": _normal(ks[6], (h * d, d_model), (h * d) ** -0.5, dtype),
+        }
+
+
+def _shift(x, n: int):
+    """``x`` (B, T, ...) moved ``n`` positions later in time, zeros first."""
+    if n == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (n, 0)
+    return jnp.pad(x, pad)[:, :x.shape[1]]
+
+
+def _rope(x, theta: float, rotary: int):
+    """``x`` (B, T, heads, d) f32: rotate the first ``rotary`` features of
+    each head by position, feature ``i`` paired with ``i + rotary // 2``."""
+    half = rotary // 2
+    inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                  * (-2.0 * math.log(theta) / rotary))
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:rotary]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotary:]], axis=-1)
+
+
+def _depthwise_conv(z, w):
+    """Causal convolution over time of ``z`` (B, T, C) with one filter
+    ``w`` (k, C) a channel; tap ``k - 1`` is the current position."""
+    n = w.shape[0]
+    return sum(_shift(z, n - 1 - j) * w[j] for j in range(n))
+
+
+def _grouped_conv(z, w):
+    """Causal convolution over time of ``z`` (B, T, G, i) with ``w``
+    (k, G, i, o): each group's channels mix among themselves.  f32 out (the
+    CPU backend has no batched bf16 x bf16 -> f32 product, so each tap
+    leaves in ``z``'s dtype and the taps add up in f32)."""
+    n = w.shape[0]
+    return sum(jnp.einsum("btgi,gio->btgo", _shift(z, n - 1 - j),
+                          w[j]).astype(jnp.float32) for j in range(n))
+
+
+def _unit(x, scale):
+    return x * (scale * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                  + 1e-12))
+
+
+@jax.named_scope("qkv_proj")
+def cca_qkv(spec: CCA, p, u, dt, *, convs=True, qk_mean=True, value_shift=True,
+            rotary=True):
+    """Normed activations ``u`` (B, T, E) -> ``q (B, T, H, d)``,
+    ``k, v (B, T, G, d)`` in ``dt``.  The four switches exist for the tests
+    that show each part moves the output; a model runs with all of them on."""
+    b, t, _ = u.shape
+    h, g, d = spec.n_heads, spec.n_kv_heads, spec.head_dim
+    u = u.astype(dt)
+
+    def proj(x, w):
+        return jnp.einsum("btd,df->btf", x, w.astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    q0, k0 = proj(u, p["wq"]), proj(u, p["wk"])               # f32 (B, T, H*d)
+    v = jnp.stack([proj(u, p["wv1"]),
+                   proj(_shift(u, 1) if value_shift else u, p["wv2"])], axis=2)
+    with jax.named_scope("cca.mix"):
+        z = jnp.concatenate([q0, k0], axis=-1)                # (B, T, C)
+        if convs:
+            z = _depthwise_conv(z, p["conv0"].astype(jnp.float32))
+            z = _grouped_conv(z.astype(dt).reshape(b, t, h + g, d),
+                              p["conv1"].astype(dt)).reshape(b, t, (h + g) * d)
+        q = z[..., :h * d].reshape(b, t, h, d)
+        k = z[..., h * d:].reshape(b, t, g, d)
+        if qk_mean:
+            m = (q0.reshape(b, t, h, d)
+                 + jnp.repeat(k0.reshape(b, t, g, d), h // g, axis=2)) * 0.5
+            q = q + m
+            k = k + m.reshape(b, t, g, h // g, d).mean(axis=3)
+        q = _unit(q, math.sqrt(d))
+        k = _unit(k, math.sqrt(d)) * p["temp"].astype(jnp.float32)[:, None]
+        if rotary:
+            r = int(spec.rotary_factor * d)
+            q, k = _rope(q, spec.rope_theta, r), _rope(k, spec.rope_theta, r)
+    return q.astype(dt), k.astype(dt), v.astype(dt)
+
+
+def _attend(q, k, v):
+    """Causal attention of ``q (B, T, H, d)`` over ``k, v (B, T, G, d)``:
+    the registry's fused kernel on a TPU for the shapes it takes, the XLA
+    path otherwise; counted like ``transformer._block`` counts its side."""
+    from ..ops.pallas import registry as kernel_registry
+    from ..ops.pallas.attention import kernel_takes
+    from .transformer import repeat_kv_heads, ring_attention
+
+    t, h, d = q.shape[1:]
+    k, v = repeat_kv_heads(k, h // k.shape[2]), repeat_kv_heads(v, h // v.shape[2])
+    on = jax.default_backend() == "tpu" and kernel_takes(t, h, d)
+    METRICS.increment("attention.path.kernel" if on else "attention.path.xla")
+    if on:
+        return kernel_registry.get("attention", "fused").fn(q, k, v, causal=True)
+    return ring_attention(q, k, v, n_sp=1, sp_axis=None, causal=True, t_local=t)
+
+
+def cca_mixer(spec: CCA, p, u, dt, **parts):
+    q, k, v = cca_qkv(spec, p, u, dt, **parts)
+    with jax.named_scope("attention"):
+        out = _attend(q, k, v)
+    with jax.named_scope("attn_out"):
+        return jnp.einsum("btf,fd->btd",
+                          out.astype(dt).reshape(*out.shape[:2], -1),
+                          p["wo"].astype(dt))
+
+
+# --------------------------------------------------------------------------- ffn
+
+@dataclasses.dataclass(frozen=True)
+class MoE:
+    """Top-1 mixture of gated-SiLU experts behind a router MLP."""
+    n_experts: int = 16              # the router's width, as published
+    held: tuple[int, int] = (0, 8)   # (first, count): this chip's experts
+    router_hidden: int = 256
+    d_ff: int = 2048
+
+    def init(self, key, d_model: int, dtype) -> Params:
+        r, f, n = self.router_hidden, self.d_ff, self.held[1]
+        ks = jax.random.split(key, 7)
+        return {
+            "router": {
+                "wd": _normal(ks[0], (d_model, r), d_model ** -0.5, dtype),
+                "w1": _normal(ks[1], (r, r), r ** -0.5, dtype),
+                "b1": jnp.zeros((r,), dtype),
+                "w2": _normal(ks[2], (r, r), r ** -0.5, dtype),
+                "b2": jnp.zeros((r,), dtype),
+                "w3": _normal(ks[3], (r, self.n_experts), r ** -0.5, dtype),
+            },
+            "wg": _normal(ks[4], (n, d_model, f), d_model ** -0.5, dtype),
+            "wu": _normal(ks[5], (n, d_model, f), d_model ** -0.5, dtype),
+            "wdn": _normal(ks[6], (n, f, d_model), f ** -0.5, dtype),
+        }
+
+
+@jax.named_scope("moe.router")
+def route(r, u):
+    """``u`` (N, E) -> ``(gate (N,) f32, e (N,) int32)``: the router MLP in
+    float32 whatever the compute dtype, so that a near-tie is decided by the
+    activations and not by the router's own rounding."""
+    hi = lax.Precision.HIGHEST
+    u = u.astype(jnp.float32)
+    p = jnp.dot(u, r["wd"].astype(jnp.float32), precision=hi)
+    a = jax.nn.gelu(jnp.dot(p, r["w1"].astype(jnp.float32), precision=hi)
+                    + r["b1"])
+    b = jax.nn.gelu(jnp.dot(a, r["w2"].astype(jnp.float32), precision=hi)
+                    + r["b2"])
+    pi = jax.nn.softmax(
+        jnp.dot(b, r["w3"].astype(jnp.float32), precision=hi), axis=-1)
+    e = jnp.argmax(pi, axis=-1).astype(jnp.int32)
+    return jnp.take_along_axis(pi, e[:, None], axis=-1)[:, 0], e
+
+
+def expert_counts(spec: MoE, e):
+    """Tokens per expert over ALL ``n_experts``, from the choices ``e``."""
+    return jnp.zeros((spec.n_experts,), jnp.int32).at[e.reshape(-1)].add(1)
+
+
+@jax.named_scope("ffn")
+def moe_ffn(spec: MoE, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> ``(this chip's part of the
+    layer's output (B, T, E), e (B, T))``.  Every token of the batch is
+    grouped at once: tokens are sorted by the held expert they chose (those
+    routed elsewhere last), three grouped matmuls run over the sorted rows,
+    and the rows go back to their places weighted by the router's
+    probability.  No capacity, no dropped token, whatever the imbalance."""
+    shape = u.shape
+    u = u.reshape(-1, shape[-1]).astype(dt)
+    n = u.shape[0]
+    first, count = spec.held
+    gate, e = route(p["router"], u)
+    with jax.named_scope("moe.dispatch"):
+        local = (e >= first) & (e < first + count)
+        slot = jnp.where(local, e - first, count)      # elsewhere: sorted last
+        order = jnp.argsort(slot, stable=True)
+        back = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[slot].add(1)[:count]
+        xs = u[order]
+    with jax.named_scope("moe.experts"):
+        # Rows past the last held group belong to no expert here.  The TPU's
+        # ragged product leaves such rows of its result UNWRITTEN (the CPU's
+        # zero-fills them), in the backward products too: masked on the way in
+        # and on the way out, so that neither pass ever reads them.
+        held_rows = (jnp.arange(n) < sizes.sum())[:, None]
+
+        def grouped(x, w):
+            out = lax.ragged_dot(jnp.where(held_rows, x, 0), w.astype(dt), sizes,
+                                 preferred_element_type=jnp.float32)
+            return jnp.where(held_rows, out, 0.0)
+
+        hidden = (jax.nn.silu(grouped(xs, p["wg"]))
+                  * grouped(xs, p["wu"])).astype(dt)
+        ys = grouped(hidden, p["wdn"])
+    with jax.named_scope("moe.dispatch"):
+        y = ys[back] * jnp.where(local, gate, 0.0)[:, None]
+    return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
+
+
+#: spec class -> the function that runs it
+MIXERS = {CCA: cca_mixer}
+FFNS = {MoE: moe_ffn}
+
+
+# --------------------------------------------------------------------------- model
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """``base`` carries what the trunk shares with the dense model
+    (``vocab_size``, ``d_model``, ``dtype``, ``param_dtype``, ``remat``,
+    ``xent_chunk``, ``xent_impl``; heads, ``d_ff``, ``max_len`` and ``causal``
+    are not read here); ``layers`` one ``(mixer spec, ffn spec)`` per layer."""
+    base: TransformerConfig
+    layers: tuple[tuple[Any, Any], ...]
+    norm_eps: float = 1e-5
+
+
+def init_params(key, cfg: HybridConfig) -> Params:
+    pd, d = cfg.base.param_dtype, cfg.base.d_model
+    assert cfg.base.tie_embeddings, "the head is the embedding transposed"
+    keys = jax.random.split(key, len(cfg.layers) + 1)
+    layers = []
+    for (mixer, ffn), k in zip(cfg.layers, keys[:-1]):
+        km, kf = jax.random.split(k)
+        layers.append({"norm1": jnp.ones((d,), pd),
+                       "cca": mixer.init(km, d, pd),
+                       "norm2": jnp.ones((d,), pd),
+                       "moe": ffn.init(kf, d, pd)})
+    return {"tok_embed": _normal(keys[-1], (cfg.base.vocab_size, d), 0.02, pd),
+            "final_norm": jnp.ones((d,), pd), "layers": layers}
+
+
+@jax.named_scope("layernorm")
+def rms_norm(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * w).astype(x.dtype)
+
+
+def block(lp, x, cfg: HybridConfig, i: int):
+    """Layer ``i``: ``(x + mixer + ffn, the ffn's expert choices)``."""
+    mixer, ffn = cfg.layers[i]
+    dt = cfg.base.dtype
+    x = x + MIXERS[type(mixer)](
+        mixer, lp["cca"], rms_norm(x, lp["norm1"], cfg.norm_eps), dt)
+    y, e = FFNS[type(ffn)](
+        ffn, lp["moe"], rms_norm(x, lp["norm2"], cfg.norm_eps), dt)
+    return x + y, e
+
+
+def encode(params, tokens, cfg: HybridConfig):
+    """``tokens`` (B, T) -> ``(final normed hidden (B, T, E), [e per layer])``."""
+    with jax.named_scope("embed"):
+        x = jnp.take(params["tok_embed"], tokens, axis=0).astype(cfg.base.dtype)
+    fn = block
+    if cfg.base.remat:
+        fn = jax.checkpoint(block, static_argnums=(2, 3))
+    choices = []
+    for i, lp in enumerate(params["layers"]):
+        x, e = fn(lp, x, cfg, i)
+        choices.append(e)
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), choices
+
+
+def forward(params, tokens, cfg: HybridConfig):
+    """f32 logits (B, T, V) over the vocabulary held."""
+    h, _ = encode(params, tokens, cfg)
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("btd,vd->btv", h.astype(cfg.base.dtype),
+                          params["tok_embed"].astype(cfg.base.dtype)
+                          ).astype(jnp.float32)
+
+
+def lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
+    """Each example's mean cross entropy, ``(B,)``, with the whole batch's
+    tokens grouped together in the expert layers and chunked together in the
+    head: the loss a ``DataParallelTrainer(per_example_loss=True)`` takes."""
+    h, _ = encode(params, tokens, cfg)
+    return lm_head_loss(params, h, targets, cfg.base, per_example=True)
+
+
+def lm_loss(params, tokens, targets, cfg: HybridConfig):
+    """Mean cross entropy over the batch."""
+    return lm_loss_per_example(params, tokens, targets, cfg).mean()
+
+
+def routing_stats(params, tokens, cfg: HybridConfig):
+    """Tokens per expert, ``(layers, n_experts)`` int32, for ``tokens``
+    (B, T) under ``params``: one forward pass, called outside the step."""
+    _, choices = encode(params, tokens, cfg)
+    return jnp.stack([expert_counts(cfg.layers[i][1], e)
+                      for i, e in enumerate(choices)])
+
+
+def place_experts(params, tokens, cfg: HybridConfig):
+    """Choose WHICH experts this chip holds, layer by layer, from the routing
+    statistics of ``tokens`` (B, T): the experts are dealt to the chips that
+    share a layer heaviest first, each to the chip with the lighter load so
+    far (as expert-parallel deployments place experts by load), and the
+    router's output columns are reordered so that this chip's are its
+    ``held`` range.  The experts' own weights are drawn alike, so the
+    reordering is the whole placement.  Without it a randomly initialised
+    router sends anything from a third to two thirds of the tokens here
+    (PERF.md section 6, PR 28).  Layers are placed in order, each on the
+    statistics the placed layers before it give.  Returns the parameters."""
+    import numpy as np
+
+    stats = jax.jit(lambda p: routing_stats(p, tokens, cfg))
+    layers = list(params["layers"])
+    with trace.span("moe.place_experts", layers=len(layers)):
+        for i, (_, ffn) in enumerate(cfg.layers):
+            counts = np.asarray(stats(dict(params, layers=layers)))[i]
+            first, n = ffn.held
+            chips = ffn.n_experts // n
+            held = [[] for _ in range(chips)]
+            for e in np.argsort(-counts, kind="stable"):
+                open_ = [c for c in range(chips) if len(held[c]) < n]
+                held[min(open_, key=lambda c: counts[held[c]].sum())].append(int(e))
+            here = first // n
+            order = sum(held[:here] + [held[here]] + held[here + 1:], [])
+            router = dict(layers[i]["moe"]["router"])
+            router["w3"] = router["w3"][:, jnp.asarray(order)]
+            layers[i] = dict(layers[i], moe=dict(layers[i]["moe"], router=router))
+    return dict(params, layers=layers)
+
+
+def publish_routing_stats(counts, cfg: HybridConfig) -> dict:
+    """Add ``counts`` (``routing_stats`` summed over any batches, already on
+    the host) to the counters ``moe.tokens_total``, ``moe.tokens_local`` and
+    ``moe.expert_load.l<layer>.e<expert>`` (held experts only); returns the
+    local share and the held experts' largest load over their mean."""
+    first, n = cfg.layers[0][1].held
+    total = float(counts.sum())
+    held = counts[:, first:first + n]
+    METRICS.increment("moe.tokens_total", total)
+    METRICS.increment("moe.tokens_local", float(held.sum()))
+    for li, row in enumerate(held):
+        for j, c in enumerate(row):
+            METRICS.increment(f"moe.expert_load.l{li}.e{first + j}", float(c))
+    per_expert = held.sum(axis=0)
+    return {"local_share": float(held.sum()) / max(total, 1.0),
+            "load_max_over_mean": float(per_expert.max())
+            / max(float(per_expert.mean()), 1e-30)}

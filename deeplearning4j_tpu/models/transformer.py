@@ -466,8 +466,30 @@ def embed_local(params, tokens, cfg: TransformerConfig,
     return (x + pos[None]).astype(cfg.dtype)
 
 
+def _per_example_xent(h_flat, t_flat, hd, cfg: TransformerConfig):
+    """Per-token cross entropy ``(N,)`` f32 of ``h_flat`` (N, D) against the
+    head ``hd`` (D, V), ``cfg.xent_chunk`` tokens at a time (the largest
+    divisor of N under it), each chunk's logits recomputed in the backward."""
+    def token_losses(h_c, t_c):
+        logits = (h_c.astype(cfg.dtype) @ hd).astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        return lse - jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+
+    n_tok, d = h_flat.shape
+    chunk = cfg.xent_chunk
+    if not chunk or n_tok <= chunk:
+        return token_losses(h_flat, t_flat)
+    while n_tok % chunk:
+        chunk -= 1
+    body = jax.checkpoint(token_losses)
+    _, per = lax.scan(lambda c, inp: (c, body(*inp)), None,
+                      (h_flat.reshape(-1, chunk, d), t_flat.reshape(-1, chunk)))
+    return per.reshape(n_tok)
+
+
 @jax.named_scope("lm_head_loss")
-def lm_head_loss(params, h, targets, cfg: TransformerConfig) -> jnp.ndarray:
+def lm_head_loss(params, h, targets, cfg: TransformerConfig,
+                 per_example: bool = False) -> jnp.ndarray:
     """Mean token cross entropy of final hidden states against targets
     (tied or separate head) — shared by the plain and pipelined paths.
 
@@ -477,12 +499,20 @@ def lm_head_loss(params, h, targets, cfg: TransformerConfig) -> jnp.ndarray:
     the backward recomputes them instead of reading a stored (B·T, V)
     tensor back from HBM.  One extra head matmul (~7% step FLOPs at
     BERT-base shapes) buys an order of magnitude less loss-layer HBM
-    traffic — the dominant bandwidth cost of big-vocab training."""
+    traffic — the dominant bandwidth cost of big-vocab training.
+
+    ``per_example=True`` returns each row's mean, ``(B,)``, from the same
+    chunks over the WHOLE batch's tokens: what a trainer built with
+    ``per_example_loss=True`` takes, so that chunking engages at the batch's
+    token count and not at one example's."""
     head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
     hd = head.astype(cfg.dtype)
     B, T, D = h.shape
     n_tok = B * T
     chunk = cfg.xent_chunk
+    if per_example:
+        return _per_example_xent(h.reshape(n_tok, D), targets.reshape(n_tok),
+                                 hd, cfg).reshape(B, T).mean(axis=1)
 
     def token_xent(h_flat, t_flat, w_flat):
         logits = (h_flat.astype(cfg.dtype) @ hd).astype(jnp.float32)

@@ -62,8 +62,9 @@ _NT = (((1,), (1,)), ((), ()))        # a @ b.T, contracting the lane axes
 #: the whole sequence of one head group sits in VMEM (q, k, v, O, dO and the
 #: three gradients double-buffered, their transposed copies, the f32 dq^T
 #: scratch): 2048 rows compile inside the 16 MiB a v5e kernel may use by
-#: default (tests/test_chip_compile.py)
-MAX_T = 2048
+#: default, 4096 rows (about 30 MiB in the backward) inside the limit
+#: ``_vmem_limit`` asks for (tests/test_chip_compile.py)
+MAX_T = 4096
 
 
 def reference_attention(q, k, v, *, causal: bool = True):
@@ -279,6 +280,15 @@ def _bwd_kernel(qt_ref, kt_ref, vt_ref, ot_ref, dot_ref, lse_ref,
     dqt_ref[0] = (dq_t_ref[...] * scale).astype(dqt_ref.dtype)
 
 
+def _vmem_limit(t: int):
+    """Mosaic parameters for a sequence past what the default VMEM budget
+    holds (of the 128 MiB a v5e core has); ``None`` up to 2048 rows, so those
+    kernels compile as they always did."""
+    if t <= 2048:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+
+
 def _layout(x, head_dim: int):
     """How both kernels walk ``(B, H*D, T)`` operands: one program per batch
     row and head group.  Returns the grid, the block spec of an operand
@@ -309,6 +319,7 @@ def _fused_fwd(q, k, v, causal, head_dim, block_q, block_k, interpret):
         out_specs=[wide, rows],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), stats],
         scratch_shapes=[pltpu.VMEM((t, lanes), q.dtype)] * 3,
+        compiler_params=_vmem_limit(t),
         interpret=interpret,
     )(q, k, v)
 
@@ -329,6 +340,7 @@ def _fused_bwd(q, k, v, out, lse, do, causal, head_dim, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((t, lanes), q.dtype)] * 4 + [
             pltpu.VMEM((lanes, t), jnp.float32),        # dq^T
             pltpu.VMEM((g, t), jnp.float32)],           # delta
+        compiler_params=_vmem_limit(t),
         interpret=interpret,
     )(q, k, v, out, do, lse)
 

@@ -140,7 +140,7 @@ class DataParallelTrainer:
     def __init__(self, loss_fn: LossFn, transform: tfm.GradientTransform,
                  mesh: Mesh | None = None, router: str = "iterative_reduce",
                  average_every: int = 8, max_pending: int = 64,
-                 zero_stage: int = 0):
+                 zero_stage: int = 0, per_example_loss: bool = False):
         if router not in ("iterative_reduce", "hogwild"):
             raise ValueError(f"unknown router {router!r}")
         if zero_stage not in (0, 1, 2, 3):
@@ -151,6 +151,9 @@ class DataParallelTrainer:
                 "keeps independent per-replica optimizer state by design, "
                 "so there is no shared state to shard")
         self.loss_fn = loss_fn
+        # True: ``loss_fn(params, x, y, key)`` returns the rows' losses (B,)
+        # for the whole local batch; False: a mean, taken row by row
+        self.per_example_loss = per_example_loss
         self.transform = transform
         self.mesh = mesh if mesh is not None else local_mesh()
         self.router = router
@@ -294,6 +297,19 @@ class DataParallelTrainer:
         return x, y, n, bucket
 
     # ------------------------------------------------------------------ steps
+    def _per_example(self, params, x, y, key):
+        """Each row's loss, ``(B,)``: through a singleton-batch vmap of a
+        ``loss_fn`` that returns a mean, or straight from one that returns
+        the rows' losses itself (``per_example_loss=True``) and so sees the
+        whole batch at once — what an expert layer that groups tokens and a
+        chunked head loss need."""
+        loss_fn = self.loss_fn
+        if self.per_example_loss:
+            return loss_fn(params, x, y, key).reshape((x.shape[0],))
+        per = jax.vmap(
+            lambda xi, yi: loss_fn(params, xi[None], yi[None], key))(x, y)
+        return per.reshape((x.shape[0],))
+
     def _masked_mean_loss(self, key_select):
         """Wrap ``loss_fn`` (a per-sample mean) into an exact masked mean:
         per-example losses via a singleton-batch vmap, zero weight for
@@ -301,13 +317,8 @@ class DataParallelTrainer:
         (per-row) losses — every loss in this repo — are exact under this
         rewrite; batch-coupled losses (cross-batch statistics) are not and
         should avoid ragged batches."""
-        loss_fn = self.loss_fn
-
         def masked(params, x, y, key, mask, denom):
-            per = jax.vmap(
-                lambda xi, yi: loss_fn(params, xi[None], yi[None],
-                                       key_select(key)))(x, y)
-            per = per.reshape((x.shape[0],))
+            per = self._per_example(params, x, y, key_select(key))
             return jnp.sum(per * mask.astype(per.dtype)) / denom.astype(per.dtype)
 
         return masked
@@ -367,7 +378,6 @@ class DataParallelTrainer:
         if z is None:
             raise RuntimeError("zero step built before init_state — the "
                                "layout comes from the param shapes")
-        loss_fn = self.loss_fn
 
         def local(params, tstate, x, y, key, iteration, n_valid):
             if stage >= 3:
@@ -383,9 +393,7 @@ class DataParallelTrainer:
             mask = rows < n_valid
 
             def local_sum(p):
-                per = jax.vmap(
-                    lambda xi, yi: loss_fn(p, xi[None], yi[None], key))(x, y)
-                per = per.reshape((x.shape[0],))
+                per = self._per_example(p, x, y, key)
                 return jnp.sum(per * mask.astype(per.dtype))
 
             # vjp with a 1/n_valid cotangent == grad of the GLOBAL masked

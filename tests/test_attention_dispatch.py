@@ -89,7 +89,8 @@ def test_lengths_the_kernel_does_not_take_fall_back_unchanged():
     ("cpu", {}, 1, 512, None),                     # the tests' backend
     ("tpu", {}, 2, 512, None),                     # the sp ring
     ("tpu", {}, 1, 448, None),                     # not whole 128-row blocks
-    ("tpu", {}, 1, 4096, None),                    # does not fit in VMEM
+    ("tpu", {}, 1, 4096, "fused"),                 # the longest it takes
+    ("tpu", {}, 1, 8192, None),                    # does not fit in VMEM
     ("tpu", {"d_model": 96, "n_heads": 3}, 1, 512, None),       # width 32
     ("tpu", {"d_model": 192, "n_heads": 3}, 1, 512, None),      # 1.5 groups
     ("tpu", {"n_kv_heads": 4}, 1, 512, None),      # GQA
@@ -97,13 +98,13 @@ def test_lengths_the_kernel_does_not_take_fall_back_unchanged():
     ("cpu", {"attention": "flash"}, 1, 512, "flash"),           # forced
     ("cpu", {"attention": "fused"}, 2, 512, None),  # forced, but the ring
 ], ids=["bert-base", "gpt2-medium", "width-128", "cpu", "sp-2", "t-448",
-        "t-4096", "width-32", "odd-heads", "gqa", "forced-ring", "forced-flash",
+        "t-4096", "t-8192", "width-32", "odd-heads", "gqa", "forced-ring", "forced-flash",
         "forced-fused-sp-2"])
 def test_attention_candidate_from_what_block_observes(monkeypatch, backend,
                                                       kw, n_sp, t, want):
     from deeplearning4j_tpu.models import transformer as tf
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    cfg = _tiny(**{"d_model": 768, "n_heads": 12, "max_len": 4096, **kw})
+    cfg = _tiny(**{"d_model": 768, "n_heads": 12, "max_len": 8192, **kw})
     assert tf._attention_candidate(cfg, n_sp, t, cfg.n_heads) == want
 
 
@@ -111,6 +112,7 @@ def test_kernel_takes():
     assert kernel_takes(512, 12, 64) and kernel_takes(1024, 3, 128)
     assert not kernel_takes(500, 12, 64) and not kernel_takes(512, 12, 80)
     assert not kernel_takes(512, 3, 64)
+    assert kernel_takes(4096, 8, 128) and not kernel_takes(4224, 8, 128)
 
 
 @pytest.mark.parametrize("zero_stage", [0, 1], ids=["dp4", "dp4-zero1"])

@@ -89,9 +89,12 @@ def _per_example(kernel):
     (fused_attention, True, (8, 1024, 16, DH)),
     (_per_example(fused_attention), False, (B, T, H, DH)),
     (fused_attention, True, (8, 2048, 8, 128)),
+    (fused_attention, True, (4, 4096, 8, 128)),
+    (fused_attention, False, (4, 4096, 12, 64)),
 ], ids=["flash-bidirectional", "fused-causal", "fused-bidirectional",
         "fused-causal-gpt2-medium", "fused-per-example-vmap",
-        "fused-causal-2048-width-128"])
+        "fused-causal-2048-width-128", "fused-causal-4096-width-128",
+        "fused-bidirectional-4096-width-64"])
 def test_attention_fwd_bwd_compiles(one_chip, kernel, causal, shape):
     hlo = _compile(
         _fwd_bwd(lambda q, k, v: kernel(q, k, v, causal=causal,
@@ -186,3 +189,44 @@ def test_int8_matmul_compiles(one_chip, k, n):
                                     interpret=False),
         one_chip, ((SLOTS, k), BF16), ((k, n), I8), ((n,), F32))
     assert "tpu_custom_call" in hlo
+
+
+def test_hybrid_block_takes_the_kernel_at_4096(one_chip, monkeypatch):
+    """One ZAYA1-width layer (CCA at 8 query heads over 2 KV heads of 128,
+    8 of 16 experts held) at 2 x 4096, forward and backward, compiled for the
+    chip as ``models/hybrid.py`` runs it there: attention is the fused kernel
+    (forward, backward, and the forward once more under ``remat``), no array
+    holds 4096 x 4096 scores, and the grouped matmuls are the compiler's
+    ragged products."""
+    import re
+
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.models.transformer import TransformerConfig
+    from deeplearning4j_tpu.observability import METRICS
+    from deeplearning4j_tpu.ops.pallas import registry
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(registry, "resolve_interpret",
+                        lambda interpret: bool(interpret))
+    t = 4096
+    base = TransformerConfig(vocab_size=2048, d_model=2048, n_heads=8,
+                             n_kv_heads=2, n_layers=1, d_ff=2048, max_len=t,
+                             causal=True, remat=True, xent_chunk=2048)
+    cfg = hybrid.HybridConfig(base=base, layers=((hybrid.CCA(), hybrid.MoE()),))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg)))
+    tokens = jax.ShapeDtypeStruct((2, t), I32, sharding=one_chip)
+    METRICS.reset()
+    hlo = jax.jit(jax.value_and_grad(
+        lambda p, x, y: hybrid.lm_loss(p, x, y, cfg))).lower(
+            params, tokens, tokens).compile().as_text()
+    counters = METRICS.snapshot()["counters"]
+    assert counters.get("attention.path.kernel") == 1
+    assert "attention.path.xla" not in counters
+    fused = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r'op_name="[^"]*attention\.fused', line)]
+    assert len(fused) == 3, len(fused)
+    assert not re.findall(rf"(?:f32|bf16)\[[0-9,]*{t},{t}\]", hlo)
+    assert "ragged-dot" in hlo
