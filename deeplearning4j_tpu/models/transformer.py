@@ -466,25 +466,98 @@ def embed_local(params, tokens, cfg: TransformerConfig,
     return (x + pos[None]).astype(cfg.dtype)
 
 
-def _per_example_xent(h_flat, t_flat, hd, cfg: TransformerConfig):
-    """Per-token cross entropy ``(N,)`` f32 of ``h_flat`` (N, D) against the
-    head ``hd`` (D, V), ``cfg.xent_chunk`` tokens at a time (the largest
-    divisor of N under it), each chunk's logits recomputed in the backward."""
-    def token_losses(h_c, t_c):
-        logits = (h_c.astype(cfg.dtype) @ hd).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        return lse - jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+def _token_xent(h_c, t_c, hd):
+    """The tokens' losses, f32, of ``h_c`` (n, D) against the cast head ``hd``
+    (D, V)."""
+    logits = (h_c.astype(hd.dtype) @ hd).astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    return lse - jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
 
+
+def _recomputed_xent(h3, t2, head, dt):
+    """The tokens' losses ``(chunks, chunk)`` f32, chunk by chunk, each
+    chunk's logits recomputed when differentiated (``jax.checkpoint``)."""
+    hd = head.astype(dt)
+    body = jax.checkpoint(lambda h_c, t_c: _token_xent(h_c, t_c, hd))
+    return lax.scan(lambda c, inp: (c, body(*inp)), None, (h3, t2))[1]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _fused_xent(h3, t2, head, dt):
+    """``_recomputed_xent`` whose gradients are made while each chunk's
+    logits exist: differentiated, the forward scan also computes, under a
+    unit cotangent, ``dh`` (a residual in ``dt``) and the head's ``dW``
+    (one f32 buffer of the head's shape), and the backward scales them by the
+    tokens' cotangent ``g``.  ``dh`` takes any ``g``, token by token; ``dW``
+    only one that is the same number for every token, which a mean over full
+    batches gives.  Any other ``g`` (a padded batch) recomputes the chunks and
+    pulls ``g`` back through them, as ``_recomputed_xent`` differentiated."""
+    return _recomputed_xent(h3, t2, head, dt)
+
+
+def _fused_xent_fwd(h3, t2, head, dt):
+    hd = head.astype(dt)
+
+    def body(dw, inp):
+        h_c, t_c = inp
+        h_c = h_c.astype(dt)
+        # _token_xent's numbers, with the targets' logits gathered BEFORE the
+        # cast (the same values): the product then leaves no f32 copy of the
+        # chunk's logits behind for the gather alone
+        logits = h_c @ hd
+        gold = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+        logits = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        # d(losses)/d(logits): rounded where the recompute's backward rounds it
+        p = jnp.exp(logits - lse[:, None])
+        p = (p - (lax.broadcasted_iota(jnp.int32, p.shape, 1) == t_c[:, None])
+             ).astype(dt)
+        dw = dw + jnp.dot(h_c.T, p, preferred_element_type=jnp.float32)
+        return dw, (lse - gold.astype(jnp.float32), p @ hd.T)
+
+    with jax.named_scope("lm_head.fused"):
+        dw, (losses, dh) = lax.scan(
+            body, jnp.zeros(head.shape, jnp.float32), (h3, t2))
+    return losses, (h3, t2, head, dh, dw)
+
+
+def _fused_xent_bwd(dt, res, g):
+    h3, t2, head, dh, dw = res
+    g0 = g[0, 0]
+
+    def scaled():
+        with jax.named_scope("lm_head.fused"):
+            return (g0 * dw).astype(head.dtype)
+
+    def recomputed():
+        with jax.named_scope("lm_head.recompute"):
+            return jax.vjp(lambda w: _recomputed_xent(h3, t2, w, dt), head)[1](g)[0]
+
+    with jax.named_scope("lm_head.fused"):
+        d_h = (dh * g[..., None]).astype(h3.dtype)
+    return d_h, None, lax.cond(jnp.all(g == g0), scaled, recomputed)
+
+
+_fused_xent.defvjp(_fused_xent_fwd, _fused_xent_bwd)
+
+
+def _per_example_xent(h_flat, t_flat, head, cfg: TransformerConfig):
+    """Per-token cross entropy ``(N,)`` f32 of ``h_flat`` (N, D) against the
+    head ``head`` (D, V, cast to ``cfg.dtype`` here), ``cfg.xent_chunk``
+    tokens at a time (the largest divisor of N under it) through
+    ``_fused_xent``; N tokens or fewer than a chunk at once."""
     n_tok, d = h_flat.shape
     chunk = cfg.xent_chunk
     if not chunk or n_tok <= chunk:
-        return token_losses(h_flat, t_flat)
+        METRICS.increment("lm_head_loss.path.plain")
+        return _token_xent(h_flat, t_flat, head.astype(cfg.dtype))
     while n_tok % chunk:
         chunk -= 1
-    body = jax.checkpoint(token_losses)
-    _, per = lax.scan(lambda c, inp: (c, body(*inp)), None,
-                      (h_flat.reshape(-1, chunk, d), t_flat.reshape(-1, chunk)))
-    return per.reshape(n_tok)
+    # counted while tracing, as attention.path.*: the chunked loss makes its
+    # gradients in the forward pass
+    METRICS.increment("lm_head_loss.path.fused")
+    return _fused_xent(h_flat.reshape(-1, chunk, d), t_flat.reshape(-1, chunk),
+                       head, cfg.dtype).reshape(n_tok)
 
 
 @jax.named_scope("lm_head_loss")
@@ -504,7 +577,16 @@ def lm_head_loss(params, h, targets, cfg: TransformerConfig,
     ``per_example=True`` returns each row's mean, ``(B,)``, from the same
     chunks over the WHOLE batch's tokens: what a trainer built with
     ``per_example_loss=True`` takes, so that chunking engages at the batch's
-    token count and not at one example's."""
+    token count and not at one example's.  Differentiated, that path does
+    NOT recompute (``_fused_xent``): the forward scan makes, while a chunk's
+    logits exist, the chunk's ``dh`` and the head's ``dW`` under a unit
+    cotangent, and stores them (``dh`` (B*T, D) in ``cfg.dtype``, ``dW`` one
+    f32 buffer of the head's shape) in place of the cast head; the backward
+    scales them.  ``dW`` can be scaled only by one number, so a ``lax.cond``
+    takes the stored one when the rows' cotangents are all equal (a mean over
+    a full batch) and otherwise recomputes chunk by chunk as the mean path
+    does (a padded batch).  "The backward recomputes them" above holds for
+    the mean path and for that fallback only."""
     head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
     hd = head.astype(cfg.dtype)
     B, T, D = h.shape
@@ -512,7 +594,7 @@ def lm_head_loss(params, h, targets, cfg: TransformerConfig,
     chunk = cfg.xent_chunk
     if per_example:
         return _per_example_xent(h.reshape(n_tok, D), targets.reshape(n_tok),
-                                 hd, cfg).reshape(B, T).mean(axis=1)
+                                 head, cfg).reshape(B, T).mean(axis=1)
 
     def token_xent(h_flat, t_flat, w_flat):
         logits = (h_flat.astype(cfg.dtype) @ hd).astype(jnp.float32)

@@ -235,6 +235,96 @@ def test_per_example_head_loss_is_the_mean_loss(chunk):
     np.testing.assert_allclose(g1["tok_embed"], g2["tok_embed"], atol=1e-6)
 
 
+def head_case(dtype, chunk):
+    cfg = config(xent_chunk=chunk, dtype=dtype).base
+    params = {"tok_embed": 0.5 * jax.random.normal(jax.random.key(0), (V, E))}
+    h = jax.random.normal(jax.random.key(1), (4, SEQ, E))
+    tgts = jax.random.randint(jax.random.key(2), (4, SEQ), 0, V)
+    return cfg, params, h, tgts
+
+
+def weighted_head_loss(cfg, tgts):
+    """``(loss, (d tok_embed, d h))`` of the rows' losses under the weights
+    ``w``, which arrive at run time as the trainer's ``mask / n_valid`` does."""
+    return jax.jit(jax.value_and_grad(
+        lambda p, h, w: (lm_head_loss(p, h, tgts, cfg, per_example=True) * w).sum(),
+        (0, 1)))
+
+
+COTANGENTS = {"equal": np.full(4, 0.25, np.float32),
+              "padded": np.array([1, 1, 1, 0], np.float32) / 3,
+              "random": np.asarray(jax.random.normal(jax.random.key(3), (4,)))}
+
+
+@pytest.mark.parametrize("weights", COTANGENTS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_chunked_head_loss_takes_any_cotangent(dtype, weights):
+    """The chunked per-example loss makes the head's gradients in its forward
+    scan and scales them when the rows' cotangents are equal (a mean over a
+    full batch), and recomputes the chunks when they are not (a padded batch,
+    any other weights): loss and both gradients are the unchunked path's."""
+    w = jnp.asarray(COTANGENTS[weights])
+    cfg, params, h, tgts = head_case(dtype, 16)
+    loss, (d_p, d_h) = weighted_head_loss(cfg, tgts)(params, h, w)
+    want, (w_p, w_h) = weighted_head_loss(
+        dataclasses.replace(cfg, xent_chunk=0), tgts)(params, h, w)
+    got = (loss, d_p["tok_embed"], d_h)
+    want = (want, w_p["tok_embed"], w_h)
+    assert all(a.dtype == b.dtype for a, b in zip(got, want))
+    if dtype == jnp.float32:
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    else:
+        for a, b in zip(got, want):
+            assert (np.linalg.norm(np.asarray(a - b, np.float32))
+                    <= 0.01 * np.linalg.norm(np.asarray(b, np.float32)))
+
+
+@pytest.mark.parametrize("chunk,path", [(16, "fused"), (0, "plain"),
+                                        (4096, "plain")])
+def test_head_loss_counts_its_path_once_a_trace(chunk, path):
+    cfg, params, h, tgts = head_case(jnp.float32, chunk)
+    step = weighted_head_loss(cfg, tgts)
+    counts = lambda: {k: METRICS.snapshot()["counters"].get(
+        f"lm_head_loss.path.{k}", 0) for k in ("fused", "plain")}
+    before = counts()
+    for _ in range(2):                      # the second call traces nothing
+        step(params, h, jnp.full(4, 0.25))
+    after = counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        "fused": int(path == "fused"), "plain": int(path == "plain")}
+
+
+def head_products(jaxpr, branch=None):
+    """How many ``dot_general``s with the vocabulary on a side ``jaxpr`` holds,
+    sub-programs included; of a ``cond`` the one ``branch``, or none of it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            n += any(V in v.aval.shape for v in (*eqn.invars, *eqn.outvars))
+        if eqn.primitive.name != "cond":
+            subs = jax.core.jaxprs_in_params(eqn.params)
+        else:
+            subs = [] if branch is None else [eqn.params["branches"][branch].jaxpr]
+        n += sum(head_products(sub, branch) for sub in subs)
+    return n
+
+
+def test_equal_cotangents_cost_three_head_products_a_chunk():
+    """Forward scan: logits, ``dh`` and ``dW`` of each chunk, and nothing of
+    the head's shape on the equal-cotangent side of the backward; the other
+    side recomputes the logits and makes ``dW`` again (the unfused chunked
+    loss needs four: the logits twice)."""
+    cfg, params, h, tgts = head_case(jnp.bfloat16, 16)
+    jaxpr = jax.make_jaxpr(weighted_head_loss(cfg, tgts))(
+        params, h, jnp.full(4, 0.25)).jaxpr
+    assert head_products(jaxpr) == 3
+    # lax.cond(pred, scaled, recomputed) lists the false branch first
+    assert head_products(jaxpr, branch=1) == 3
+    assert head_products(jaxpr, branch=0) >= 3 + 2
+
+
 def test_loss_is_the_same_row_by_row_and_as_a_whole_batch(case):
     """What the trainer's singleton ``vmap`` would compute for each row (one
     example's tokens grouped alone; ``lax.ragged_dot`` itself has no batching
