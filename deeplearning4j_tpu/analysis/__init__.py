@@ -9,7 +9,7 @@ Two halves of one contract (DESIGN.md §11):
   (HOT02) — plus per-line suppressions and a committed baseline so
   ``python -m tools.graftlint --check`` can gate every PR on *new*
   violations only.
-- **runtime**: ``runtime.hot_loop_guard()`` wraps the trainer/bench hot
+- **runtime**: ``runtime.hot_loop_guard()`` wraps the trainer's hot
   loops in ``jax.transfer_guard("disallow")`` so implicit transfers fail
   loudly at the call site (opt out: ``DL4J_TPU_TRANSFER_GUARD=0``),
   ``lockguard.LOCKGUARD`` instruments ``threading`` locks to detect

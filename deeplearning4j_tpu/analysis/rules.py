@@ -2044,7 +2044,7 @@ class UnstableReductionRule(Rule):
     moment one logit exceeds ~88 (f32) or ~11 (bf16) — which real logits
     do.  The sanctioned implementations in this tree are the blocked-
     xent kernel (``ops/pallas/xent.py``), the online-softmax attention
-    kernels (``ops/flash_attention.py``, ``ops/pallas/attention.py``)
+    kernels (``ops/pallas/attention.py``, ``ops/pallas/paged_attention.py``)
     and ``jax.scipy.special.logsumexp`` / ``jax.nn.softmax`` — all of
     which subtract a running or global max first.  Scoped to ``ops/``
     and ``models/``, the rule fires on three shapes: direct

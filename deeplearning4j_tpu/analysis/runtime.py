@@ -1,7 +1,7 @@
 """Runtime enforcement: jax.transfer_guard scopes for hot loops.
 
 The static rules catch the *patterns*; this module catches the *behavior*:
-hot loops (trainer steady state, bench legs, perf smoke) run under
+hot loops (trainer steady state, perf smoke) run under
 ``jax.transfer_guard("disallow")``, so any IMPLICIT host<->device transfer
 — a numpy batch leaking into a jitted call, a Python scalar materialized
 per step, a stray ``float(loss)`` on a real accelerator — raises at the

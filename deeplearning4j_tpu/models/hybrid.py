@@ -164,18 +164,17 @@ def cca_qkv(spec: CCA, p, u, dt, *, convs=True, qk_mean=True, value_shift=True,
 
 def _attend(q, k, v):
     """Causal attention of ``q (B, T, H, d)`` over ``k, v (B, T, G, d)``:
-    the registry's fused kernel on a TPU for the shapes it takes, the XLA
-    path otherwise; counted like ``transformer._block`` counts its side."""
+    the candidate ``attention_candidate`` answers for the shape (the fused
+    kernel on a TPU for the shapes it takes), the XLA path otherwise."""
     from ..ops.pallas import registry as kernel_registry
-    from ..ops.pallas.attention import kernel_takes
+    from ..ops.pallas.attention import attention_candidate
     from .transformer import repeat_kv_heads, ring_attention
 
     t, h, d = q.shape[1:]
     k, v = repeat_kv_heads(k, h // k.shape[2]), repeat_kv_heads(v, h // v.shape[2])
-    on = jax.default_backend() == "tpu" and kernel_takes(t, h, d)
-    METRICS.increment("attention.path.kernel" if on else "attention.path.xla")
-    if on:
-        return kernel_registry.get("attention", "fused").fn(q, k, v, causal=True)
+    name = attention_candidate(t, h, d)
+    if name:
+        return kernel_registry.get("attention", name).fn(q, k, v, causal=True)
     return ring_attention(q, k, v, n_sp=1, sp_axis=None, causal=True, t_local=t)
 
 

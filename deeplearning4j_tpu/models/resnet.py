@@ -50,9 +50,8 @@ class ResNetConfig:
 
     def flops_per_image(self, image_size: int = 224) -> float:
         """Analytic training FLOPs per image (2*MACs forward, ×3 for
-        fwd+bwd), counting convs + the classifier matmul.  Used for MFU
-        accounting in bench.py (same 2*MACs convention the transformer leg
-        validates against XLA ``cost_analysis()`` there)."""
+        fwd+bwd), counting convs + the classifier matmul: the 2*MACs
+        convention of ``TransformerConfig.flops_per_token``."""
         def conv_flops(hw, k, cin, cout, stride):
             out_hw = hw // stride
             return 2.0 * out_hw * out_hw * k * k * cin * cout, out_hw

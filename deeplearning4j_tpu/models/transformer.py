@@ -54,23 +54,26 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16        # MXU compute dtype
     param_dtype: Any = jnp.float32
     remat: bool = True               # jax.checkpoint each block (HBM for FLOPs)
-    attention: str = "auto"          # "auto" (default): the fused Pallas
-    #                                  kernel on a TPU for the shapes it
-    #                                  takes, the XLA ring path otherwise
-    #                                  (_attention_candidate) | "ring":
-    #                                  always the XLA path | a registered
-    #                                  ops/pallas candidate ("fused",
-    #                                  "flash"): that kernel, single-shard
-    #                                  only — how a parity test forces a side
+    attention: str = "auto"          # "auto": the fused Pallas kernel on a
+    #                                  TPU for the shapes it takes, the XLA
+    #                                  ring path otherwise (ops/pallas/
+    #                                  attention.attention_candidate) |
+    #                                  "ring": always the XLA path |
+    #                                  "fused": that kernel, single-shard
+    #                                  only.  No production caller sets it:
+    #                                  it is how a parity test forces a side
     fused_ln: bool = False           # fuse the mid-block residual+LN seam
     #                                  through ops/pallas/layernorm — one
     #                                  VMEM pass instead of two HBM
-    #                                  round-trips; bench-gated opt-in
+    #                                  round-trips; default off, unmeasured
+    #                                  on the chip; ROADMAP D1b
     xent_impl: str = "scan"          # "scan" (chunked lax.scan, default) |
     #                                  "blocked" (ops/pallas/xent streaming
     #                                  kernel for ALL chunked cases; the
     #                                  near-prime fallback always streams
-    #                                  through the blocked kernel)
+    #                                  through the blocked kernel); default
+    #                                  off, unmeasured on the chip; ROADMAP
+    #                                  D1b
     xent_chunk: int = 2048           # LM-loss token-chunk size; 0 disables.
     #                                  Full (B*T, V) f32 logits are the
     #                                  biggest HBM tensor in training (4.3 GB
@@ -375,19 +378,16 @@ def _ffn(lp, h, dt):
 
 def _attention_candidate(cfg: TransformerConfig, n_sp: int, t_local: int,
                          n_heads_local: int) -> str | None:
-    """The registered ops/pallas attention candidate ``_block`` runs, from
-    what it can observe, or ``None`` for ``ring_attention``: the sp ring is
-    the collective and never a candidate, and by default the kernel runs
-    where it compiles (a TPU backend) on the shapes it is built for."""
-    if cfg.attention == "ring" or n_sp != 1:
-        return None
-    if cfg.attention == "auto":
-        from ..ops.pallas.attention import kernel_takes
-        on = (jax.default_backend() == "tpu"
-              and cfg.kv_heads == cfg.n_heads
-              and kernel_takes(t_local, n_heads_local, cfg.head_dim))
-        return "fused" if on else None
-    return cfg.attention if t_local % 128 == 0 else None
+    """The registered ops/pallas attention candidate ``_block`` runs, or
+    ``None`` for ``ring_attention``: what ``attention_candidate`` answers
+    for this block's shapes.  Dense GQA keeps the XLA path by default (no
+    cell has measured it on the kernel)."""
+    from ..ops.pallas.attention import attention_candidate
+    asked = cfg.attention
+    if asked == "auto" and cfg.kv_heads != cfg.n_heads:
+        asked = "ring"
+    return attention_candidate(t_local, n_heads_local, cfg.head_dim,
+                               n_sp=n_sp, asked=asked)
 
 
 def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
@@ -408,10 +408,6 @@ def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
                 "GQA (n_kv_heads < n_heads) does not shard over tp")
             k = repeat_kv_heads(k, q.shape[-2] // k.shape[-2])
             v = repeat_kv_heads(v, q.shape[-2] // v.shape[-2])
-        # counted while tracing, once per block and compilation: tells a
-        # step on the kernel from one that fell back
-        METRICS.increment(
-            "attention.path.kernel" if name else "attention.path.xla")
         if name:
             from ..ops.pallas import registry as kernel_registry
             attn = kernel_registry.get("attention", name).fn(
@@ -420,7 +416,7 @@ def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
             attn = ring_attention(q, k, v, n_sp=n_sp, sp_axis=sp_axis,
                                   causal=cfg.causal, t_local=t_local)
     # one VMEM pass for the mid-block residual-add + LayerNorm seam
-    # (bench-gated opt-in; under tp the unfused path keeps the copy_to_tp
+    # (default off, unmeasured; under tp the unfused path keeps the copy_to_tp
     # placement below untouched)
     fuse_ln = cfg.fused_ln and not tp_axis
     with jax.named_scope("attn_out"):
@@ -852,7 +848,7 @@ def scatter_paged_layer(c, flat, k, v) -> dict:
     absmax scales, so untouched pages round-trip byte-identically and
     only the written page can re-round.  This jnp path is the parity
     REFERENCE; the streamed ``paged_attention_int8`` kernel is the perf
-    path behind the autopick gate."""
+    path, default off, unmeasured."""
     if "k_scale" not in c:
         return {
             key: c[key].reshape((-1,) + c[key].shape[2:]).at[flat].set(
@@ -885,8 +881,8 @@ def decode_step_paged(params, pages, block_tables, tokens, pos,
     bitwise parity.  ``attn_fn`` optionally swaps the gather+softmax read
     for a registry candidate ``(q, k_pages, v_pages, block_tables,
     lengths) -> (B, H, Dh)`` (``(q, k_pages, v_pages, k_scale, v_scale,
-    block_tables, lengths)`` for quantized pools — the bench-autopick
-    perf path).  Returns ``(logits (B, V) f32, new_pages)``."""
+    block_tables, lengths)`` for quantized pools — default off,
+    unmeasured).  Returns ``(logits (B, V) f32, new_pages)``."""
     dt = cfg.dtype
     ps = pages[0]["k"].shape[1]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape)  # (B,)

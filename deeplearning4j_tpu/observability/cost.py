@@ -10,8 +10,7 @@ result publishes live utilization gauges:
     ``{prefix}.mfu``  = flops / (seconds * peak_flops)
     ``{prefix}.mbu``  = bytes_accessed / (seconds * peak_bytes_per_s)
 
-the same accounting bench.py reports, so artifact and ``/metrics.prom``
-agree.  Peaks come from :data:`PEAKS`, the repo's one table, keyed by the
+Peaks come from :data:`PEAKS`, the repo's one table, keyed by the
 exact ``device_kind``; an unknown kind publishes no utilization gauge.
 Caveats (see DESIGN.md §18): some backends return no ``cost_analysis`` or
 report ``flops <= 0`` ("unknown"); ``capture`` then falls back to a
@@ -45,7 +44,7 @@ class DevicePeak:
 #: The repo's ONE peak table, keyed by the exact ``device_kind`` JAX
 #: reports (``jax.devices()[0].device_kind``).  A kind that is not here has
 #: no utilization: the library publishes no ``*.mfu``/``*.mbu`` gauge for
-#: it, and ``bench.py``/``chip_smoke.py`` treat it as an error.
+#: it, and ``chip_smoke.py`` treats it as an error.
 PEAKS: dict[str, DevicePeak] = {
     "TPU v5 lite": DevicePeak(
         flops=197e12, bytes_per_s=819e9, hbm_bytes=16e9,

@@ -16,8 +16,7 @@ the fleet view on top of three primitives:
   fleet rollups (``fleet.tokens_per_sec``, ``fleet.kv_pages_in_use``,
   ``fleet.queue_depth``, ``fleet.tokens_total``) plus per-replica
   min/median/max spreads into the *normal* registry — so
-  ``TimeSeriesStore``, ``SLOEvaluator``, ``perf_gate`` and the flight
-  recorder see the whole fleet without learning anything new.  The pool
+  ``TimeSeriesStore``, ``SLOEvaluator`` and the flight recorder see the whole fleet without learning anything new.  The pool
   is duck-typed (``names()`` / ``is_active()`` / ``replica()``) so this
   module never imports the serving tier.  Replica clocks are never
   trusted: staleness is judged purely by the *local* receive time of the

@@ -1,19 +1,10 @@
-"""Kernel-tier observability: per-kernel timing histograms and the
-bench auto-pick gauges.
+"""Kernel-tier observability: per-kernel timing histograms.
 
-Two thin publication shims over the global ``METRICS`` registry so the
-kernel tier (``ops/pallas``) and the bench pick chain never import
-histogram internals:
-
-- ``record_kernel_time`` — one wall-clock observation per kernel call
-  (``kernel.<kind>.<name>`` histogram) plus an optional bytes-moved
-  gauge, fed by ``tools/kernel_smoke.py`` and any harness that times a
-  dispatched kernel.
-- ``publish_autopick`` — every :class:`ops.pallas.registry.Pick` lands
-  as ``bench.autopick.<kind>.*`` gauges (candidates considered, dropped,
-  whether a non-incumbent was adopted) and a decisions counter, so a
-  dashboard shows at a glance which kernels production actually runs
-  and how many candidates the gate rejected.
+``record_kernel_time`` is one wall-clock observation per kernel call
+(``kernel.<kind>.<name>`` histogram) plus an optional bytes-moved gauge,
+fed by ``tools/kernel_smoke.py`` and any harness that times a dispatched
+kernel, so the kernel tier (``ops/pallas``) never imports histogram
+internals.
 """
 
 from __future__ import annotations
@@ -36,13 +27,3 @@ def record_kernel_time(kind: str, name: str, seconds: float,
         METRICS.gauge(f"{metric}.bytes_per_call", bytes_moved)
         if seconds > 0:
             METRICS.gauge(f"{metric}.gbps", bytes_moved / seconds / 1e9)
-
-
-def publish_autopick(pick) -> None:
-    """Export one auto-pick decision (a ``registry.Pick``) as gauges."""
-    base = f"bench.autopick.{pick.kind}"
-    METRICS.gauge(f"{base}.candidates", pick.considered)
-    METRICS.gauge(f"{base}.dropped", len(pick.dropped))
-    METRICS.gauge(f"{base}.adopted", 0.0 if pick.reason.startswith("default")
-                  else 1.0)
-    METRICS.increment("bench.autopick.decisions")

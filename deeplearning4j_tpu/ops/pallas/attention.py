@@ -1,7 +1,8 @@
 """Fused attention, forward and backward, with the scores kept in VMEM.
 
-The training default on a TPU (``models/transformer._block`` takes it for
-every shape ``kernel_takes`` accepts): the XLA path writes the
+The training default on a TPU (``attention_candidate`` gives it to
+``models/transformer._block`` and ``models/hybrid.cca_mixer`` for every
+shape ``kernel_takes`` accepts): the XLA path writes the
 ``(B, H, T, T)`` f32 scores of every layer to HBM and reads them back a
 dozen times over forward and backward; these kernels hold one
 ``(block_q, block_k)`` tile of them at a time and write no ``(…, T, T)``
@@ -53,8 +54,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..flash_attention import vmem_spec
+from ...observability import METRICS
 from . import registry
+from .vmem import vmem_spec
 
 _NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))        # a @ b.T, contracting the lane axes
@@ -85,9 +87,35 @@ def reference_attention(q, k, v, *, causal: bool = True):
 def kernel_takes(t: int, h: int, d: int) -> bool:
     """Shapes the compiled kernel is built and measured for: whole 128-row
     blocks, a sequence that fits in VMEM, heads that tile groups of 128
-    features.  ``_block`` keeps the XLA path for everything else."""
+    features."""
     return (t % 128 == 0 and t <= MAX_T and d in (64, 128)
             and (h * d) % 128 == 0)
+
+
+def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
+                        asked: str = "auto") -> str | None:
+    """The registered attention candidate a block runs on ``(B, t, h, d)``
+    queries, or ``None`` for the XLA path (``ring_attention``) — the one
+    place that decides, for every block family.  By default the fused
+    kernel runs where it compiles (a TPU backend) on the shapes it is built
+    for.  ``asked="ring"`` is always the XLA path; a registered name is that
+    kernel wherever whole 128-row blocks allow it, interpreted on the CPU:
+    how a parity test forces a side.  The sp ring is the collective and
+    never a candidate.
+
+    Counts its answer as ``attention.path.kernel`` / ``.xla``: callers ask
+    once per block while tracing, so the counters tell a step on the kernel
+    from one that fell back."""
+    if asked == "ring" or n_sp != 1:
+        name = None
+    elif asked == "auto":
+        on = jax.default_backend() == "tpu" and kernel_takes(t, h, d)
+        name = "fused" if on else None
+    else:
+        name = asked if t % 128 == 0 else None
+    METRICS.increment(
+        "attention.path.kernel" if name else "attention.path.xla")
+    return name
 
 
 def _block_size(t: int) -> int:
@@ -370,9 +398,8 @@ def fused_attention(q, k, v, *, causal: bool = True,
                     interpret: bool | None = None):
     """Fused attention for (B, T, H, D) tensors (the transformer's layout).
 
-    Block sizes follow the shape unless given (the tune battery sweeps
-    them); ``interpret=None`` resolves through
-    ``registry.resolve_interpret``.
+    Block sizes follow the shape unless given (the tests sweep them);
+    ``interpret=None`` resolves through ``registry.resolve_interpret``.
     """
     interpret = registry.resolve_interpret(interpret)
     b, t, h, d = q.shape
@@ -398,12 +425,7 @@ def _ring_single_shard(q, k, v, *, causal: bool = True, **_):
 registry.register(registry.KernelCandidate(
     kind="attention", name="fused", fn=fused_attention,
     reference=reference_attention,
-    blocks=({"block_q": 128, "block_k": 128},
-            {"block_q": 256, "block_k": 128},
-            {"block_q": 128, "block_k": 256},
-            {"block_q": 256, "block_k": 256}),
-    # fwd/bwd max abs error vs reference_attention on the battery shapes
-    # (f32; matches the flash_check gate bench has always applied)
+    # fwd/bwd max abs error vs reference_attention
     tolerances={"max_err": 0.05},
 ))
 

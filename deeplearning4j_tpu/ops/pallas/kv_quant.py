@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 #: fp8 storage rides the same seam as int8 but only exists when the
 #: installed jax exposes float8_e4m3fn — and is gated off by default
-#: either way (adoption goes through the bench autopick agreement gate)
+#: either way (unmeasured on the chip)
 _FP8 = getattr(jnp, "float8_e4m3fn", None)
 
 #: kv_quant modes ServingConfig accepts on this build

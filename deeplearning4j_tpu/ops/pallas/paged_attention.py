@@ -23,13 +23,13 @@ Two axes ride this read path (DESIGN.md §20):
   kv_quant.py``); the kernel dequantizes each page inside the same
   streamed read — one broadcast multiply on the block it DMA'd anyway.
 
-Like every kernel in this tier both kinds enter production only through
-the bench auto-pick gate: :func:`reference_paged_attention` /
+Both kinds are default off and unmeasured on the chip:
+:func:`reference_paged_attention` /
 :func:`reference_paged_attention_int8` (pure jnp, the same gather the
 engine's parity path uses) are both the incumbent candidates
-("gather"/"gather_int8", source="xla") and the correctness references
-the TUNE battery checks the Pallas candidates against — the int8 kind
-additionally gated on the ≥0.999 token top-1-agreement floor.
+("gather"/"gather_int8", source="xla") and the references a tier-1
+test holds the Pallas candidates' declared tolerances to — the int8
+kind with a ≥0.999 token top-1-agreement floor beside its error bound.
 """
 
 from __future__ import annotations
@@ -40,10 +40,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from ..flash_attention import pltpu, vmem_spec
+from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
+from .vmem import vmem_spec
 
 _NEG_INF = -1e30
 
@@ -266,7 +266,6 @@ def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
 registry.register(registry.KernelCandidate(
     kind="paged_attention", name="pallas", fn=paged_attention,
     reference=reference_paged_attention,
-    blocks=({},),              # the page size IS the block: nothing to sweep
     tolerances={"max_err": 0.05},
 ))
 
@@ -278,10 +277,9 @@ registry.register(registry.KernelCandidate(
 registry.register(registry.KernelCandidate(
     kind="paged_attention_int8", name="pallas_int8", fn=paged_attention_int8,
     reference=reference_paged_attention_int8,
-    blocks=({},),
     # same numeric band as the float kind, PLUS the served-token
-    # agreement floor the int8 weight path already enforces: autopick
-    # cannot adopt a cache precision that flips >1/1000 greedy tokens
+    # agreement floor the int8 weight path declares: a cache precision
+    # may not flip more than 1/1000 greedy tokens
     tolerances={"max_err": 0.05, "min": {"top1_agree": 0.999}},
 ))
 

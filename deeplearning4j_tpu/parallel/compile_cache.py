@@ -4,7 +4,7 @@ Large jitted programs (the BERT-base train step, every serving prefill
 bucket) pay tens of seconds of trace+lower+compile on first call.  JAX
 ships a persistent on-disk compilation cache that skips that cost across
 process restarts; this module is the single place the repo turns it on, so
-the trainer, ``InferenceEngine``, ``MultiLayerNetwork``, ``bench.py`` and
+the trainer, ``InferenceEngine``, ``MultiLayerNetwork`` and
 ``chip_smoke.py`` all share one policy:
 
 - ``JAX_COMPILATION_CACHE_DIR`` is set: JAX itself reads it, the cache

@@ -37,7 +37,7 @@ class PodSliceSpec:
     a named slice of an accelerator type in a zone."""
 
     name: str = "dl4j-tpu-slice"
-    accelerator_type: str = "v5litepod-64"   # BASELINE.md scaling target
+    accelerator_type: str = "v5litepod-64"   # BASELINE.json's scaling target
     zone: str = "us-west4-a"
     runtime_version: str = "tpu-ubuntu2204-base"
     project: str | None = None

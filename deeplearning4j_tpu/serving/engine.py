@@ -105,8 +105,8 @@ class ServingConfig:
     speculative: bool = False       # draft-proposes / target-verifies decode
     spec_k: int = 3                 # draft tokens proposed per verify window
     paged_attention_impl: str = "gather"  # "gather" (jnp, bitwise) or a
-    #                                 registry candidate name — only adopt a
-    #                                 kernel through the bench autopick gate
+    #                                 registry candidate name — default off,
+    #                                 unmeasured
     kv_quant: str | None = None     # KV-page storage precision (DESIGN.md
     #                                 §20): None = model dtype (bitwise),
     #                                 "int8" = per-page per-head absmax int8
@@ -434,8 +434,7 @@ class InferenceEngine:
     def _paged_attn_fn(self):
         """The paged-attention read the step uses: None selects the
         bitwise jnp gather path; any other name resolves a registry
-        candidate — which only config written by the bench autopick gate
-        (TUNE evidence + tolerance + margin) should ever select."""
+        candidate — default off, unmeasured."""
         impl = self.cfg.paged_attention_impl
         if impl == "gather":
             return None

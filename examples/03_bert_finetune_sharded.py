@@ -1,6 +1,6 @@
 """Fine-tune a transformer classifier over an explicit SPMD device mesh.
 
-The BERT-fine-tune north star (BASELINE.md) in miniature: build a
+The BERT-fine-tune north star (BASELINE.json) in miniature: build a
 bidirectional transformer, attach a classification head, and run the
 AdamW fine-tune step jitted over a (dp, sp, tp) mesh — the same program
 shape the framework uses on a TPU pod slice. Here the mesh is 8 virtual

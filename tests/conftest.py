@@ -7,7 +7,8 @@ collectives layer is exercised on one host with
 
 The suite pins the CPU: ``JAX_PLATFORMS=cpu`` and the device-count flag
 are exported (so subprocesses inherit them) before jax is imported.  The
-chip is driven by ``chip_smoke.py`` and ``bench.py``, never from here.
+chip is driven by ``chip_smoke.py`` and ``benchmark/run.py``, never from
+here.
 
 The persistent compilation cache is switched off through JAX's own flag
 (exported for subprocesses too), so no test writes into the checkout's

@@ -1,4 +1,5 @@
-"""``_block``'s choice between the fused attention kernel and the XLA path,
+"""The choice between the fused attention kernel and the XLA path, made in one
+place (``ops.pallas.attention.attention_candidate``) for both block families
 from what it can observe: backend, ``n_sp``, length, head width.  On the CPU
 backend these tests run on, the default is the XLA path, bit for bit; the
 kernel is forced (interpret mode) through ``TransformerConfig.attention``."""
@@ -8,7 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.ops.pallas.attention import kernel_takes
+from deeplearning4j_tpu.ops.pallas import attention as pallas_attention
+from deeplearning4j_tpu.ops.pallas.attention import (attention_candidate,
+                                                     kernel_takes)
 
 
 def _tiny(**kw):
@@ -95,17 +98,64 @@ def test_lengths_the_kernel_does_not_take_fall_back_unchanged():
     ("tpu", {"d_model": 192, "n_heads": 3}, 1, 512, None),      # 1.5 groups
     ("tpu", {"n_kv_heads": 4}, 1, 512, None),      # GQA
     ("tpu", {"attention": "ring"}, 1, 512, None),  # forced XLA
-    ("cpu", {"attention": "flash"}, 1, 512, "flash"),           # forced
+    ("cpu", {"attention": "fused"}, 1, 512, "fused"),           # forced
     ("cpu", {"attention": "fused"}, 2, 512, None),  # forced, but the ring
 ], ids=["bert-base", "gpt2-medium", "width-128", "cpu", "sp-2", "t-448",
-        "t-4096", "t-8192", "width-32", "odd-heads", "gqa", "forced-ring", "forced-flash",
-        "forced-fused-sp-2"])
+        "t-4096", "t-8192", "width-32", "odd-heads", "gqa", "forced-ring",
+        "forced-fused", "forced-fused-sp-2"])
 def test_attention_candidate_from_what_block_observes(monkeypatch, backend,
                                                       kw, n_sp, t, want):
     from deeplearning4j_tpu.models import transformer as tf
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = _tiny(**{"d_model": 768, "n_heads": 12, "max_len": 8192, **kw})
     assert tf._attention_candidate(cfg, n_sp, t, cfg.n_heads) == want
+
+
+@pytest.mark.parametrize("backend,t,want", [
+    ("tpu", 4096, "fused"), ("cpu", 4096, None), ("tpu", 8192, None),
+], ids=["tpu-4096", "cpu-4096", "tpu-8192"])
+def test_attention_candidate_at_the_zaya_shapes(monkeypatch, backend, t, want):
+    """``hybrid``'s question, 8 heads of width 128: the kernel on a TPU up
+    to the 4096 rows that fit in VMEM, the XLA path elsewhere — and the
+    answer is counted where it is given."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert attention_candidate(t, 8, 128) == want
+    assert _paths() == ((1, 0) if want else (0, 1))
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_each_block_family_asks_the_one_predicate_once_a_layer(monkeypatch,
+                                                               family):
+    """``transformer._block`` and ``hybrid.block`` decide nothing themselves:
+    tracing two layers asks ``attention_candidate`` twice, with the shapes
+    the block holds."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.models import transformer as tf
+    asked = []
+    real = attention_candidate
+
+    def spy(t, h, d, **kw):
+        asked.append((t, h, d, kw))
+        return real(t, h, d, **kw)
+
+    monkeypatch.setattr(pallas_attention, "attention_candidate", spy)
+    toks = jnp.zeros((2, 128), jnp.int32)
+    if family == "dense":
+        cfg = _tiny(n_layers=2)
+        params = jax.eval_shape(lambda: tf.init_params(jax.random.key(0), cfg))
+        jax.eval_shape(lambda p: tf.lm_loss_local(p, toks, toks, cfg), params)
+        want = (128, 2, 64, {"n_sp": 1, "asked": "auto"})
+    else:
+        layer = (hybrid.CCA(4, 2, 16), hybrid.MoE(8, (0, 4), 32, 48))
+        cfg = hybrid.HybridConfig(
+            base=_tiny(d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
+                       causal=True), layers=(layer,) * 2)
+        params = jax.eval_shape(
+            lambda: hybrid.init_params(jax.random.key(0), cfg))
+        jax.eval_shape(lambda p: hybrid.lm_loss(p, toks, toks, cfg), params)
+        want = (128, 4, 16, {})
+    assert asked == [want, want]
+    assert _paths() == (0, 2)
 
 
 def test_kernel_takes():

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.ops.flash_attention import flash_attention
 from deeplearning4j_tpu.ops.pallas.attention import fused_attention
 from deeplearning4j_tpu.ops.pallas.layernorm import fused_residual_layernorm
 from deeplearning4j_tpu.ops.pallas.matmul_int8 import (QuantizedLinear,
@@ -83,7 +82,6 @@ def _per_example(kernel):
 
 
 @pytest.mark.parametrize("kernel,causal,shape", [
-    (flash_attention, False, (B, T, H, DH)),
     (fused_attention, True, (B, T, H, DH)),
     (fused_attention, False, (B, T, H, DH)),
     (fused_attention, True, (8, 1024, 16, DH)),
@@ -91,7 +89,7 @@ def _per_example(kernel):
     (fused_attention, True, (8, 2048, 8, 128)),
     (fused_attention, True, (4, 4096, 8, 128)),
     (fused_attention, False, (4, 4096, 12, 64)),
-], ids=["flash-bidirectional", "fused-causal", "fused-bidirectional",
+], ids=["fused-causal", "fused-bidirectional",
         "fused-causal-gpt2-medium", "fused-per-example-vmap",
         "fused-causal-2048-width-128", "fused-causal-4096-width-128",
         "fused-bidirectional-4096-width-64"])

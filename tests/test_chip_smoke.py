@@ -1,6 +1,6 @@
-"""``chip_smoke.py`` and ``bench.py`` off the chip.
+"""``chip_smoke.py`` off the chip.
 
-Two things can be checked without a TPU: that both scripts refuse to run
+Two things can be checked without a TPU: that the script refuses to run
 (non-zero exit, the platform they found named, no result printed), and that
 ``chip_smoke.py``'s phases — the same code the chip runs — go through end to
 end at a tiny size on the CPU (on-chip-measurement guide §2, rehearsals 1
@@ -26,7 +26,7 @@ import chip_smoke as cs  # noqa: E402
 from __graft_entry__ import _flagship_cfg  # noqa: E402
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_refuses_to_run_without_a_tpu(script):
     proc = subprocess.run(
         [sys.executable, str(REPO / script)], capture_output=True, text=True,

@@ -126,6 +126,24 @@ def test_trainer_publishes_train_mfu_on_cpu(cpu_peak_row):
 
 # --------------------------------------------------------------------------- device memory degradation
 
+def test_peak_table_agrees_with_the_benchmarks_row_by_row():
+    """``cost.PEAKS`` (the library's gauges) and ``benchmark/peaks.json``
+    (the judged ``mfu.train``) are two files: the same kinds, the same
+    peaks."""
+    from pathlib import Path
+
+    from deeplearning4j_tpu.observability.cost import PEAKS
+    rows = json.loads((Path(__file__).resolve().parents[1]
+                       / "benchmark" / "peaks.json").read_text())
+    assert set(rows) == set(PEAKS)
+    for kind, row in rows.items():
+        peak = PEAKS[kind]
+        assert (row["bf16_flops_per_s"], row["hbm_bytes_per_s"],
+                row["hbm_bytes"]) == (peak.flops, peak.bytes_per_s,
+                                      peak.hbm_bytes), kind
+        assert row["source"].startswith(peak.source), kind
+
+
 def test_sample_device_memory_degrades_on_cpu():
     """Satellite 6: the CPU backend has no memory_stats — sampling stays
     a no-op gauge (supported=0) instead of raising or publishing junk."""

@@ -1,11 +1,10 @@
-"""Goodput accounting, time-series telemetry, SLO burn rates, perf gate.
+"""Goodput accounting, time-series telemetry, SLO burn rates.
 
 The ISSUE-14 observability tier: deterministic interval accounting
 (explicit timestamps, no sleeps), the store's bounded rings + torn-tail
 JSONL reader, burn-rate math for all three objective kinds with a real
 breach bundle on disk, the disabled-is-free contract (no thread, no
-hot-path allocation), the histogram window cap, and the perf gate's
-seed/idempotent/regression behavior.
+hot-path allocation) and the histogram window cap.
 """
 
 import json
@@ -369,110 +368,3 @@ def test_registry_surfaces_dropped_samples_counter():
     snap = reg.snapshot()
     assert snap["counters"]["metrics.dropped_samples"] == 24.0
     assert "metrics_dropped_samples_total 24.0" in reg.to_prometheus()
-
-
-# ---------------------------------------------------------------- perf gate
-
-
-def _write(path, obj):
-    path.write_text(json.dumps(obj))
-    return path
-
-
-def test_perf_gate_seeds_then_idempotent_then_fails(tmp_path):
-    from tools.perf_gate import run
-
-    traj = tmp_path / "traj.json"
-    art = _write(tmp_path / "bench.json",
-                 {"value": 1000.0, "extra": {"mfu": 0.4}})
-
-    first = run(art, traj)                   # empty trajectory: self-seeds
-    assert first["seeded"] and first["ok"] and first["recorded"]
-    assert first["series"] == {"mfu": 0.4, "tokens_per_sec": 1000.0}
-
-    second = run(art, traj)                  # same artifact: within any tol
-    assert second["ok"] and not second["seeded"] and not second["recorded"]
-    assert set(second["compared"]) == {"mfu", "tokens_per_sec"}
-
-    bad = _write(tmp_path / "bad.json",
-                 {"value": 900.0, "extra": {"mfu": 0.4}})   # -10% tokens/sec
-    res = run(bad, traj)
-    assert not res["ok"]
-    assert len(res["failures"]) == 1
-    assert "tokens_per_sec" in res["failures"][0]
-    assert "5%" in res["failures"][0]        # names the tolerance
-
-
-def test_perf_gate_direction_and_record(tmp_path):
-    from tools.perf_gate import main, run
-
-    traj = tmp_path / "traj.json"
-    _write(traj, {"tolerance": 0.05, "series_tolerance": {},
-                  "entries": [{"label": "seed", "source": "x",
-                               "series": {"ttft_p99_s": 0.100}}]})
-    # lower-is-better: a faster TTFT passes, a 10% slower one fails
-    fast = _write(tmp_path / "fast.json", {"ttft_s": {"p99": 0.080}})
-    slow = _write(tmp_path / "slow.json", {"ttft_s": {"p99": 0.110}})
-    assert run(fast, traj)["ok"]
-    res = run(slow, traj)
-    assert not res["ok"] and "ttft_p99_s" in res["failures"][0]
-
-    # --record appends a new baseline entry the next gate is held to
-    rc = main([str(fast), "--trajectory", str(traj), "--record",
-               "--label", "fast run"])
-    assert rc == 0
-    entries = json.loads(traj.read_text())["entries"]
-    assert entries[-1]["label"] == "fast run"
-    assert entries[-1]["series"] == {"ttft_p99_s": 0.080}
-    # new baseline 0.080: the old 0.100 would now itself be a regression
-    old = _write(tmp_path / "old.json", {"ttft_s": {"p99": 0.100}})
-    assert not run(old, traj)["ok"]
-
-
-def test_perf_gate_per_series_tolerance(tmp_path):
-    from tools.perf_gate import run
-
-    traj = tmp_path / "traj.json"
-    _write(traj, {"tolerance": 0.05,
-                  "series_tolerance": {"goodput_fraction": 0.5},
-                  "entries": [{"label": "seed", "source": "x",
-                               "series": {"goodput_fraction": 0.8}}]})
-    # -40% goodput sits inside its widened 50% band...
-    ok = _write(tmp_path / "ok.json", {"goodput": {"fraction": 0.48}})
-    assert run(ok, traj)["ok"]
-    # ...but -60% does not
-    bad = _write(tmp_path / "bad.json", {"goodput": {"fraction": 0.3}})
-    res = run(bad, traj)
-    assert not res["ok"] and "goodput_fraction" in res["failures"][0]
-
-
-def test_perf_gate_device_scoped_baselines(tmp_path):
-    from tools.perf_gate import run
-
-    traj = tmp_path / "traj.json"
-    _write(traj, {"tolerance": 0.05, "series_tolerance": {},
-                  "entries": [{"label": "tpu seed", "source": "x",
-                               "device": "tpu",
-                               "series": {"tokens_per_sec": 87000.0}},
-                              {"label": "cpu seed", "source": "y",
-                               "device": "cpu", "tolerance": 0.3,
-                               "series": {"tokens_per_sec": 10000.0}}]})
-    # a CPU-fallback artifact is held to the CPU entry's loose band,
-    # never to the TPU baseline 8x above it
-    cpu = _write(tmp_path / "cpu.json",
-                 {"metric": "bert_CPU_FALLBACK", "value": 9000.0,
-                  "extra": {"device": "TFRT_CPU_0"}})
-    res = run(cpu, traj)
-    assert res["device"] == "cpu" and res["ok"], res["failures"]
-    # -30% busts even the loose CPU band
-    slow = _write(tmp_path / "slow.json",
-                  {"metric": "bert_CPU_FALLBACK", "value": 6900.0,
-                   "extra": {"device": "TFRT_CPU_0"}})
-    assert not run(slow, traj)["ok"]
-    # a TPU artifact skips the CPU entry and fails against the TPU seed
-    tpu = _write(tmp_path / "tpu.json",
-                 {"metric": "bert_base_train_tokens_per_sec",
-                  "value": 70000.0, "extra": {"device": "TPU v5 lite"}})
-    res = run(tpu, traj)
-    assert res["device"] == "tpu" and not res["ok"]
-    assert "87000" in res["failures"][0]

@@ -697,7 +697,7 @@ def _write(tmp_path, name, source):
     return str(p)
 
 
-def test_cli_check_passes_on_clean_file(tmp_path):
+def test_cli_check_succeeds_on_clean_file(tmp_path):
     from tools.graftlint import main
 
     path = _write(tmp_path, "ok.py", "x = 1\n")

@@ -35,16 +35,6 @@ def test_kernel_smoke_runs_every_candidate_and_records_metrics():
         assert f"kernel.{key}.bytes_per_call" in snap["gauges"], key
 
 
-def test_autopick_publishes_observability_gauges():
-    from deeplearning4j_tpu.ops.pallas import registry
-    registry.autopick("attention", [], incumbent="ring")
-    snap = METRICS.snapshot()
-    assert snap["gauges"]["bench.autopick.attention.candidates"] == 0
-    assert snap["gauges"]["bench.autopick.attention.dropped"] == 2
-    assert snap["gauges"]["bench.autopick.attention.adopted"] == 0.0
-    assert snap["counters"]["bench.autopick.decisions"] == 1
-
-
 def test_pallas_tier_is_lint_clean_with_zero_baseline_entries():
     analyzer = Analyzer(baseline=Baseline.load(BASELINE), root=REPO)
     findings = analyzer.analyze_paths([PALLAS])

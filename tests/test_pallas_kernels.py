@@ -3,11 +3,13 @@
 Every kernel candidate must match its pure-jnp reference forward AND
 backward, in Pallas interpret mode on CPU (the same code compiles to
 Mosaic on TPU), at odd/near-prime shapes and in both f32 and bf16 — plus
-unit coverage of the candidate registry and the evidence-gated auto-pick
-that decides what production runs.
+unit coverage of the candidate registry, and every Pallas candidate held
+to the tolerances it declares against its reference.
 """
 
+import ast
 import dataclasses
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -46,18 +48,19 @@ def test_registry_kinds_and_candidates_complete():
                                 "layernorm_residual", "paged_attention",
                                 "paged_attention_int8", "xent"]
     assert [c.name for c in registry.candidates("attention")] == [
-        "flash", "fused", "ring"]
+        "fused", "ring"]
     # every pallas candidate ships a reference and documented tolerances
     for kind in registry.kinds():
         for c in registry.candidates(kind):
             assert c.reference is not None, (kind, c.name)
             if c.source == "pallas":
                 assert c.tolerances, (kind, c.name)
-                assert c.blocks, (kind, c.name)
+                # and is held to them below
+                assert (kind, c.name) in _CHECKS, (kind, c.name)
 
 
 def test_registry_get_unknown_lists_registered():
-    with pytest.raises(KeyError, match="flash"):
+    with pytest.raises(KeyError, match="fused"):
         registry.get("attention", "nope")
 
 
@@ -69,70 +72,156 @@ def test_registry_reregistration_same_fn_is_noop_different_fn_raises():
         registry.register(clash)
 
 
-# ------------------------------------------------------------------ autopick
-
-def _rows(kind, cand, metric_vals, check=None, incumbent=None, inc_vals=()):
-    rows = []
-    if check is not None:
-        rows.append({"kernel": kind, "candidate": cand, "check": check})
-    rows += [{"kernel": kind, "candidate": cand, "tokens_per_sec": v}
-             for v in metric_vals]
-    rows += [{"kernel": kind, "candidate": incumbent, "tokens_per_sec": v}
-             for v in inc_vals]
-    return rows
+def _package_sources():
+    import deeplearning4j_tpu
+    root = Path(deeplearning4j_tpu.__file__).parent
+    return {f: f.read_text() for f in sorted(root.rglob("*.py"))}
 
 
-def test_autopick_needs_margin_and_correctness():
-    ok = {"max_err": 1e-4}
-    win = registry.autopick("attention", _rows(
-        "attention", "fused", [103.0], ok, "ring", [100.0]), incumbent="ring")
-    assert win.choice == "fused" and "TUNE" in win.reason
-    # 1% is inside jitter -> incumbent, with the loser's reason on record
-    jit = registry.autopick("attention", _rows(
-        "attention", "fused", [101.0], ok, "ring", [100.0]), incumbent="ring")
-    assert jit.choice == "ring"
-    assert any(d["candidate"] == "fused" and "margin" in d["reason"]
-               for d in jit.dropped)
-    # failed correctness gate -> speed win is irrelevant
-    bad = registry.autopick("attention", _rows(
-        "attention", "fused", [200.0], {"max_err": 0.2}, "ring", [100.0]),
-        incumbent="ring")
-    assert bad.choice == "ring"
-    assert any("correctness" in d["reason"] for d in bad.dropped)
+def test_registry_imports_without_pallas():
+    """``registry.py`` names no jax module at its top level: the kernels,
+    and ``jax.experimental.pallas`` with them, load at the first lookup."""
+    tree = ast.parse(Path(registry.__file__).read_text())
+    top = [n for n in tree.body if isinstance(n, (ast.Import, ast.ImportFrom))]
+    named = [a.name for n in top if isinstance(n, ast.Import)
+             for a in n.names] + [n.module or "" for n in top
+                                  if isinstance(n, ast.ImportFrom)]
+    assert not [m for m in named if m.split(".")[0] == "jax"], named
 
 
-def test_autopick_zero_throughput_and_void_are_evidence():
-    ok = {"max_err": 1e-4}
-    # 0.0 tok/s is a broken config, not missing data
-    zero = registry.autopick("attention", _rows(
-        "attention", "fused", [0.0], ok, "ring", [100.0]), incumbent="ring")
-    assert zero.choice == "ring"
-    # no incumbent evidence at all -> never adopt by void
-    void = registry.autopick("attention", _rows(
-        "attention", "fused", [103.0], ok), incumbent="ring")
-    assert void.choice == "ring"
-    assert any("void" in d["reason"] for d in void.dropped)
+def test_kernels_take_vmem_spec_from_inside_the_tier():
+    tier = Path(registry.__file__).parent
+    users = {f.name: [l for l in text.splitlines()
+                      if "import" in l and "vmem_spec" in l]
+             for f, text in _package_sources().items() if "vmem_spec" in text}
+    assert set(users) == {"attention.py", "layernorm.py", "matmul_int8.py",
+                          "paged_attention.py", "xent.py", "vmem.py"}
+    assert (tier / "vmem.py").is_file()
+    for name, lines in users.items():
+        if name != "vmem.py":
+            assert lines == ["from .vmem import vmem_spec"], (name, lines)
 
 
-def test_autopick_every_loser_lands_in_dropped():
-    pick = registry.autopick("attention", [], incumbent="ring")
-    assert pick.choice == "ring"
-    assert {d["candidate"] for d in pick.dropped} == {"flash", "fused"}
-    assert pick.as_dict()["rows_considered"] == 0
+def test_one_function_joins_the_backend_with_the_shape():
+    """The choice of attention kernel lives in one function of the package:
+    nothing else asks for the TPU backend and ``kernel_takes`` together."""
+    joins = []
+    for f, text in _package_sources().items():
+        for node in ast.walk(ast.parse(text)):
+            if isinstance(node, ast.FunctionDef):
+                src = ast.get_source_segment(text, node)
+                if ('default_backend() == "tpu"' in src
+                        and "kernel_takes(" in src):
+                    joins.append(f"{f.name}:{node.name}")
+    assert joins == ["attention.py:attention_candidate"]
 
 
-def test_autopick_int8_min_gate():
-    # int8 adoption needs top-1 agreement ABOVE the floor, not just a
-    # small max_err — the "min" tolerance direction
-    rows = _rows("int8_matmul", "pallas_int8", [200.0],
-                 {"max_err": 1e-4, "top1_agree": 0.9},   # disagreement!
-                 "f32", [100.0])
-    pick = registry.autopick("int8_matmul", rows, incumbent="f32")
-    assert pick.choice == "f32"
-    rows = _rows("int8_matmul", "pallas_int8", [200.0],
-                 {"max_err": 1e-4, "top1_agree": 1.0}, "f32", [100.0])
-    assert registry.autopick("int8_matmul", rows,
-                             incumbent="f32").choice == "pallas_int8"
+# ------------------------------------------------------ declared tolerances
+
+def _max_abs(a, b):
+    return float(np.max(np.abs(np.asarray(a, np.float32)
+                               - np.asarray(b, np.float32))))
+
+
+def _grad_err(fn, ref, *args, argnums=0):
+    """Largest difference between the gradients of ``sum(out ** 2) / 2``
+    through ``fn`` and through ``ref`` (a tuple's last member is the
+    output that counts)."""
+    def loss(f):
+        def l(*a):
+            out = f(*a)
+            out = out[-1] if isinstance(out, tuple) else out
+            return (out.astype(jnp.float32) ** 2).sum() / 2
+        return l
+    ga = jax.grad(loss(fn), argnums)(*args)
+    gb = jax.grad(loss(ref), argnums)(*args)
+    return max(_max_abs(a, b) for a, b in zip(
+        jax.tree_util.tree_leaves(ga), jax.tree_util.tree_leaves(gb)))
+
+
+def _attention_check(cand):
+    rng = np.random.default_rng(0)
+    qkv = tuple(jnp.asarray(rng.standard_normal((2, 256, 2, 64)), jnp.float32)
+                for _ in range(3))
+    return {"max_err": _max_abs(cand.fn(*qkv), cand.reference(*qkv)),
+            "grad_err": _grad_err(cand.fn, cand.reference, *qkv,
+                                  argnums=(0, 1, 2))}
+
+
+def _ln_check(cand):
+    rng = np.random.default_rng(1)
+    x, r = (jnp.asarray(rng.standard_normal((101, 64)), jnp.float32)
+            for _ in range(2))
+    scale = jnp.asarray(rng.standard_normal(64), jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(64), jnp.float32)
+    y, h = cand.fn(x, r, scale, bias)
+    yr, hr = cand.reference(x, r, scale, bias)
+    return {"max_err": max(_max_abs(y, yr), _max_abs(h, hr)),
+            "grad_err": _grad_err(cand.fn, cand.reference, x, r, scale, bias,
+                                  argnums=(0, 1, 2, 3))}
+
+
+def _xent_check(cand):
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((101, 64)), jnp.float32)
+    head = jnp.asarray(rng.standard_normal((64, 77)) * 0.05, jnp.float32)
+    tgt = jnp.asarray(rng.integers(0, 77, 101), jnp.int32)
+    a, b = float(cand.fn(x, head, tgt)), float(cand.reference(x, head, tgt))
+    return {"max_err": abs(a - b) / max(abs(b), 1e-9),
+            "grad_err": _grad_err(
+                lambda x_, h_: jnp.sqrt(cand.fn(x_, h_, tgt)),
+                lambda x_, h_: jnp.sqrt(cand.reference(x_, h_, tgt)),
+                x, head, argnums=(0, 1))}
+
+
+def _int8_check(cand):
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((101, 64)), jnp.float32)
+    qw = quantize(jnp.asarray(rng.standard_normal((64, 77)) * 0.05))
+    out, ref = cand.fn(x, qw), cand.reference(x, qw)
+    return {"max_err": _max_abs(out, ref),
+            "top1_agree": float(top1_agreement(out, ref))}
+
+
+def _paged_check(cand):
+    _, _, args = _paged_case(jnp.float32)
+    return {"max_err": _max_abs(cand.fn(*args), cand.reference(*args))}
+
+
+def _paged_int8_check(cand):
+    _, _, args = _paged_int8_case()
+    out, ref = cand.fn(*args), cand.reference(*args)
+    return {"max_err": _max_abs(out, ref),
+            "top1_agree": float(top1_agreement(out, ref))}
+
+
+_CHECKS = {
+    ("attention", "fused"): _attention_check,
+    ("layernorm_residual", "fused"): _ln_check,
+    ("xent", "blocked"): _xent_check,
+    ("int8_matmul", "pallas_int8"): _int8_check,
+    ("paged_attention", "pallas"): _paged_check,
+    ("paged_attention_int8", "pallas_int8"): _paged_int8_check,
+}
+
+
+@pytest.mark.parametrize("kind,name", list(_CHECKS),
+                         ids=[f"{k}.{n}" for k, n in _CHECKS])
+def test_pallas_candidate_holds_its_declared_tolerances(kind, name):
+    """The correctness half of how a kernel becomes a default, on every PR:
+    the candidate's kernel (interpreted here) against its ``reference``,
+    every reading under the ``max_err`` it declares and every reading named
+    in ``min`` at or above its floor.  The speed half is the ledger."""
+    cand = registry.get(kind, name)
+    check = _CHECKS[kind, name](cand)
+    floors = cand.tolerances.get("min", {})
+    assert set(floors) <= set(check), "a declared floor was not measured"
+    for key, val in check.items():
+        if key in floors:
+            assert val >= floors[key], (key, val, floors[key])
+        else:
+            assert val < cand.tolerances["max_err"], (
+                key, val, cand.tolerances["max_err"])
 
 
 # ---------------------------------------------------------- fused attention
@@ -349,7 +438,7 @@ def _tiny_cfg(**kw):
     {"xent_impl": "blocked", "xent_chunk": 64},
 ])
 def test_transformer_kernel_variants_match_default(variant):
-    """Each bench-gated kernel opt-in computes the same loss and gradients
+    """Each kernel opt-in computes the same loss and gradients
     as the default XLA path (vocab 101 is prime: the blocked variant runs
     the shape-independent streaming schedule, not a lucky divisor)."""
     from deeplearning4j_tpu.models.transformer import (init_params,
@@ -433,26 +522,13 @@ def test_paged_attention_reads_through_block_table():
     _close(again, base, jnp.float32)
 
 
-def test_paged_attention_registered_behind_autopick_gate():
-    """The serving engine may only reach the Pallas candidate through
-    the registry, and the registry's gate must refuse it without fresh
-    correctness + margin evidence."""
+def test_paged_attention_registered_beside_its_xla_incumbent():
+    """The serving engine reaches the Pallas candidate through the
+    registry, beside the jnp gather it is held to."""
     cand = registry.get("paged_attention", "pallas")
     inc = registry.get("paged_attention", "gather")
     assert inc.source == "xla" and cand.tolerances["max_err"] == 0.05
-    rows = [
-        {"kernel": "paged_attention", "candidate": "gather",
-         "tokens_per_sec": 100.0},
-        {"kernel": "paged_attention", "candidate": "pallas",
-         "check": {"max_err": 0.001}},
-        {"kernel": "paged_attention", "candidate": "pallas",
-         "tokens_per_sec": 101.0},
-    ]
-    pick = registry.autopick("paged_attention", rows, incumbent="gather")
-    assert pick.choice == "gather"       # within 2%: no adoption
-    rows[-1]["tokens_per_sec"] = 150.0
-    pick = registry.autopick("paged_attention", rows, incumbent="gather")
-    assert pick.choice == "pallas"       # evidence + margin: adopted
+    assert cand.source == "pallas" and cand.reference is inc.fn.unscoped
 
 
 # ------------------------------------------------ paged attention: GQA + int8
@@ -513,7 +589,7 @@ def test_paged_attention_int8_kernel_matches_reference(n_kv):
 def test_paged_attention_int8_tracks_float_within_quant_band():
     """Quantize-then-attend stays inside the kind's registered numeric
     band (max_err 0.05) of full-precision attention over the ORIGINAL
-    float pool content — the error budget autopick holds it to."""
+    float pool content — the error budget the candidate declares."""
     from deeplearning4j_tpu.ops.pallas import kv_quant
     from deeplearning4j_tpu.ops.pallas.paged_attention import (
         reference_paged_attention, reference_paged_attention_int8)
@@ -534,25 +610,11 @@ def test_paged_attention_int8_tracks_float_within_quant_band():
     assert float(jnp.max(jnp.abs(a - b))) < 0.05
 
 
-def test_paged_attention_int8_gate_needs_agreement_floor():
-    """int8 KV adoption requires the top-1 agreement floor on top of
-    margin + max_err — a fast kernel that flips tokens stays dropped."""
+def test_paged_attention_int8_declares_the_agreement_floor():
+    """The int8 KV candidate declares the top-1 agreement floor on top of
+    ``max_err``: a kernel that flips tokens does not hold its tolerances."""
     cand = registry.get("paged_attention_int8", "pallas_int8")
     inc = registry.get("paged_attention_int8", "gather_int8")
     assert inc.source == "xla"
     assert cand.tolerances["min"]["top1_agree"] == 0.999
-    rows = [
-        {"kernel": "paged_attention_int8", "candidate": "gather_int8",
-         "tokens_per_sec": 100.0},
-        {"kernel": "paged_attention_int8", "candidate": "pallas_int8",
-         "check": {"max_err": 0.001, "top1_agree": 0.99}},   # below floor
-        {"kernel": "paged_attention_int8", "candidate": "pallas_int8",
-         "tokens_per_sec": 200.0},
-    ]
-    pick = registry.autopick("paged_attention_int8", rows,
-                             incumbent="gather_int8")
-    assert pick.choice == "gather_int8"
-    rows[1]["check"]["top1_agree"] = 1.0
-    pick = registry.autopick("paged_attention_int8", rows,
-                             incumbent="gather_int8")
-    assert pick.choice == "pallas_int8"
+    assert cand.tolerances["max_err"] == 0.05

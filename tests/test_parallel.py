@@ -145,6 +145,29 @@ def test_sync_matches_single_device_math():
     np.testing.assert_allclose(got_w, np.asarray(expected[0]["W"]), atol=1e-5)
 
 
+def test_dp8_train_step_hlo_holds_the_gradient_all_reduce():
+    """The transformer's train step over a dp=8 mesh, compiled: the
+    gradients' all-reduce is in the program (a step that dropped it would
+    still run, each shard on its own gradients)."""
+    from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                       TransformerLM)
+    from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    cfg = TransformerConfig(vocab_size=512, d_model=128, n_heads=4,
+                            n_layers=2, d_ff=512, max_len=128, causal=False,
+                            dtype=jnp.float32, remat=False)
+    model = TransformerLM(cfg, mesh=make_mesh(MeshSpec(dp=8, sp=1, tp=1),
+                                              devices=jax.devices()[:8]))
+    tx = tfm.chain(tfm.momentum(0.9), tfm.sgd_lr(1e-3))
+    params = model.place(model.init(jax.random.key(0)))
+    opt = model.init_opt(params, tx)
+    tokens = jax.random.randint(jax.random.key(1), (32, 128), 0,
+                                cfg.vocab_size)
+    step = model.build_train_step(tx).lower(
+        params, opt, tokens, jnp.roll(tokens, -1, axis=1)).compile()
+    assert "all-reduce" in step.as_text()
+
+
 def test_checkpoint_roundtrip(tmp_path):
     net = _iris_net()
     transform = tfm.from_conf(net.layers[-1].conf)

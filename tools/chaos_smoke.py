@@ -243,7 +243,7 @@ def run_serving(seed: int, kv_quant: str | None = None) -> dict:
     With ``kv_quant`` set the same dice roll runs against a paged +
     prefix-cache engine with quantized KV pages and ALL-greedy requests:
     exact parity relaxes to the >= 0.999 served-token top-1 agreement
-    floor (the same floor the autopick gate enforces), so fault-driven
+    floor (the same floor the int8 candidates declare), so fault-driven
     retry/skip paths are exercised through the quantized write path too.
 
     The whole leg runs under lockguard: injected faults drive the

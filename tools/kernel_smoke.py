@@ -8,8 +8,8 @@ bytes/GB-s gauges), and prints one JSON line.
 
 On CPU the kernels run in Pallas interpret mode, so the numbers are a
 SANITY signal (does the kernel dispatch, is nothing pathologically
-slow), NOT a perf claim — on-chip claims come only from the TUNE battery
-(tools/tune_tpu.py) through the bench auto-pick gate.
+slow), NOT a perf claim — on-chip claims come only from the benchmark's
+cells (``benchmark/run.py``, ``PERF_LEDGER.jsonl``).
 
 Wired as a fast tier-1 test (``tests/test_kernel_smoke.py``); also
 runnable standalone: ``python tools/kernel_smoke.py``.

@@ -388,8 +388,8 @@ def render_forecast(snap: dict) -> str | None:
 
 def render_utilization(snap: dict) -> str | None:
     """MFU / memory-bandwidth gauges from the analytic cost model
-    (``observability.cost``): published by the trainer, the decode loop
-    and bench.py from the same ``cost_analysis()``-derived FLOPs."""
+    (``observability.cost``): published by the trainer and the decode loop
+    from the same ``cost_analysis()``-derived FLOPs."""
     gauges = snap.get("gauges", {})
     rows = [(name, f"{gauges[name] * 100:.2f}%")
             for name in ("train.mfu", "train.mbu",

@@ -29,7 +29,7 @@ held token-identical across every membership change, then the same
 controller runs a deterministic diurnal-plus-spike day and must hold
 the TTFT objective (>= 95% of simulated time) with measurably fewer
 replica-hours than a static peak-provisioned fleet.  The JSON line
-carries ``{"autoscale": {"saved_frac": ...}}`` for ``perf_gate.py``.
+carries ``{"autoscale": {"saved_frac": ...}}``.
 
 ``--online`` switches to the online-learning leg (DESIGN.md §23): waves
 of greedy traffic are served through a ``ModelServer`` whose capture
@@ -67,7 +67,7 @@ tenant), a mid-run SIGKILL of one replica degrades to
 ``fleet.scrape_errors`` + a stale mark for that replica only, and — on
 a synthetic ramp — the ``forecast_breach`` flight bundle lands strictly
 before the ``SLOEvaluator`` records the breach.  The JSON line carries
-``{"fleet": {"scrape_ms": ...}}`` for ``perf_gate.py --record``.
+``{"fleet": {"scrape_ms": ...}}``.
 
 ``--replicas N`` switches to the multi-replica router smoke: the SAME
 Zipf multi-tenant workload is run twice through a ``RouterServer`` —
@@ -561,9 +561,8 @@ def run_disagg(requests: int = 24, threads: int = 3, seed: int = 0,
     stream's p99 inter-token latency at 2x prefill load stays within
     1.15x of its 1x baseline: prefill pressure lands on the prefill
     tier, not on the decode cadence.  The shared background prompts
-    also exercise the content-addressed dedup path; the emitted
-    ``{"disagg": {"dedup_frac": ...}}`` feeds ``perf_gate.py
-    --record``."""
+    also exercise the content-addressed dedup path (the emitted
+    ``{"disagg": {"dedup_frac": ...}}``)."""
     import jax
     import jax.numpy as jnp
 
@@ -1222,7 +1221,7 @@ def run_fleet(requests: int = 36, threads: int = 6, seed: int = 0,
     ``forecast.time_to_breach.serving_ttft`` dumps its
     ``forecast_breach`` bundle strictly before the ``SLOEvaluator``
     records the actual breach.  The JSON line carries
-    ``{"fleet": {"scrape_ms": ...}}`` for ``perf_gate.py``.
+    ``{"fleet": {"scrape_ms": ...}}``.
     """
     import tempfile
     import time as _time
@@ -1438,7 +1437,7 @@ def run_autoscale(seed: int = 0, requests: int = 24, threads: int = 4,
     of simulated time (the SLO budget) while the autoscaler burns
     measurably fewer replica-hours than a static fleet provisioned for
     the peak.  The JSON line carries
-    ``{"autoscale": {"saved_frac": ...}}`` for ``perf_gate.py``.
+    ``{"autoscale": {"saved_frac": ...}}``.
     """
     import math
 
