@@ -39,15 +39,16 @@ def reduce_scatter(x, axis: str, *, scatter_dimension: int = 0):
 
 # --------------------------------------------------------------- ZeRO helpers
 #
-# The sharded weight update (trainer zero_stage >= 1) communicates flattened
-# 1-D gradient/param chunks.  On a 1-member dp axis the tiled collectives
+# The sharded weight update (trainer zero_stage >= 1) communicates gradient
+# and param chunks cut along axis 0 (zero.py: a leaf in its own shape, or a
+# flattened 1-D vector).  On a 1-member dp axis the tiled collectives
 # degenerate — the "scatter" of one tile is the whole array and the "gather"
 # of one shard is the input — so these wrappers take the axis size explicitly
 # and fall back to a plain psum / identity, keeping the dp=1 step the same
 # compiled program shape as the replicated path.
 
 def reduce_scatter_or_psum(x, axis: str, axis_size: int):
-    """Reduce-scatter ``x`` (1-D, length divisible by ``axis_size``) into
+    """Reduce-scatter ``x`` (axis 0 divisible by ``axis_size``) into
     per-member contiguous tiles; psum fallback when the axis has one member
     (sum of one shard = the shard, and the tile IS the array)."""
     if axis_size == 1:
@@ -56,8 +57,8 @@ def reduce_scatter_or_psum(x, axis: str, axis_size: int):
 
 
 def all_gather_or_identity(x, axis: str, axis_size: int):
-    """Tiled all-gather of per-member chunks back to the full flattened
-    vector; identity when the axis has one member."""
+    """Tiled all-gather of per-member chunks along axis 0 back to the
+    full leaf; identity when the axis has one member."""
     if axis_size == 1:
         return x
     return lax.all_gather(x, axis, tiled=True)

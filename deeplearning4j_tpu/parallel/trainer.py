@@ -228,7 +228,7 @@ class DataParallelTrainer:
                 tstate = jax.device_put(tstate, NamedSharding(self.mesh, P(DP)))
             elif self.zero_stage >= 1:
                 # ZeRO: optimizer state is born shard-local — init runs
-                # jitted over the flattened+padded param view with
+                # jitted over the flat param view (zero.py) with
                 # out_shardings from state_spec, so each chip materializes
                 # only its 1/ndp chunk of every state leaf
                 z = self._zero_layout(params)
@@ -250,7 +250,7 @@ class DataParallelTrainer:
         return state
 
     def _zero_layout(self, params) -> ZeroLayout:
-        """Build (once) the flatten/pad/shard metadata for zero_stage >= 1.
+        """Build (once) the per-leaf shard metadata for zero_stage >= 1.
         Pure shape metadata — safe under a transfer guard."""
         if self._zero is None:
             self._zero = ZeroLayout(self.mesh, self.transform, params)
@@ -361,9 +361,15 @@ class DataParallelTrainer:
 
         local grads -> stage 1: all-reduce + slice this chip's chunk
                        stage >= 2: reduce-scatter (full grads never land)
-        -> ``transform.update`` on this chip's flattened chunk only
+        -> ``transform.update`` on this chip's chunk only
         -> stage <= 2: all-gather updated params, rebuild natural shapes
            stage 3: params stay sharded; the NEXT step gathers them.
+
+        Chunks are cut along axis 0 of each leaf's flat view, which for a
+        leaf that ``ZeroLayout.splits`` (the dp width divides its leading
+        dimension, its chunk fills the chip's tiles) is the leaf itself:
+        collectives, slices and the update run on the natural shape and
+        nothing is reshaped or padded.
 
         Numerics match the replicated step bitwise on the CPU mesh: the
         per-row losses, the 1/n_valid cotangent, and the elementwise
@@ -409,7 +415,7 @@ class DataParallelTrainer:
                 if stage == 1:
                     gfull = jax.tree_util.tree_map(
                         lambda g: clv.psum(g, DP), gflat)
-                    gchunk = z.chunk_tree(gfull, idx, z.natural_params)
+                    gchunk = z.chunk_tree(gfull, idx)
                 else:
                     gchunk = jax.tree_util.tree_map(
                         lambda g: clv.reduce_scatter_or_psum(g, DP, n_dp),
@@ -417,10 +423,10 @@ class DataParallelTrainer:
             if stage >= 3:
                 pchunk = params  # already this chip's chunks
             else:
-                pchunk = z.chunk_tree(z.flatten_tree(nat), idx,
-                                      z.natural_params)
+                pchunk = z.chunk_tree(z.flatten_tree(nat), idx)
             # decay classification must come from the NATURAL shapes — on
-            # 1-D chunks the ndim >= 2 heuristic would decay nothing
+            # the 1-D chunks of a leaf that flattens, the ndim >= 2
+            # heuristic would decay nothing
             with tfm.decay_mask_override(z.decay_mask), \
                     jax.named_scope("optimizer"):
                 updates, tstate = self.transform.update(
@@ -808,9 +814,10 @@ class DataParallelTrainer:
 
         ``layout="natural"`` (default) gathers ZeRO state back to natural
         shapes — the width-agnostic on-disk format.  ``layout="flat"``
-        writes the on-device flat padded ``P('dp')`` leaves as-is (skipping
-        the unflatten); the manager stamps the save-side width so a restore
-        at any other width re-splits host-side, exactly."""
+        writes the on-device flat ``P('dp')`` leaves as-is (skipping the
+        unflatten: natural shapes for leaves that split, 1-D padded vectors
+        for the rest); the manager stamps the save-side width so a restore
+        at any other width re-splits those host-side, exactly."""
         self._resolve_pending()
         jax.block_until_ready((state.params, state.tstate))
         METRICS.increment("checkpoint.fences")

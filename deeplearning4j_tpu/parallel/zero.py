@@ -1,15 +1,30 @@
-"""ZeRO layout: the flattened+padded view of a param tree that the sharded
-weight update (DESIGN.md §15, arXiv:2004.13336) trains in.
+"""ZeRO layout: the sharded view of a param tree that the sharded weight
+update (DESIGN.md §15, arXiv:2004.13336) trains in.
 
-Every leaf of the natural param tree maps to a 1-D vector zero-padded to a
-multiple of the dp width, so a ``NamedSharding(mesh, P('dp'))`` over the
-(only) axis gives each chip one contiguous, equal-size chunk per leaf.
+Every leaf of the natural param tree has one "flat" view whose axis 0 a
+``NamedSharding(mesh, P('dp'))`` cuts into one contiguous, equal-size chunk
+a chip.  Which view is chosen per leaf, from its shape alone:
+
+- a leaf whose leading dimension is a positive multiple of the dp width and
+  whose chunk fills the chip's tiles (``ZeroLayout.splits``) is sharded IN
+  ITS OWN SHAPE along axis 0: the flat view is the natural view, a chunk is
+  ``(d0 // n_dp, *rest)``, and flatten, unflatten and chunk do no reshape, no
+  pad and no slice of padding.  A TPU keeps an f32 array in (8, 128) tiles
+  over its last two dimensions, so a reshape of a matrix to 1-D and back is
+  a physical relayout, a pass over the leaf that computes nothing;
+- any other leaf maps to a 1-D vector zero-padded to a multiple of the dp
+  width: a scalar, a leading dimension the width does not divide, and a leaf
+  whose last two dimensions do not fill those tiles — BERT's ``(768, 3, 12,
+  64)`` query-key-value weight pads 12 x 64 to 16 x 128, and sharded in its
+  own shape its all-gather and copies cost the four-chip step 1.1 ms more
+  than the 1-D vector's (PERF.md §6, PR 31).
+
 Optimizer-state leaves mirror the flat tree (the ``state_spec`` contract in
 ``optimize/transforms``), which is what makes the shard-local
 ``transform.update`` exact: every transform in this repo is elementwise
 over its leaves, so updating 1/ndp of the elements on each chip computes
-the same numbers the replicated update would — padding rows carry zero
-gradients and are sliced off before the natural view is rebuilt.
+the same numbers the replicated update would — padding carries zero
+gradients and is sliced off before the natural view is rebuilt.
 
 The layout is pure metadata (``ShapeDtypeStruct`` trees + cached
 shardings): flatten/unflatten are trace-safe and appear both inside the
@@ -27,10 +42,16 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..observability import METRICS
 from ..optimize import transforms as tfm
 from .mesh import DP
 
 tree_map = jax.tree_util.tree_map
+
+
+# an f32 array's tile on a TPU, over its last two dimensions (gradients, the
+# optimizer state and the collectives are f32 whatever the model computes in)
+_SUBLANES, _LANES = 8, 128
 
 
 def _round_up(n: int, m: int) -> int:
@@ -75,7 +96,8 @@ def host_natural_to_flat(arr: np.ndarray, n_dp: int) -> np.ndarray:
 
 
 class ZeroLayout:
-    """Static flatten/pad/shard metadata for one (mesh, transform, params).
+    """Static shard metadata for one (mesh, transform, params): which leaves
+    split in their own shape and which flatten + pad.
 
     Built once at ``init_state`` from abstract shapes only — nothing here
     touches device memory, so constructing a layout is transfer-guard safe.
@@ -93,47 +115,75 @@ class ZeroLayout:
         self.state_shardings = tfm.state_shardings(
             transform, flat_params, P(DP), mesh)
         # weight-decay classification comes from the NATURAL layout: the
-        # ndim >= 2 heuristic is meaningless on 1-D chunks, so the sharded
-        # step pushes this mask through decay_mask_override
+        # ndim >= 2 heuristic is meaningless on the 1-D chunks of a leaf
+        # that flattens, so the sharded step pushes this mask through
+        # decay_mask_override
         self.decay_mask = tree_map(lambda a: a.ndim >= 2, self.natural_params)
+        # which path each leaf took, once per layout (as attention.path.*
+        # is counted once per trace)
+        split = [self.splits(a.shape)
+                 for a in jax.tree_util.tree_leaves(self.natural_params)]
+        METRICS.increment("zero.leaves.natural", sum(split))
+        METRICS.increment("zero.leaves.flat", len(split) - sum(split))
 
     # ---------------------------------------------------------- per-leaf ops
+    def splits(self, shape) -> bool:
+        """True for a leaf that is sharded in its own shape along axis 0:
+        its leading dimension is a positive multiple of the dp width, and
+        its chunk's last two dimensions are whole (8, 128) tiles (a 1-D
+        leaf's chunk is contiguous either way)."""
+        if not shape or shape[0] <= 0 or shape[0] % self.n_dp:
+            return False
+        if len(shape) == 1:
+            return True
+        rows = shape[0] // self.n_dp if len(shape) == 2 else shape[-2]
+        return shape[-1] % _LANES == 0 and rows % _SUBLANES == 0
+
     def padded_size(self, size: int) -> int:
-        """Leaf length after zero-padding: dp-divisible, never empty."""
-        return max(_round_up(size, self.n_dp), self.n_dp)
+        """Length of a leaf that flattens, after zero-padding: dp-divisible,
+        never empty."""
+        return flat_padded_size(size, self.n_dp)
 
     def chunk_size(self, size: int) -> int:
         return self.padded_size(size) // self.n_dp
 
     def _flatten_leaf(self, x):
+        if self.splits(x.shape):
+            return x
         flat = jnp.reshape(x, (-1,))
         pad = self.padded_size(flat.shape[0]) - flat.shape[0]
         if pad:
             flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
         return flat
 
+    def _unflatten_leaf(self, v, shape):
+        """``v`` (a jax or a numpy array) back in its natural ``shape``."""
+        if self.splits(shape):
+            return v
+        return v[:_size(shape)].reshape(shape)
+
     # ---------------------------------------------------------- tree ops
     def flatten_tree(self, tree):
-        """Natural -> flat padded, leaf by leaf (trace-safe).  Works on any
+        """Natural -> flat view, leaf by leaf (trace-safe).  Works on any
         tree whose array leaves carry natural shapes — params and the
         optimizer state both, since state leaves mirror param shapes."""
         return tree_map(self._flatten_leaf, tree)
 
     def unflatten_like(self, flat_tree, natural_template):
-        """Flat padded -> natural shapes (trace-safe): slice the pad off,
-        reshape to the template leaf's shape."""
-        return tree_map(
-            lambda v, t: jnp.reshape(v[:_size(t.shape)], t.shape),
-            flat_tree, natural_template)
+        """Flat view -> natural shapes (trace-safe): a leaf that split is
+        already there; one that flattened has its pad sliced off and is
+        reshaped to the template leaf's shape."""
+        return tree_map(lambda v, t: self._unflatten_leaf(v, t.shape),
+                        flat_tree, natural_template)
 
-    def chunk_tree(self, flat_tree, idx, natural_template):
-        """This chip's contiguous chunk of every flat leaf (inside
-        shard_map: ``idx = lax.axis_index(dp)``)."""
-        return tree_map(
-            lambda v, t: lax.dynamic_slice(
-                v, (idx * self.chunk_size(_size(t.shape)),),
-                (self.chunk_size(_size(t.shape)),)),
-            flat_tree, natural_template)
+    def chunk_tree(self, flat_tree, idx):
+        """This chip's contiguous chunk of every flat leaf along axis 0
+        (inside shard_map: ``idx = lax.axis_index(dp)``)."""
+        def chunk(v):
+            rows = v.shape[0] // self.n_dp
+            return lax.dynamic_slice_in_dim(v, idx * rows, rows, axis=0)
+
+        return tree_map(chunk, flat_tree)
 
     # ---------------------------------------------------------- host ops
     def to_natural_host(self, flat_tree, natural_template):
@@ -142,13 +192,13 @@ class ZeroLayout:
         checkpoint is byte-compatible with a replicated one, and restores
         onto any dp width)."""
         return tree_map(
-            lambda v, t: (np.asarray(v)[:_size(t.shape)].reshape(t.shape)
+            lambda v, t: (self._unflatten_leaf(np.asarray(v), t.shape)
                           if isinstance(v, (jnp.ndarray, np.ndarray)) else v),
             flat_tree, natural_template)
 
     def place_flat(self, natural_tree, out_shardings):
-        """Natural-layout host/device arrays -> flat padded leaves placed
-        per ``out_shardings`` (restore path: reshard onto the CURRENT
-        mesh, whatever dp width wrote the checkpoint)."""
+        """Natural-layout host/device arrays -> flat leaves placed per
+        ``out_shardings`` (restore path: reshard onto the CURRENT mesh,
+        whatever dp width wrote the checkpoint)."""
         return jax.jit(self.flatten_tree,
                        out_shardings=out_shardings)(natural_tree)
