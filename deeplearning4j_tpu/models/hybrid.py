@@ -1,18 +1,25 @@
-"""Models whose layers are (mixer, ffn) pairs chosen per layer (DESIGN D5).
+"""Models whose layers are (mixer, ffn) pairs chosen per layer (DESIGN D5),
+run once or several times over the same weights.
 
 ``models/transformer.py`` hard-codes one block (learned positions, biased
 LayerNorm, GELU FFN, equal heads).  Here a layer is ``x + mixer(norm(x))``
 then ``h + ffn(norm(h))`` with RMSNorm, no biases and no position table, and
 the two halves are specs looked up in ``MIXERS`` / ``FFNS``: a spec is a
-frozen dataclass that knows how to make its parameters and how to run.  The
-trunk (embedding, ``lm_head_loss`` with its chunked cross entropy, dtypes,
-``remat``) is the dense model's: ``HybridConfig.base`` is a
-``TransformerConfig`` and the head's code is shared, so a change to the head
-or to the ``attention`` registry's kernels moves both families.
+frozen dataclass that knows how to make its parameters, under which key of
+the layer they live (``key``), whether its output is normed once more before
+it joins the residual (``post_norm``: the sandwich layer), and how to run.
+Any mixer goes with any ffn.  The trunk (embedding, ``lm_head_loss`` with its
+chunked cross entropy, dtypes, ``remat``) is the dense model's:
+``HybridConfig.base`` is a ``TransformerConfig`` and the head's code is
+shared, so a change to the head or to the ``attention`` registry's kernels
+moves every family.  The head is the embedding transposed or, untied,
+``params["lm_head"]``.
 
-What is here: the ZAYA1 layer (``CCA`` mixer, arXiv:2510.04476; ``MoE`` ffn,
-arXiv:2511.17127).  The plain reference is ``models/reference/zaya.py``; the
-parameter tree below is the one it reads.
+Two families are here, each with a plain reference whose parameter tree is
+the one below (``models/reference/zaya.py``, ``models/reference/ouro.py``).
+
+The ZAYA1 layer (``CCA`` mixer, arXiv:2510.04476; ``MoE`` ffn,
+arXiv:2511.17127):
 
 - ``CCA``: attention in a compressed latent — ``H`` query heads and ``G`` KV
   heads of width ``d`` projected straight from the hidden size, two causal
@@ -26,9 +33,30 @@ parameter tree below is the one it reads.
   elsewhere gets zero from this chip: on one chip the layer runs without its
   exchange, and nothing stands in for the absent chips.
 
+The looped layer (Ouro, arXiv:2510.25741):
+
+- ``Attention``: plain causal attention, ``H`` query heads over ``G`` KV
+  heads of width ``d``, rotary positions on ``rotary_factor`` of each head
+  (the whole head by default), through the same kernel.
+- ``GatedMLP``: ``W_down(silu(W_gate u) * (W_up u))``, dense.
+- Both with ``post_norm``: ``x + N2(mixer(N1(x)))``, ``x + N4(ffn(N3(x)))``.
+
+The loop (``HybridConfig.n_loops``, ``exit_beta``): ``encode_steps`` runs the
+SAME layers ``n_loops`` times as one traced body (``lax.scan`` over loop steps
+around the checkpointed blocks: a step compiles each layer once), the final
+norm closing every step, so that the normed state is that step's output and
+the next step's input, and hands back every step's state.  With an exit gate
+(``exit_beta`` set: one Linear E -> 1 on each step's state)
+``looped_lm_loss_per_example`` sends all steps' states through the head's
+chunked loss in ONE call, turns the gates into a distribution over the steps
+token by token, and weights the steps' cross entropies by it, less
+``exit_beta`` times its entropy.  ``n_loops == 1`` without a gate is one plain
+pass.
+
 Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix``
 inside), ``attention``, ``attn_out``, ``ffn`` (with ``moe.router``,
-``moe.dispatch``, ``moe.experts`` inside), ``embed``, ``lm_head_loss``.
+``moe.dispatch``, ``moe.experts`` inside), ``embed``, ``lm_head_loss`` (with
+``loop.exit`` inside: gate, exit distribution, objective).
 """
 
 from __future__ import annotations
@@ -42,7 +70,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..observability import METRICS, trace
-from .transformer import TransformerConfig, lm_head_loss
+from .transformer import (TransformerConfig, lm_head_loss,
+                          lm_head_token_loss)
 
 Params = Any
 
@@ -62,6 +91,8 @@ class CCA:
     conv_kernels: tuple[int, int] = (2, 2)   # depthwise, then grouped by head
     rope_theta: float = 5_000_000.0
     rotary_factor: float = 0.5
+    key = "cca"            # where a layer keeps these parameters
+    post_norm = False      # the output joins the residual as it is
 
     def init(self, key, d_model: int, dtype) -> Params:
         h, g, d = self.n_heads, self.n_kv_heads, self.head_dim
@@ -178,14 +209,61 @@ def _attend(q, k, v):
     return ring_attention(q, k, v, n_sp=1, sp_axis=None, causal=True, t_local=t)
 
 
-def cca_mixer(spec: CCA, p, u, dt, **parts):
-    q, k, v = cca_qkv(spec, p, u, dt, **parts)
+def _attend_and_project(q, k, v, wo, dt):
+    """What every mixer ends with: the scores, then the heads through ``wo``."""
     with jax.named_scope("attention"):
         out = _attend(q, k, v)
     with jax.named_scope("attn_out"):
         return jnp.einsum("btf,fd->btd",
                           out.astype(dt).reshape(*out.shape[:2], -1),
-                          p["wo"].astype(dt))
+                          wo.astype(dt))
+
+
+def cca_mixer(spec: CCA, p, u, dt, **parts):
+    q, k, v = cca_qkv(spec, p, u, dt, **parts)
+    return _attend_and_project(q, k, v, p["wo"], dt)
+
+
+@dataclasses.dataclass(frozen=True)
+class Attention:
+    """Plain causal attention with rotary positions."""
+    n_heads: int = 16
+    n_kv_heads: int = 16
+    head_dim: int = 128
+    rope_theta: float = 1_000_000.0
+    rotary_factor: float = 1.0
+    key = "attn"
+    post_norm = True       # normed once more before the residual (sandwich)
+
+    def init(self, key, d_model: int, dtype) -> Params:
+        h, g, d = self.n_heads, self.n_kv_heads, self.head_dim
+        assert h % g == 0, "whole groups of query heads a KV head"
+        ks = jax.random.split(key, 4)
+        return {
+            "wq": _normal(ks[0], (d_model, h * d), d_model ** -0.5, dtype),
+            "wk": _normal(ks[1], (d_model, g * d), d_model ** -0.5, dtype),
+            "wv": _normal(ks[2], (d_model, g * d), d_model ** -0.5, dtype),
+            "wo": _normal(ks[3], (h * d, d_model), (h * d) ** -0.5, dtype),
+        }
+
+
+def attention_mixer(spec: Attention, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> the mixer's output (B, T, E)."""
+    b, t, _ = u.shape
+    with jax.named_scope("qkv_proj"):
+        u = u.astype(dt)
+
+        def proj(w, heads):
+            return jnp.einsum("btd,df->btf", u, w.astype(dt),
+                              preferred_element_type=jnp.float32
+                              ).reshape(b, t, heads, spec.head_dim)
+
+        q, k = proj(p["wq"], spec.n_heads), proj(p["wk"], spec.n_kv_heads)
+        v = proj(p["wv"], spec.n_kv_heads)
+        r = int(spec.rotary_factor * spec.head_dim)
+        q, k = _rope(q, spec.rope_theta, r), _rope(k, spec.rope_theta, r)
+        q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
+    return _attend_and_project(q, k, v, p["wo"], dt)
 
 
 # --------------------------------------------------------------------------- ffn
@@ -197,6 +275,8 @@ class MoE:
     held: tuple[int, int] = (0, 8)   # (first, count): this chip's experts
     router_hidden: int = 256
     d_ff: int = 2048
+    key = "moe"
+    post_norm = False
 
     def init(self, key, d_model: int, dtype) -> Params:
         r, f, n = self.router_hidden, self.d_ff, self.held[1]
@@ -280,9 +360,38 @@ def moe_ffn(spec: MoE, p, u, dt):
     return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
 
 
+@dataclasses.dataclass(frozen=True)
+class GatedMLP:
+    """One gated-SiLU MLP for every token."""
+    d_ff: int = 5632
+    key = "mlp"
+    post_norm = True
+
+    def init(self, key, d_model: int, dtype) -> Params:
+        f = self.d_ff
+        ks = jax.random.split(key, 3)
+        return {"wg": _normal(ks[0], (d_model, f), d_model ** -0.5, dtype),
+                "wu": _normal(ks[1], (d_model, f), d_model ** -0.5, dtype),
+                "wdn": _normal(ks[2], (f, d_model), f ** -0.5, dtype)}
+
+
+@jax.named_scope("ffn")
+def gated_mlp(spec: GatedMLP, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> ``(the layer's output, None)``:
+    there are no expert choices to report."""
+    u = u.astype(dt)
+
+    def up(w):
+        return jnp.einsum("btd,df->btf", u, w.astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    hidden = (jax.nn.silu(up(p["wg"])) * up(p["wu"])).astype(dt)
+    return jnp.einsum("btf,fd->btd", hidden, p["wdn"].astype(dt)), None
+
+
 #: spec class -> the function that runs it
-MIXERS = {CCA: cca_mixer}
-FFNS = {MoE: moe_ffn}
+MIXERS = {CCA: cca_mixer, Attention: attention_mixer}
+FFNS = {MoE: moe_ffn, GatedMLP: gated_mlp}
 
 
 # --------------------------------------------------------------------------- model
@@ -291,26 +400,40 @@ FFNS = {MoE: moe_ffn}
 class HybridConfig:
     """``base`` carries what the trunk shares with the dense model
     (``vocab_size``, ``d_model``, ``dtype``, ``param_dtype``, ``remat``,
-    ``xent_chunk``, ``xent_impl``; heads, ``d_ff``, ``max_len`` and ``causal``
-    are not read here); ``layers`` one ``(mixer spec, ffn spec)`` per layer."""
+    ``xent_chunk``, ``xent_impl``, ``tie_embeddings``; heads, ``d_ff``,
+    ``max_len`` and ``causal`` are not read here); ``layers`` one ``(mixer
+    spec, ffn spec)`` per layer; ``n_loops`` how many times the layers run
+    over the same weights; ``exit_beta`` the weight of the exit
+    distribution's entropy in the looped objective, and None for a model
+    without an exit gate."""
     base: TransformerConfig
     layers: tuple[tuple[Any, Any], ...]
     norm_eps: float = 1e-5
+    n_loops: int = 1
+    exit_beta: float | None = None
 
 
 def init_params(key, cfg: HybridConfig) -> Params:
     pd, d = cfg.base.param_dtype, cfg.base.d_model
-    assert cfg.base.tie_embeddings, "the head is the embedding transposed"
     keys = jax.random.split(key, len(cfg.layers) + 1)
     layers = []
     for (mixer, ffn), k in zip(cfg.layers, keys[:-1]):
         km, kf = jax.random.split(k)
-        layers.append({"norm1": jnp.ones((d,), pd),
-                       "cca": mixer.init(km, d, pd),
-                       "norm2": jnp.ones((d,), pd),
-                       "moe": ffn.init(kf, d, pd)})
-    return {"tok_embed": _normal(keys[-1], (cfg.base.vocab_size, d), 0.02, pd),
-            "final_norm": jnp.ones((d,), pd), "layers": layers}
+        lp = {"norm1": jnp.ones((d,), pd), mixer.key: mixer.init(km, d, pd),
+              "norm2": jnp.ones((d,), pd), ffn.key: ffn.init(kf, d, pd)}
+        for spec, name in ((mixer, "norm1_post"), (ffn, "norm2_post")):
+            if spec.post_norm:
+                lp[name] = jnp.ones((d,), pd)
+        layers.append(lp)
+    params = {"tok_embed": _normal(keys[-1], (cfg.base.vocab_size, d), 0.02, pd),
+              "final_norm": jnp.ones((d,), pd), "layers": layers}
+    kh, kg = jax.random.split(jax.random.fold_in(keys[-1], 1))
+    if not cfg.base.tie_embeddings:
+        params["lm_head"] = _normal(kh, (d, cfg.base.vocab_size), d ** -0.5, pd)
+    if cfg.exit_beta is not None:
+        params["exit_gate"] = {"w": _normal(kg, (d,), d ** -0.5, pd),
+                               "b": jnp.zeros((), pd)}
+    return params
 
 
 @jax.named_scope("layernorm")
@@ -321,37 +444,64 @@ def rms_norm(x, w, eps):
 
 
 def block(lp, x, cfg: HybridConfig, i: int):
-    """Layer ``i``: ``(x + mixer + ffn, the ffn's expert choices)``."""
+    """Layer ``i``: ``(x + mixer + ffn, the ffn's expert choices)``, each
+    half's output normed before it is added where its spec says so."""
     mixer, ffn = cfg.layers[i]
-    dt = cfg.base.dtype
-    x = x + MIXERS[type(mixer)](
-        mixer, lp["cca"], rms_norm(x, lp["norm1"], cfg.norm_eps), dt)
-    y, e = FFNS[type(ffn)](
-        ffn, lp["moe"], rms_norm(x, lp["norm2"], cfg.norm_eps), dt)
-    return x + y, e
+    dt, eps = cfg.base.dtype, cfg.norm_eps
+    a = MIXERS[type(mixer)](mixer, lp[mixer.key], rms_norm(x, lp["norm1"], eps), dt)
+    x = x + (rms_norm(a, lp["norm1_post"], eps) if mixer.post_norm else a)
+    y, e = FFNS[type(ffn)](ffn, lp[ffn.key], rms_norm(x, lp["norm2"], eps), dt)
+    return x + (rms_norm(y, lp["norm2_post"], eps) if ffn.post_norm else y), e
 
 
-def encode(params, tokens, cfg: HybridConfig):
-    """``tokens`` (B, T) -> ``(final normed hidden (B, T, E), [e per layer])``."""
+def encode_steps(params, tokens, cfg: HybridConfig):
+    """``tokens`` (B, T) -> ``(every loop step's final normed hidden
+    (n_loops, B, T, E), [e per layer, each (n_loops, B, T) or None])``.  The
+    loop is one ``lax.scan`` whose body holds each layer once; ``n_loops == 1``
+    is the layers in line, with no loop around them."""
     with jax.named_scope("embed"):
         x = jnp.take(params["tok_embed"], tokens, axis=0).astype(cfg.base.dtype)
     fn = block
     if cfg.base.remat:
         fn = jax.checkpoint(block, static_argnums=(2, 3))
-    choices = []
-    for i, lp in enumerate(params["layers"]):
-        x, e = fn(lp, x, cfg, i)
-        choices.append(e)
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), choices
+    # counted while tracing, as attention.path.*: what one trace of the model
+    # holds (layers) against what a step runs (layer applications)
+    METRICS.increment("loop.steps", cfg.n_loops)
+    METRICS.increment("loop.layer_applications", cfg.n_loops * len(cfg.layers))
+
+    def step(x):
+        choices = []
+        for i, lp in enumerate(params["layers"]):
+            x, e = fn(lp, x, cfg, i)
+            choices.append(e)
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), choices
+
+    if cfg.n_loops == 1:
+        h, choices = step(x)
+        return h[None], [None if e is None else e[None] for e in choices]
+
+    def body(x, _):
+        h, choices = step(x)
+        return h, (h, choices)     # the normed state is the next step's input
+
+    return lax.scan(body, x, None, length=cfg.n_loops)[1]
+
+
+def encode(params, tokens, cfg: HybridConfig):
+    """``tokens`` (B, T) -> ``(the last loop step's final normed hidden
+    (B, T, E), [e per layer])``."""
+    hs, choices = encode_steps(params, tokens, cfg)
+    return hs[-1], [None if e is None else e[-1] for e in choices]
 
 
 def forward(params, tokens, cfg: HybridConfig):
-    """f32 logits (B, T, V) over the vocabulary held."""
+    """f32 logits (B, T, V) over the vocabulary held, of the last loop step."""
     h, _ = encode(params, tokens, cfg)
+    head = (params["tok_embed"].T if cfg.base.tie_embeddings
+            else params["lm_head"])
     with jax.named_scope("lm_head"):
-        return jnp.einsum("btd,vd->btv", h.astype(cfg.base.dtype),
-                          params["tok_embed"].astype(cfg.base.dtype)
-                          ).astype(jnp.float32)
+        return jnp.einsum("btd,dv->btv", h.astype(cfg.base.dtype),
+                          head.astype(cfg.base.dtype)).astype(jnp.float32)
 
 
 def lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
@@ -365,6 +515,66 @@ def lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
 def lm_loss(params, tokens, targets, cfg: HybridConfig):
     """Mean cross entropy over the batch."""
     return lm_loss_per_example(params, tokens, targets, cfg).mean()
+
+
+def exit_distribution(gate, hs):
+    """Every loop step's state ``hs`` (n, B, T, E) -> ``log p`` (n, B, T)
+    f32, the distribution over the steps at which a token leaves the loop:
+    ``lambda_t = sigmoid(w . h_t + b)``, ``p_t = lambda_t prod_{s<t} (1 -
+    lambda_s)`` for ``t < n`` and the rest of the mass at the last step (whose
+    own gate is not read).  In logarithms, so that a gate near 0 or 1 leaves
+    the entropy's ``p log p`` finite."""
+    z = jnp.sum(hs.astype(jnp.float32) * gate["w"].astype(jnp.float32),
+                axis=-1) + gate["b"].astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)    # log S_1..S_{n-1}
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), stay[:-1]])
+    return jnp.concatenate([jax.nn.log_sigmoid(z[:-1]) + before, stay[-1:]])
+
+
+def looped_losses(params, tokens, targets, cfg: HybridConfig):
+    """``(objective (B, T), cross entropy of every loop step (n, B, T), log of
+    the exit distribution (n, B, T))``, all f32, token by token: the
+    objective is ``sum_t p_t xent_t - exit_beta H(p)``.  The steps' states go
+    through the head's chunked loss as one call over ``n x B x T`` tokens: one
+    scan, one ``dh``, one ``dW``."""
+    hs, _ = encode_steps(params, tokens, cfg)
+    n, b, t, d = hs.shape
+    with jax.named_scope("lm_head_loss"):
+        xent = lm_head_token_loss(
+            params, hs.reshape(n * b, t, d), jnp.tile(targets, (n, 1)), cfg.base
+        ).reshape(n, b, t)
+        with jax.named_scope("loop.exit"):
+            log_p = exit_distribution(params["exit_gate"], hs)
+            p = jnp.exp(log_p)
+            objective = jnp.sum(p * (xent + cfg.exit_beta * log_p), axis=0)
+    return objective, xent, log_p
+
+
+def looped_lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
+    """Each example's mean looped objective, ``(B,)``: the loss a
+    ``DataParallelTrainer(per_example_loss=True)`` takes for a model with an
+    exit gate."""
+    return looped_losses(params, tokens, targets, cfg)[0].mean(axis=1)
+
+
+def exit_stats(params, tokens, cfg: HybridConfig):
+    """The exit distribution summed over ``tokens`` (B, T), ``(n_loops,)``
+    f32, under ``params``: one forward pass, called outside the step."""
+    hs, _ = encode_steps(params, tokens, cfg)
+    return jnp.exp(exit_distribution(params["exit_gate"], hs)).sum(axis=(1, 2))
+
+
+def publish_exit_stats(mass, tokens_total: float) -> float:
+    """Add ``mass`` (``exit_stats`` summed over any batches, already on the
+    host) to the counters ``loop.exit_mass.t<k>`` (k from 1) and
+    ``loop.tokens_total``; returns the expected number of loop steps a token
+    takes before it leaves."""
+    METRICS.increment("loop.tokens_total", float(tokens_total))
+    steps = 0.0
+    for k, m in enumerate(mass, start=1):
+        METRICS.increment(f"loop.exit_mass.t{k}", float(m))
+        steps += k * float(m)
+    return steps / max(float(tokens_total), 1.0)
 
 
 def routing_stats(params, tokens, cfg: HybridConfig):
@@ -401,9 +611,10 @@ def place_experts(params, tokens, cfg: HybridConfig):
                 held[min(open_, key=lambda c: counts[held[c]].sum())].append(int(e))
             here = first // n
             order = sum(held[:here] + [held[here]] + held[here + 1:], [])
-            router = dict(layers[i]["moe"]["router"])
-            router["w3"] = router["w3"][:, jnp.asarray(order)]
-            layers[i] = dict(layers[i], moe=dict(layers[i]["moe"], router=router))
+            held_here = layers[i][ffn.key]
+            router = dict(held_here["router"],
+                          w3=held_here["router"]["w3"][:, jnp.asarray(order)])
+            layers[i] = dict(layers[i], **{ffn.key: dict(held_here, router=router)})
     return dict(params, layers=layers)
 
 

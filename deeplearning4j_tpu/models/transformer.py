@@ -556,6 +556,17 @@ def _per_example_xent(h_flat, t_flat, head, cfg: TransformerConfig):
                        head, cfg.dtype).reshape(n_tok)
 
 
+def lm_head_token_loss(params, h, targets, cfg: TransformerConfig):
+    """Every token's cross entropy ``(B, T)`` f32 of hidden states ``h``
+    (B, T, D): what ``lm_head_loss(per_example=True)`` takes its rows' means
+    of, for a caller that weights the tokens itself.  The caller names the
+    scope (``lm_head_loss``)."""
+    head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
+    B, T, D = h.shape
+    return _per_example_xent(h.reshape(B * T, D), targets.reshape(B * T),
+                             head, cfg).reshape(B, T)
+
+
 @jax.named_scope("lm_head_loss")
 def lm_head_loss(params, h, targets, cfg: TransformerConfig,
                  per_example: bool = False) -> jnp.ndarray:
@@ -589,8 +600,7 @@ def lm_head_loss(params, h, targets, cfg: TransformerConfig,
     n_tok = B * T
     chunk = cfg.xent_chunk
     if per_example:
-        return _per_example_xent(h.reshape(n_tok, D), targets.reshape(n_tok),
-                                 head, cfg).reshape(B, T).mean(axis=1)
+        return lm_head_token_loss(params, h, targets, cfg).mean(axis=1)
 
     def token_xent(h_flat, t_flat, w_flat):
         logits = (h_flat.astype(cfg.dtype) @ hd).astype(jnp.float32)

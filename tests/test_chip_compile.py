@@ -89,10 +89,12 @@ def _per_example(kernel):
     (fused_attention, True, (8, 2048, 8, 128)),
     (fused_attention, True, (4, 4096, 8, 128)),
     (fused_attention, False, (4, 4096, 12, 64)),
+    (fused_attention, True, (2, 4096, 16, 128)),
 ], ids=["fused-causal", "fused-bidirectional",
         "fused-causal-gpt2-medium", "fused-per-example-vmap",
         "fused-causal-2048-width-128", "fused-causal-4096-width-128",
-        "fused-bidirectional-4096-width-64"])
+        "fused-bidirectional-4096-width-64",
+        "fused-causal-4096-16-heads-of-128"])
 def test_attention_fwd_bwd_compiles(one_chip, kernel, causal, shape):
     hlo = _compile(
         _fwd_bwd(lambda q, k, v: kernel(q, k, v, causal=causal,
@@ -228,3 +230,51 @@ def test_hybrid_block_takes_the_kernel_at_4096(one_chip, monkeypatch):
     assert len(fused) == 3, len(fused)
     assert not re.findall(rf"(?:f32|bf16)\[[0-9,]*{t},{t}\]", hlo)
     assert "ragged-dot" in hlo
+
+
+def test_looped_step_compiles_each_layer_once_at_4096(one_chip, monkeypatch):
+    """Two layers of the looped family at its published widths (16 heads of
+    128, no KV repeat, gated FFN 5632, sandwich norms) run 4 times at 2 x 4096
+    with the exit-weighted objective, forward and backward, compiled for the
+    chip: each layer's attention is the fused kernel three times (forward,
+    recomputed forward, backward) however many loop steps run it, no array
+    holds 4096 x 4096 scores, and the head's recomputed chunks are in the
+    program (the token weights are unequal)."""
+    import re
+
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.models.transformer import TransformerConfig
+    from deeplearning4j_tpu.observability import METRICS
+    from deeplearning4j_tpu.ops.pallas import registry
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(registry, "resolve_interpret",
+                        lambda interpret: bool(interpret))
+    t = 4096
+    base = TransformerConfig(vocab_size=4096, d_model=2048, n_heads=16,
+                             n_kv_heads=16, n_layers=2, d_ff=5632, max_len=t,
+                             causal=True, tie_embeddings=False, remat=True,
+                             xent_chunk=2048)
+    cfg = hybrid.HybridConfig(
+        base=base, layers=((hybrid.Attention(), hybrid.GatedMLP()),) * 2,
+        norm_eps=1e-6, n_loops=4, exit_beta=0.1)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg)))
+    tokens = jax.ShapeDtypeStruct((2, t), I32, sharding=one_chip)
+    before = METRICS.snapshot()["counters"]
+    hlo = jax.jit(jax.value_and_grad(
+        lambda p, x, y: hybrid.looped_lm_loss_per_example(p, x, y, cfg).mean())
+    ).lower(params, tokens, tokens).compile().as_text()
+    after = METRICS.snapshot()["counters"]
+    moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "attention.path.kernel", "attention.path.xla", "loop.layer_applications")}
+    assert moved == {"attention.path.kernel": 2, "attention.path.xla": 0,
+                     "loop.layer_applications": 8}
+    fused = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r'op_name="[^"]*attention\.fused', line)]
+    assert len(fused) == 2 * 3, len(fused)
+    assert not re.findall(rf"(?:f32|bf16)\[[0-9,]*{t},{t}\]", hlo)
+    assert re.search(r'op_name="[^"]*lm_head\.recompute', hlo)
+    assert re.search(r'op_name="[^"]*loop\.exit', hlo)
