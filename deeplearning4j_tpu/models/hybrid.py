@@ -50,7 +50,9 @@ the next step's input, and hands back every step's state.  With an exit gate
 ``looped_lm_loss_per_example`` sends all steps' states through the head's
 chunked loss in ONE call, turns the gates into a distribution over the steps
 token by token, and weights the steps' cross entropies by it, less
-``exit_beta`` times its entropy.  ``n_loops == 1`` without a gate is one plain
+``exit_beta`` times its entropy.  The weights go into the head's call
+(``lm_head_token_loss(weights=...)``), so its forward scan makes the weighted
+``dh`` and ``dW`` once.  ``n_loops == 1`` without a gate is one plain
 pass.
 
 Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix``
@@ -536,18 +538,25 @@ def looped_losses(params, tokens, targets, cfg: HybridConfig):
     the exit distribution (n, B, T))``, all f32, token by token: the
     objective is ``sum_t p_t xent_t - exit_beta H(p)``.  The steps' states go
     through the head's chunked loss as one call over ``n x B x T`` tokens: one
-    scan, one ``dh``, one ``dW``."""
+    scan, one ``dh``, one ``dW``.  The exit distribution comes from the gate
+    on each step's state, so it is known before the head runs and goes INTO
+    that call as the tokens' weights: the gradients the scan stores are the
+    objective's own, and a mean over a full batch takes them as they are.
+    The cross entropies returned beside the objective are values only (no
+    gradient flows through them)."""
     hs, _ = encode_steps(params, tokens, cfg)
     n, b, t, d = hs.shape
     with jax.named_scope("lm_head_loss"):
-        xent = lm_head_token_loss(
-            params, hs.reshape(n * b, t, d), jnp.tile(targets, (n, 1)), cfg.base
-        ).reshape(n, b, t)
         with jax.named_scope("loop.exit"):
             log_p = exit_distribution(params["exit_gate"], hs)
             p = jnp.exp(log_p)
-            objective = jnp.sum(p * (xent + cfg.exit_beta * log_p), axis=0)
-    return objective, xent, log_p
+        weighted, xent = lm_head_token_loss(
+            params, hs.reshape(n * b, t, d), jnp.tile(targets, (n, 1)),
+            cfg.base, weights=p.reshape(n * b, t))
+        with jax.named_scope("loop.exit"):
+            objective = jnp.sum(weighted.reshape(n, b, t)
+                                + cfg.exit_beta * p * log_p, axis=0)
+    return objective, xent.reshape(n, b, t), log_p
 
 
 def looped_lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):

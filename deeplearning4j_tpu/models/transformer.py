@@ -478,8 +478,8 @@ def _recomputed_xent(h3, t2, head, dt):
     return lax.scan(lambda c, inp: (c, body(*inp)), None, (h3, t2))[1]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _fused_xent(h3, t2, head, dt):
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _fused_xent(h3, t2, head, w2, dt):
     """``_recomputed_xent`` whose gradients are made while each chunk's
     logits exist: differentiated, the forward scan also computes, under a
     unit cotangent, ``dh`` (a residual in ``dt``) and the head's ``dW``
@@ -487,15 +487,25 @@ def _fused_xent(h3, t2, head, dt):
     tokens' cotangent ``g``.  ``dh`` takes any ``g``, token by token; ``dW``
     only one that is the same number for every token, which a mean over full
     batches gives.  Any other ``g`` (a padded batch) recomputes the chunks and
-    pulls ``g`` back through them, as ``_recomputed_xent`` differentiated."""
-    return _recomputed_xent(h3, t2, head, dt)
+    pulls ``g`` back through them, as ``_recomputed_xent`` differentiated.
+
+    With the tokens' weights ``w2`` (``(chunks, chunk)`` f32; ``None`` is the
+    program above, operation for operation) the result is ``(w2 * xent,
+    xent)`` and the scan folds the weights into what it stores: ``dW`` sums
+    ``h^T (w (softmax - onehot))``, weighted in f32 before the cast to ``dt``,
+    and ``dh``'s rows are scaled in f32 as they leave their product.  Both are
+    the weighted losses' own, so weights that differ token by token (an exit
+    distribution) still leave ``g`` one number and ``dW`` made once.  The
+    weights' gradient is ``g * xent``; the second result carries none."""
+    xent = _recomputed_xent(h3, t2, head, dt)
+    return xent if w2 is None else (w2 * xent, xent)
 
 
-def _fused_xent_fwd(h3, t2, head, dt):
+def _fused_xent_fwd(h3, t2, head, w2, dt):
     hd = head.astype(dt)
 
     def body(dw, inp):
-        h_c, t_c = inp
+        h_c, t_c, *w_c = inp
         h_c = h_c.astype(dt)
         # _token_xent's numbers, with the targets' logits gathered BEFORE the
         # cast (the same values): the product then leaves no f32 copy of the
@@ -506,19 +516,36 @@ def _fused_xent_fwd(h3, t2, head, dt):
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         # d(losses)/d(logits): rounded where the recompute's backward rounds it
         p = jnp.exp(logits - lse[:, None])
-        p = (p - (lax.broadcasted_iota(jnp.int32, p.shape, 1) == t_c[:, None])
-             ).astype(dt)
-        dw = dw + jnp.dot(h_c.T, p, preferred_element_type=jnp.float32)
-        return dw, (lse - gold.astype(jnp.float32), p @ hd.T)
+        p = p - (lax.broadcasted_iota(jnp.int32, p.shape, 1) == t_c[:, None])
+        if not w_c:
+            p = p.astype(dt)
+            dw = dw + jnp.dot(h_c.T, p, preferred_element_type=jnp.float32)
+            return dw, (lse - gold.astype(jnp.float32), p @ hd.T)
+        # dW needs the weights inside its sum over tokens; dh takes them row
+        # by row on its way out of the product (chunk x D numbers, not
+        # chunk x V)
+        w = w_c[0][:, None]
+        dw = dw + jnp.dot(h_c.T, (w * p).astype(dt),
+                          preferred_element_type=jnp.float32)
+        dh_c = w * jnp.dot(p.astype(dt), hd.T, preferred_element_type=jnp.float32)
+        return dw, (lse - gold.astype(jnp.float32), dh_c.astype(dt))
 
     with jax.named_scope("lm_head.fused"):
-        dw, (losses, dh) = lax.scan(
-            body, jnp.zeros(head.shape, jnp.float32), (h3, t2))
-    return losses, (h3, t2, head, dh, dw)
+        dw, (xent, dh) = lax.scan(
+            body, jnp.zeros(head.shape, jnp.float32),
+            (h3, t2) if w2 is None else (h3, t2, w2))
+    if w2 is None:
+        return xent, (h3, t2, head, None, dh, dw)
+    return (w2 * xent, xent), (h3, t2, head, (w2, xent), dh, dw)
 
 
 def _fused_xent_bwd(dt, res, g):
-    h3, t2, head, dh, dw = res
+    h3, t2, head, weighted, dh, dw = res
+    d_w = None
+    if weighted is not None:
+        w2, xent = weighted
+        g = g[0]                    # the unweighted losses carry no gradient
+        d_w = g * xent
     g0 = g[0, 0]
 
     def scaled():
@@ -526,45 +553,63 @@ def _fused_xent_bwd(dt, res, g):
             return (g0 * dw).astype(head.dtype)
 
     def recomputed():
+        g_xent = g if weighted is None else g * w2
         with jax.named_scope("lm_head.recompute"):
-            return jax.vjp(lambda w: _recomputed_xent(h3, t2, w, dt), head)[1](g)[0]
+            return jax.vjp(lambda w: _recomputed_xent(h3, t2, w, dt),
+                           head)[1](g_xent)[0]
 
     with jax.named_scope("lm_head.fused"):
         d_h = (dh * g[..., None]).astype(h3.dtype)
-    return d_h, None, lax.cond(jnp.all(g == g0), scaled, recomputed)
+    return d_h, None, lax.cond(jnp.all(g == g0), scaled, recomputed), d_w
 
 
 _fused_xent.defvjp(_fused_xent_fwd, _fused_xent_bwd)
 
 
-def _per_example_xent(h_flat, t_flat, head, cfg: TransformerConfig):
+def _per_example_xent(h_flat, t_flat, head, cfg: TransformerConfig,
+                      w_flat=None):
     """Per-token cross entropy ``(N,)`` f32 of ``h_flat`` (N, D) against the
     head ``head`` (D, V, cast to ``cfg.dtype`` here), ``cfg.xent_chunk``
     tokens at a time (the largest divisor of N under it) through
-    ``_fused_xent``; N tokens or fewer than a chunk at once."""
+    ``_fused_xent``; N tokens or fewer than a chunk at once.  With the tokens'
+    weights ``w_flat`` (N,) f32: ``(w_flat * xent, xent)``, the second without
+    a gradient."""
     n_tok, d = h_flat.shape
     chunk = cfg.xent_chunk
+    if w_flat is not None:
+        METRICS.increment("lm_head_loss.path.weighted")
     if not chunk or n_tok <= chunk:
         METRICS.increment("lm_head_loss.path.plain")
-        return _token_xent(h_flat, t_flat, head.astype(cfg.dtype))
+        xent = _token_xent(h_flat, t_flat, head.astype(cfg.dtype))
+        if w_flat is None:
+            return xent
+        return w_flat * xent, lax.stop_gradient(xent)
     while n_tok % chunk:
         chunk -= 1
     # counted while tracing, as attention.path.*: the chunked loss makes its
     # gradients in the forward pass
     METRICS.increment("lm_head_loss.path.fused")
-    return _fused_xent(h_flat.reshape(-1, chunk, d), t_flat.reshape(-1, chunk),
-                       head, cfg.dtype).reshape(n_tok)
+    out = _fused_xent(
+        h_flat.reshape(-1, chunk, d), t_flat.reshape(-1, chunk), head,
+        None if w_flat is None else w_flat.reshape(-1, chunk), cfg.dtype)
+    return jax.tree_util.tree_map(lambda a: a.reshape(n_tok), out)
 
 
-def lm_head_token_loss(params, h, targets, cfg: TransformerConfig):
+def lm_head_token_loss(params, h, targets, cfg: TransformerConfig,
+                       weights=None):
     """Every token's cross entropy ``(B, T)`` f32 of hidden states ``h``
     (B, T, D): what ``lm_head_loss(per_example=True)`` takes its rows' means
-    of, for a caller that weights the tokens itself.  The caller names the
-    scope (``lm_head_loss``)."""
+    of.  A caller that weights the tokens hands ``weights`` (B, T) f32 in and
+    gets ``(weights * xent, xent)``: the first differentiable in ``h``, the
+    head and ``weights``, with the weights already inside the gradients the
+    chunked loss stores (``_fused_xent``); the second a value beside it that
+    carries NO gradient.  The caller names the scope (``lm_head_loss``)."""
     head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
     B, T, D = h.shape
-    return _per_example_xent(h.reshape(B * T, D), targets.reshape(B * T),
-                             head, cfg).reshape(B, T)
+    out = _per_example_xent(
+        h.reshape(B * T, D), targets.reshape(B * T), head, cfg,
+        None if weights is None else weights.reshape(B * T))
+    return jax.tree_util.tree_map(lambda a: a.reshape(B, T), out)
 
 
 @jax.named_scope("lm_head_loss")
@@ -592,8 +637,11 @@ def lm_head_loss(params, h, targets, cfg: TransformerConfig,
     scales them.  ``dW`` can be scaled only by one number, so a ``lax.cond``
     takes the stored one when the rows' cotangents are all equal (a mean over
     a full batch) and otherwise recomputes chunk by chunk as the mean path
-    does (a padded batch).  "The backward recomputes them" above holds for
-    the mean path and for that fallback only."""
+    does (a padded batch).  A caller whose weights differ token by token
+    hands them to ``lm_head_token_loss(weights=...)``: they go into the
+    stored gradients, and the cotangent stays one number.  "The backward
+    recomputes them" above holds for the mean path and for that fallback
+    only."""
     head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
     hd = head.astype(cfg.dtype)
     B, T, D = h.shape

@@ -239,7 +239,8 @@ def test_looped_step_compiles_each_layer_once_at_4096(one_chip, monkeypatch):
     chip: each layer's attention is the fused kernel three times (forward,
     recomputed forward, backward) however many loop steps run it, no array
     holds 4096 x 4096 scores, and the head's recomputed chunks are in the
-    program (the token weights are unequal)."""
+    program (compiled for a padded batch's unequal rows; the exit weights are
+    inside the head's call and a full batch never runs them)."""
     import re
 
     from deeplearning4j_tpu.models import hybrid
@@ -263,9 +264,13 @@ def test_looped_step_compiles_each_layer_once_at_4096(one_chip, monkeypatch):
         jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg)))
     tokens = jax.ShapeDtypeStruct((2, t), I32, sharding=one_chip)
     before = METRICS.snapshot()["counters"]
+    # the rows' weights arrive at run time, as the trainer's mask / n_valid
+    # does: under a constant mean XLA folds the head's cond away
+    rows = jax.ShapeDtypeStruct((2,), jnp.float32, sharding=one_chip)
     hlo = jax.jit(jax.value_and_grad(
-        lambda p, x, y: hybrid.looped_lm_loss_per_example(p, x, y, cfg).mean())
-    ).lower(params, tokens, tokens).compile().as_text()
+        lambda p, x, y, w: jnp.sum(
+            hybrid.looped_lm_loss_per_example(p, x, y, cfg) * w))
+    ).lower(params, tokens, tokens, rows).compile().as_text()
     after = METRICS.snapshot()["counters"]
     moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
         "attention.path.kernel", "attention.path.xla", "loop.layer_applications")}

@@ -243,10 +243,12 @@ def test_the_loop_traces_each_layer_once(case):
     after = METRICS.snapshot()["counters"]
     moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
         "attention.path.xla", "attention.path.kernel", "loop.steps",
-        "loop.layer_applications", "lm_head_loss.path.fused")}
+        "loop.layer_applications", "lm_head_loss.path.fused",
+        "lm_head_loss.path.weighted")}
     assert moved == {"attention.path.xla": 3, "attention.path.kernel": 0,
                      "loop.steps": LOOPS, "loop.layer_applications": 3 * LOOPS,
-                     "lm_head_loss.path.fused": 1}
+                     "lm_head_loss.path.fused": 1,
+                     "lm_head_loss.path.weighted": 1}
     scans = [e.params["jaxpr"].jaxpr.eqns for e in jaxpr.jaxpr.eqns
              if e.primitive.name == "scan" and e.params["length"] == LOOPS]
     assert len(scans) == 2                   # the loop, forward and backward
@@ -421,43 +423,54 @@ WEIGHTS = {"equal": np.full((4, SEQ), 1.0 / (4 * SEQ), np.float32),
            "random": np.asarray(jax.random.uniform(jax.random.key(3), (4, SEQ)))}
 
 
+def token_weighted(cfg, tgts, how):
+    """``(loss, (d head, d h, d w))`` of the tokens' losses under the weights
+    ``w``, handed to the chunked loss or applied to what it returns."""
+    def loss(p, h, w):
+        if how == "handed in":
+            return lm_head_token_loss(p, h, tgts, cfg, weights=w)[0].sum()
+        return (lm_head_token_loss(p, h, tgts, cfg) * w).sum()
+
+    return jax.jit(jax.value_and_grad(loss, (0, 1, 2)))
+
+
+@pytest.mark.parametrize("how", ["handed in", "applied outside"])
 @pytest.mark.parametrize("weights", WEIGHTS)
 @pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
-def test_chunked_token_losses_take_token_weights(weights, tied):
+def test_chunked_token_losses_take_token_weights(weights, tied, how):
     """The weighted sum of the chunked per-token losses differentiates to what
-    plain autodiff of the unchunked loss gives, for the head and for the
-    hidden states: with equal weights through the gradients made in the
-    forward scan, with unequal ones (what the exit distribution gives) through
-    the recomputed chunks, ``_fused_xent``'s ``lax.cond`` decided at run
-    time."""
+    plain autodiff of the unchunked loss gives, for the head, the hidden
+    states and the weights themselves.  Handed in, any weights go into the
+    gradients made in the forward scan (the cotangent is one number).  Applied
+    outside, equal weights take those gradients and unequal ones (a padded
+    batch's) the recomputed chunks, ``_fused_xent``'s ``lax.cond`` decided at
+    run time."""
     cfg = config(tied=tied).base
     head = "tok_embed" if tied else "lm_head"
     shape = (V, E) if tied else (E, V)
     params = {head: 0.5 * jax.random.normal(jax.random.key(0), shape)}
     h = jax.random.normal(jax.random.key(1), (4, SEQ, E))
     tgts = jax.random.randint(jax.random.key(2), (4, SEQ), 0, V)
-
-    def weighted(cfg_):
-        return jax.jit(jax.value_and_grad(
-            lambda p, h_, w: (lm_head_token_loss(p, h_, tgts, cfg_) * w).sum(),
-            (0, 1)))
-
     w = jnp.asarray(WEIGHTS[weights])
-    loss, (d_p, d_h) = weighted(cfg)(params, h, w)
-    want, (w_p, w_h) = weighted(dataclasses.replace(cfg, xent_chunk=0))(params, h, w)
+    loss, (d_p, d_h, d_w) = token_weighted(cfg, tgts, how)(params, h, w)
+    want, (w_p, w_h, w_w) = token_weighted(
+        dataclasses.replace(cfg, xent_chunk=0), tgts, "applied outside")(params, h, w)
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
-    for got, want_ in ((d_p[head], w_p[head]), (d_h, w_h)):
+    for got, want_ in ((d_p[head], w_p[head]), (d_h, w_h), (d_w, w_w)):
         np.testing.assert_allclose(got, want_, rtol=1e-4,
                                    atol=1e-5 * float(jnp.abs(want_).max()))
+    if how == "handed in":      # the losses beside the weighted ones: plain
+        np.testing.assert_allclose(
+            lm_head_token_loss(params, h, tgts, cfg, weights=w)[1], w_w, rtol=1e-5)
 
 
 # --------------------------------------------------- the trainer, names, counts
 
-def trainer_for(cfg):
+def trainer_for(cfg, tx=None):
     def loss(p, x, y, key=None):
         return hybrid.looped_lm_loss_per_example(p, x, y, cfg)
 
-    return DataParallelTrainer(loss, T.adamw(3e-3, weight_decay=0.0),
+    return DataParallelTrainer(loss, tx or T.adamw(3e-3, weight_decay=0.0),
                                mesh=local_mesh(1), per_example_loss=True)
 
 
@@ -478,10 +491,49 @@ def test_trainer_steps_the_looped_model(case):
     assert all(v["rel"] > 0 for v in moved.values()), moved
 
 
+def test_full_batches_take_the_stored_head_gradient(monkeypatch):
+    """What ``_fused_xent``'s ``lax.cond`` decides at run time, read through a
+    callback on its predicate: the looped objective through the trainer hands
+    the head one number for every token and loop step on a full batch (the
+    exit weights are inside the call), so the ``dW`` the forward scan made is
+    taken; a padded batch (3 rows in a bucket of 4) hands ``mask / n_valid``
+    and recomputes, with the weights.  Either way the step is plain
+    autodiff's of the unchunked loss."""
+    seen, cond = [], jax.lax.cond
+
+    def spy(pred, true_fun, false_fun, *operands):
+        if true_fun.__name__ == "scaled":
+            jax.debug.callback(lambda took: seen.append(bool(took)), pred)
+        return cond(pred, true_fun, false_fun, *operands)
+
+    monkeypatch.setattr(jax.lax, "cond", spy)
+    cfg = config()
+    start = seeded_params(cfg)
+    toks, tgts = (np.asarray(a) for a in batch(n=4))
+    after = {}
+    for chunk in (16, 0):
+        trainer = trainer_for(
+            dataclasses.replace(cfg, base=dataclasses.replace(
+                cfg.base, xent_chunk=chunk)), T.sgd_lr(0.5))
+        for rows in (4, 3):
+            state, loss = trainer.step(trainer.init_state(start),
+                                       toks[:rows], tgts[:rows])
+            after[chunk, rows] = (float(loss), state.params)
+    jax.effects_barrier()
+    assert seen == [True, False]        # the unchunked loss has no cond
+    for rows in (4, 3):
+        (loss, got), (want_loss, want) = after[16, rows], after[0, rows]
+        assert loss == pytest.approx(want_loss, rel=1e-5)
+        for a, b, p0 in zip(*map(jax.tree_util.tree_leaves, (got, want, start))):
+            np.testing.assert_allclose(
+                a, b, rtol=1e-4, atol=1e-4 * float(jnp.abs(b - p0).max()) + 1e-7)
+
+
 def test_loop_names_in_the_lowered_step():
     """The sublayer names ``scope_share`` reads, ``loop.exit`` nested in
     ``lm_head_loss`` and the recomputed head chunks under
-    ``lm_head.recompute``: what the benchmark's readers attribute by."""
+    ``lm_head.recompute`` (compiled for a padded batch, run by no full one):
+    what the benchmark's readers attribute by."""
     import sys
     from pathlib import Path
 

@@ -16,7 +16,8 @@ import pytest
 from deeplearning4j_tpu.models import hybrid
 from deeplearning4j_tpu.models.reference import zaya as ref
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
-                                                   lm_head_loss)
+                                                   lm_head_loss,
+                                                   lm_head_token_loss)
 from deeplearning4j_tpu.observability import METRICS
 from deeplearning4j_tpu.optimize import transforms as T
 from deeplearning4j_tpu.parallel import DataParallelTrainer
@@ -287,13 +288,14 @@ def test_head_loss_counts_its_path_once_a_trace(chunk, path):
     cfg, params, h, tgts = head_case(jnp.float32, chunk)
     step = weighted_head_loss(cfg, tgts)
     counts = lambda: {k: METRICS.snapshot()["counters"].get(
-        f"lm_head_loss.path.{k}", 0) for k in ("fused", "plain")}
+        f"lm_head_loss.path.{k}", 0) for k in ("fused", "plain", "weighted")}
     before = counts()
     for _ in range(2):                      # the second call traces nothing
         step(params, h, jnp.full(4, 0.25))
     after = counts()
     assert {k: after[k] - before[k] for k in after} == {
-        "fused": int(path == "fused"), "plain": int(path == "plain")}
+        "fused": int(path == "fused"), "plain": int(path == "plain"),
+        "weighted": 0}                      # no weights handed in: ZAYA's call
 
 
 def head_products(jaxpr, branch=None):
@@ -323,6 +325,52 @@ def test_equal_cotangents_cost_three_head_products_a_chunk():
     # lax.cond(pred, scaled, recomputed) lists the false branch first
     assert head_products(jaxpr, branch=1) == 3
     assert head_products(jaxpr, branch=0) >= 3 + 2
+
+
+def test_looped_objective_costs_three_head_products_a_chunk():
+    """The same count for the looped model's whole step: the exit
+    distribution goes into the head's call as the tokens' weights, so the
+    program outside the ``cond`` holds logits, ``dh`` and ``dW`` of each chunk
+    and the branch a full batch takes holds no product of the head's shape;
+    the recomputed chunks are compiled on the other branch only."""
+    base = dataclasses.replace(config().base, tie_embeddings=False, remat=True)
+    cfg = hybrid.HybridConfig(
+        base=base, layers=((hybrid.Attention(H, G, D, 1e6), hybrid.GatedMLP(F)),),
+        norm_eps=1e-6, n_loops=4, exit_beta=0.1)
+    params = hybrid.init_params(jax.random.key(0), cfg)
+    toks = jnp.zeros((BATCH, SEQ), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: hybrid.looped_lm_loss_per_example(p, toks, toks, cfg).mean()
+    ))(params).jaxpr
+    assert head_products(jaxpr) == 3
+    assert head_products(jaxpr, branch=1) == 3
+    assert head_products(jaxpr, branch=0) >= 3 + 2
+
+
+def count_eqns(jaxpr, name):
+    """How many ``name`` equations ``jaxpr`` holds, sub-programs included."""
+    return sum((eqn.primitive.name == name)
+               + sum(count_eqns(sub, name)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+def test_unweighted_head_loss_multiplies_by_no_weights():
+    """``lm_head_loss(per_example=True)`` hands the chunked loss no weights,
+    and its program holds the multiplies it held before the loss took any (5:
+    the rows' weights outside and their transpose, ``dh * g``, ``g0 * dW``,
+    and the softmax's own in the recomputed chunks' backward); the weighted
+    call holds more (the weight in the scan's body, ``w * xent``, ``g *
+    xent``)."""
+    cfg, params, h, tgts = head_case(jnp.bfloat16, 16)
+    rows = jax.make_jaxpr(weighted_head_loss(cfg, tgts))(
+        params, h, jnp.full(4, 0.25)).jaxpr
+    assert count_eqns(rows, "mul") == 5
+    tokens = jax.make_jaxpr(jax.grad(
+        lambda p, h_, w: lm_head_token_loss(p, h_, tgts, cfg, weights=w)[0].sum(),
+        (0, 1)))(params, h, jnp.full((4, SEQ), 0.25)).jaxpr
+    assert count_eqns(tokens, "mul") > 5
+    assert head_products(tokens, branch=1) == 3
 
 
 def test_loss_is_the_same_row_by_row_and_as_a_whole_batch(case):
