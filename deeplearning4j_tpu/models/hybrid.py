@@ -15,8 +15,9 @@ shared, so a change to the head or to the ``attention`` registry's kernels
 moves every family.  The head is the embedding transposed or, untied,
 ``params["lm_head"]``.
 
-Two families are here, each with a plain reference whose parameter tree is
-the one below (``models/reference/zaya.py``, ``models/reference/ouro.py``).
+Three families are here, each with a plain reference whose parameter tree is
+the one below (``models/reference/zaya.py``, ``models/reference/ouro.py``,
+``models/reference/keye.py``).
 
 The ZAYA1 layer (``CCA`` mixer, arXiv:2510.04476; ``MoE`` ffn,
 arXiv:2511.17127):
@@ -32,6 +33,27 @@ arXiv:2511.17127):
   ``held`` experts this chip owns.  A token routed to an expert that lives
   elsewhere gets zero from this chip: on one chip the layer runs without its
   exchange, and nothing stands in for the absent chips.
+
+The sparse-attention layer (a learned top-k selection of keys, DeepSeek sparse
+attention's "lightning indexer", DeepSeek-V3.2-Exp report; top-k experts
+behind a linear router, the Qwen3-MoE family's):
+
+- ``SparseAttention``: grouped-query attention with per-head RMSNorm on q and
+  k, restricted query by query to the ``top_k`` earlier keys that a small
+  scoring network ranks highest: ``I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+  kI[s])`` over ``index_heads`` heads of ``index_dim``, all read from the
+  normed hidden state behind a ``stop_gradient``.  The selection is exact
+  (the k-th largest score by bisection on the scores' bits, ties to the lower
+  position) and a constant of the backward pass.  Computed ``rows``
+  queries at a time against the keys so far, each chunk checkpointed, on the
+  XLA path.  Beside its output the mixer yields the indexer's own loss: the
+  divergence of the head-summed attention probabilities from the softmax of
+  the index scores over the selected keys, which reaches the indexer's
+  parameters alone, as the language-model loss reaches everything else alone.
+- ``MoE`` with ``top_k > 1`` and ``router_hidden = 0``: a linear softmax
+  router, the ``top_k`` largest probabilities renormalised, each choice sent
+  through the SAME dispatch as a top-1 layer's one choice (a scan over the
+  choices), so that memory stays what one choice needs.
 
 The looped layer (Ouro, arXiv:2510.25741):
 
@@ -55,21 +77,25 @@ token by token, and weights the steps' cross entropies by it, less
 ``dh`` and ``dW`` once.  ``n_loops == 1`` without a gate is one plain
 pass.
 
-Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix``
-inside), ``attention``, ``attn_out``, ``ffn`` (with ``moe.router``,
-``moe.dispatch``, ``moe.experts`` inside), ``embed``, ``lm_head_loss`` (with
-``loop.exit`` inside: gate, exit distribution, objective).
+Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix`` or
+``dsa.index_proj`` inside), ``attention`` (with ``dsa.index_scores``,
+``dsa.select``, ``dsa.index_loss`` inside), ``attn_out``, ``ffn`` (with
+``moe.router``, ``moe.dispatch``, ``moe.experts`` inside), ``embed``,
+``lm_head_loss`` (with ``loop.exit`` inside: gate, exit distribution,
+objective).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..observability import METRICS, trace
 from .transformer import (TransformerConfig, lm_head_loss,
@@ -95,6 +121,7 @@ class CCA:
     rotary_factor: float = 0.5
     key = "cca"            # where a layer keeps these parameters
     post_norm = False      # the output joins the residual as it is
+    aux_loss = False       # the mixer returns its output alone
 
     def init(self, key, d_model: int, dtype) -> Params:
         h, g, d = self.n_heads, self.n_kv_heads, self.head_dim
@@ -236,6 +263,7 @@ class Attention:
     rotary_factor: float = 1.0
     key = "attn"
     post_norm = True       # normed once more before the residual (sandwich)
+    aux_loss = False
 
     def init(self, key, d_model: int, dtype) -> Params:
         h, g, d = self.n_heads, self.n_kv_heads, self.head_dim
@@ -268,30 +296,287 @@ def attention_mixer(spec: Attention, p, u, dt):
     return _attend_and_project(q, k, v, p["wo"], dt)
 
 
+@dataclasses.dataclass(frozen=True)
+class SparseAttention:
+    """Causal grouped-query attention over the ``top_k`` earlier keys a
+    learned indexer ranks highest for each query."""
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 10_000_000.0
+    qk_norm: bool = True             # RMSNorm on each head of q and k
+    index_heads: int = 16
+    index_dim: int = 64
+    top_k: int = 2048
+    q_chunk: int = 512               # with kv_chunk, the tile of dsa.tiles_*
+    kv_chunk: int = 512
+    rows: int = 256                  # queries the XLA path computes at a time
+    norm_eps: float = 1e-6           # of the head norms and the key LayerNorm
+    key = "dsa"
+    post_norm = False
+    aux_loss = True        # the mixer returns (output, its own loss (B,))
+
+    def init(self, key, d_model: int, dtype) -> Params:
+        h, g, d = self.n_heads, self.n_kv_heads, self.head_dim
+        hi, di = self.index_heads, self.index_dim
+        assert h % g == 0, "whole groups of query heads a KV head"
+        ks = jax.random.split(key, 7)
+        p = {
+            "wq": _normal(ks[0], (d_model, h * d), d_model ** -0.5, dtype),
+            "wk": _normal(ks[1], (d_model, g * d), d_model ** -0.5, dtype),
+            "wv": _normal(ks[2], (d_model, g * d), d_model ** -0.5, dtype),
+            "wo": _normal(ks[3], (h * d, d_model), (h * d) ** -0.5, dtype),
+            "index": {
+                "wq": _normal(ks[4], (d_model, hi * di), d_model ** -0.5, dtype),
+                "wk": _normal(ks[5], (d_model, di), d_model ** -0.5, dtype),
+                "ww": _normal(ks[6], (d_model, hi), d_model ** -0.5, dtype),
+                "k_norm_w": jnp.ones((di,), dtype),
+                "k_norm_b": jnp.zeros((di,), dtype),
+            },
+        }
+        if self.qk_norm:
+            p["q_norm"], p["k_norm"] = jnp.ones((d,), dtype), jnp.ones((d,), dtype)
+        return p
+
+
+#: what a checkpointed block keeps of a sparse mixer besides its input: the
+#: heads' outputs (B, T, H, d) and the index loss (B,)
+KEPT = ("dsa.out", "dsa.loss")
+#: stands for "not selected" in a row of scores: finite, so that 0 x it is 0
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+#: key lengths a sequence's query chunks are computed at: chunk ``c`` needs
+#: the keys up to its own end, and a static shape serves a run of chunks
+_KEY_SPANS = 4
+
+
+def _project(u, w, dt):
+    return jnp.einsum("btd,df->btf", u, w.astype(dt),
+                      preferred_element_type=jnp.float32)
+
+
+@jax.named_scope("dsa.index_proj")
+def index_inputs(spec: SparseAttention, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> the indexer's queries ``(B, T,
+    index_heads, index_dim)`` and keys ``(B, T, index_dim)`` in ``dt``, and
+    its head weights ``(B, T, index_heads)`` f32, all behind a
+    ``stop_gradient``: the indexer learns from its own loss alone."""
+    b, t, _ = u.shape
+    hi, di = spec.index_heads, spec.index_dim
+    u = lax.stop_gradient(u).astype(dt)
+    qi = _rope(_project(u, p["wq"], dt).reshape(b, t, hi, di),
+               spec.rope_theta, di)
+    ki = _project(u, p["wk"], dt)
+    ki = ki - ki.mean(axis=-1, keepdims=True)
+    ki = (ki * lax.rsqrt(jnp.mean(ki * ki, axis=-1, keepdims=True)
+                         + spec.norm_eps) * p["k_norm_w"] + p["k_norm_b"])
+    ki = _rope(ki[:, :, None, :], spec.rope_theta, di)[:, :, 0, :]
+    w = _project(u, p["ww"], dt) * (hi ** -0.5 * di ** -0.5)
+    return qi.astype(dt), ki.astype(dt), w
+
+
+@jax.named_scope("dsa.index_scores")
+def index_scores(qi, ki, w):
+    """``I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s])``: ``(C, L)`` f32 from
+    ``qi (C, J, D)``, ``ki (L, D)`` and ``w (C, J)``; the products take their
+    operands as they come and accumulate in float32."""
+    pre = jnp.einsum("tjd,sd->tjs", qi, ki, preferred_element_type=jnp.float32)
+    return jnp.sum(w[:, :, None] * jax.nn.relu(pre), axis=1)
+
+
+@jax.named_scope("dsa.select")
+def select_top_k(scores, allowed, count):
+    """The ``count[t]`` positions of row ``t`` with the largest ``scores``
+    ``(C, L)`` f32 among those ``allowed`` ``(C, L)``, ties to the lower
+    position: bool ``(C, L)`` with exactly ``count[t]`` set in row ``t``
+    (``1 <= count[t] <=`` the row's allowed positions).  No sort: a float's
+    bits, the sign bit flipped (all of them for a negative number), order as
+    the floats do, so the ``count``-th largest is found bit by bit, 32 counts
+    over the row; among the scores equal to it the first ones make up the
+    number."""
+    scores = jnp.where(scores == 0, 0.0, scores)      # -0.0 is 0.0's equal
+    bits = lax.bitcast_convert_type(scores, jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    keys = jnp.where(allowed, keys, 0)                # under every number's
+
+    def narrow(i, kth):
+        trial = kth | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = jnp.sum(keys >= trial[:, None], axis=1) >= count
+        return jnp.where(enough, trial, kth)
+
+    kth = lax.fori_loop(0, 32, narrow, jnp.zeros(keys.shape[:1], jnp.uint32))
+    above, tied = keys > kth[:, None], keys == kth[:, None]
+    short = count - jnp.sum(above, axis=1)
+    return above | (tied & (jnp.cumsum(tied, axis=1) <= short[:, None]))
+
+
+def _chunk_rows(spec: SparseAttention, start, c: int, n_keys: int):
+    """For the ``c`` queries from ``start`` on against keys ``0..n_keys``:
+    which keys are not later than the query ``(c, n_keys)``, and how many
+    each query selects ``(c,)``."""
+    tq = start + jnp.arange(c, dtype=jnp.int32)
+    causal = jnp.arange(n_keys, dtype=jnp.int32)[None, :] <= tq[:, None]
+    return causal, jnp.minimum(tq + 1, spec.top_k)
+
+
+def _sparse_chunk(spec: SparseAttention, k, v, ki, start, q, qi, w):
+    """One chunk of queries ``q (C, H, d)`` (the first at position ``start``)
+    against the keys so far ``k, v (L, G, d)``: ``(output (C, H, d), the
+    chunk's sum of index losses)``."""
+    c, h, d = q.shape
+    n_keys, g = k.shape[:2]
+    causal, count = _chunk_rows(spec, start, c, n_keys)
+    scores = index_scores(qi, ki, w)
+    chosen = select_top_k(lax.stop_gradient(scores), causal, count)
+    # (KV head, query, query head of the group, key): the product's own order
+    s = jnp.einsum("tgrd,sgd->gtrs", q.reshape(c, g, h // g, d), k,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    s = jnp.where(chosen[None, :, None, :], s, _MASKED)
+    # exp once, in the values' dtype for the product; the rows' sums divide
+    # the product's output (C x H x d numbers, not C x H x L probabilities)
+    e = jnp.exp(s - lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+    inv = 1.0 / jnp.sum(e, axis=-1)                           # (G, C, R)
+    e = e.astype(v.dtype)
+    out = jnp.einsum("gtrs,sgd->tgrd", e, v, preferred_element_type=jnp.float32
+                     ) * inv.transpose(1, 0, 2)[..., None]
+    with jax.named_scope("dsa.index_loss"):
+        # what the heads attend to, summed: the indexer's target, a constant
+        target = lax.stop_gradient(jnp.einsum(
+            "gtrs,gtr->ts", e, inv, preferred_element_type=jnp.float32) / h)
+        logp = jax.nn.log_softmax(jnp.where(chosen, scores, _MASKED), axis=-1)
+        loss = jnp.sum(jnp.where(
+            chosen, jax.scipy.special.xlogy(target, target) - target * logp, 0.0))
+    return out.reshape(c, h, d).astype(q.dtype), loss
+
+
+def _key_spans(spec: SparseAttention, t: int):
+    """``(chunk rows, [(first chunk, chunks, keys)])``: the sequence cut into
+    chunks of ``rows`` queries (one chunk where it does not divide), in at
+    most ``_KEY_SPANS`` runs of chunks that share a key length."""
+    c = spec.rows if t % spec.rows == 0 else t
+    n = t // c
+    per = -(-n // min(_KEY_SPANS, n))
+    return c, [(a, min(per, n - a), (min(a + per, n)) * c)
+               for a in range(0, n, per)]
+
+
+def _over_chunks(spec: SparseAttention, t: int, fn, chunked, whole):
+    """``fn(*[a[:keys] for a in whole], start, *[a's chunk for a in chunked])``
+    for every chunk of queries of one example, in order, one at a time; its
+    results stacked over the chunks."""
+    c, spans = _key_spans(spec, t)
+    outs = []
+    for first, n, keys in spans:
+        rows = slice(first * c, (first + n) * c)
+        xs = (jnp.arange(first, first + n, dtype=jnp.int32) * c,
+              *(a[rows].reshape(n, c, *a.shape[1:]) for a in chunked))
+        held = [a[:keys] for a in whole]
+        outs.append(lax.map(lambda x: fn(*held, *x), xs))
+    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
+
+
+def sparse_attend(spec: SparseAttention, q, k, v, qi, ki, w):
+    """``q (B, T, H, d)`` over ``k, v (B, T, G, d)`` under the selection the
+    indexer's ``qi, ki, w`` make: ``(output (B, T, H, d), index loss (B,))``,
+    the loss a mean over the example's positions.  One example and one chunk
+    of queries at a time, each chunk recomputed in the backward pass: no
+    more than ``rows x T`` scores a head are ever held."""
+    t = q.shape[1]
+    chunk = jax.checkpoint(functools.partial(_sparse_chunk, spec))
+
+    def example(args):
+        q, k, v, qi, ki, w = args
+        out, loss = _over_chunks(spec, t, chunk, (q, qi, w), (k, v, ki))
+        return out.reshape(q.shape), jnp.sum(loss) / t
+
+    return lax.map(example, (q, k, v, qi, ki, w))
+
+
+def sparse_attention_mixer(spec: SparseAttention, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> ``(the mixer's output (B, T, E),
+    the indexer's loss (B,))``."""
+    from ..ops.pallas.attention import attention_candidate
+
+    b, t, _ = u.shape
+    h, g, d = spec.n_heads, spec.n_kv_heads, spec.head_dim
+    METRICS.increment("dsa.layers")      # per layer per trace, as attention.path
+    with jax.named_scope("qkv_proj"):
+        x = u.astype(dt)
+        q = _project(x, p["wq"], dt).reshape(b, t, h, d)
+        k = _project(x, p["wk"], dt).reshape(b, t, g, d)
+        v = _project(x, p["wv"], dt).reshape(b, t, g, d)
+        if spec.qk_norm:
+            q = rms_norm(q, p["q_norm"], spec.norm_eps)      # over each head
+            k = rms_norm(k, p["k_norm"], spec.norm_eps)
+        q, k = _rope(q, spec.rope_theta, d), _rope(k, spec.rope_theta, d)
+        q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
+        qi, ki, w = index_inputs(spec, p["index"], u, dt)
+    with jax.named_scope("attention"):
+        # no registered kernel takes a selection: always the XLA path, asked
+        # for where every block asks so that attention.path.* counts it
+        attention_candidate(t, h, d, asked="ring")
+        out, loss = sparse_attend(spec, q, k, v, qi, ki, w)
+        # kept across a checkpointed block (run_layers' policy): the block's
+        # recomputed forward then stops at the chunks' inputs, and each chunk
+        # is computed twice (forward, and once more in its own backward)
+        out, loss = checkpoint_name(out, KEPT[0]), checkpoint_name(loss, KEPT[1])
+    with jax.named_scope("attn_out"):
+        return jnp.einsum("btf,fd->btd", out.reshape(b, t, h * d),
+                          p["wo"].astype(dt)), loss
+
+
+def sparse_selection(spec: SparseAttention, p, u, dt, reduce=None):
+    """What the mixer selects for normed activations ``u`` (B, T, E): bool
+    ``(B, T, T)``, row ``t`` its query's keys; or, with ``reduce(chosen (C,
+    L), causal (C, L), start)``, that function's results stacked ``(B,
+    chunks, ...)`` with no ``T x T`` array made.  The indexer's half of the
+    mixer alone, outside any step: for comparisons and counters."""
+    t = u.shape[1]
+    qi, ki, w = index_inputs(spec, p["index"], u, dt)
+    if reduce is not None:            # counted over whole tiles of queries
+        spec = dataclasses.replace(spec, rows=spec.q_chunk)
+
+    def chunk(ki, start, qi, w):
+        causal, count = _chunk_rows(spec, start, qi.shape[0], ki.shape[0])
+        chosen = select_top_k(index_scores(qi, ki, w), causal, count)
+        if reduce is not None:
+            return reduce(chosen, causal, start)
+        return jnp.pad(chosen, ((0, 0), (0, t - ki.shape[0])))
+
+    out = lax.map(lambda a: _over_chunks(spec, t, chunk, (a[0], a[2]), (a[1],)),
+                  (qi, ki, w))
+    return out if reduce is not None else out.reshape(u.shape[0], t, t)
+
+
 # --------------------------------------------------------------------------- ffn
 
 @dataclasses.dataclass(frozen=True)
 class MoE:
-    """Top-1 mixture of gated-SiLU experts behind a router MLP."""
+    """Top-k mixture of gated-SiLU experts behind a router MLP (or, with
+    ``router_hidden = 0``, one linear layer)."""
     n_experts: int = 16              # the router's width, as published
     held: tuple[int, int] = (0, 8)   # (first, count): this chip's experts
     router_hidden: int = 256
     d_ff: int = 2048
+    top_k: int = 1                   # experts a token
+    renormalize: bool = False        # the chosen weights divided by their sum
     key = "moe"
     post_norm = False
 
     def init(self, key, d_model: int, dtype) -> Params:
         r, f, n = self.router_hidden, self.d_ff, self.held[1]
+        assert self.top_k > 1 or not self.renormalize, "one weight is its own sum"
         ks = jax.random.split(key, 7)
+        router = {
+            "wd": _normal(ks[0], (d_model, r), d_model ** -0.5, dtype),
+            "w1": _normal(ks[1], (r, r), r ** -0.5, dtype),
+            "b1": jnp.zeros((r,), dtype),
+            "w2": _normal(ks[2], (r, r), r ** -0.5, dtype),
+            "b2": jnp.zeros((r,), dtype),
+            "w3": _normal(ks[3], (r, self.n_experts), r ** -0.5, dtype),
+        } if r else {
+            "w": _normal(ks[0], (d_model, self.n_experts), d_model ** -0.5, dtype)}
         return {
-            "router": {
-                "wd": _normal(ks[0], (d_model, r), d_model ** -0.5, dtype),
-                "w1": _normal(ks[1], (r, r), r ** -0.5, dtype),
-                "b1": jnp.zeros((r,), dtype),
-                "w2": _normal(ks[2], (r, r), r ** -0.5, dtype),
-                "b2": jnp.zeros((r,), dtype),
-                "w3": _normal(ks[3], (r, self.n_experts), r ** -0.5, dtype),
-            },
+            "router": router,
             "wg": _normal(ks[4], (n, d_model, f), d_model ** -0.5, dtype),
             "wu": _normal(ks[5], (n, d_model, f), d_model ** -0.5, dtype),
             "wdn": _normal(ks[6], (n, f, d_model), f ** -0.5, dtype),
@@ -299,41 +584,47 @@ class MoE:
 
 
 @jax.named_scope("moe.router")
-def route(r, u):
-    """``u`` (N, E) -> ``(gate (N,) f32, e (N,) int32)``: the router MLP in
+def route(spec: MoE, r, u):
+    """``u`` (N, E) -> ``(gate f32, e int32)``, each ``(N,)`` for a top-1
+    layer and ``(top_k, N)``, one row a choice, above it: the router in
     float32 whatever the compute dtype, so that a near-tie is decided by the
     activations and not by the router's own rounding."""
     hi = lax.Precision.HIGHEST
     u = u.astype(jnp.float32)
-    p = jnp.dot(u, r["wd"].astype(jnp.float32), precision=hi)
-    a = jax.nn.gelu(jnp.dot(p, r["w1"].astype(jnp.float32), precision=hi)
-                    + r["b1"])
-    b = jax.nn.gelu(jnp.dot(a, r["w2"].astype(jnp.float32), precision=hi)
-                    + r["b2"])
-    pi = jax.nn.softmax(
-        jnp.dot(b, r["w3"].astype(jnp.float32), precision=hi), axis=-1)
-    e = jnp.argmax(pi, axis=-1).astype(jnp.int32)
-    return jnp.take_along_axis(pi, e[:, None], axis=-1)[:, 0], e
+    if spec.router_hidden:
+        p = jnp.dot(u, r["wd"].astype(jnp.float32), precision=hi)
+        a = jax.nn.gelu(jnp.dot(p, r["w1"].astype(jnp.float32), precision=hi)
+                        + r["b1"])
+        b = jax.nn.gelu(jnp.dot(a, r["w2"].astype(jnp.float32), precision=hi)
+                        + r["b2"])
+        pi = jax.nn.softmax(
+            jnp.dot(b, r["w3"].astype(jnp.float32), precision=hi), axis=-1)
+    else:
+        pi = jax.nn.softmax(
+            jnp.dot(u, r["w"].astype(jnp.float32), precision=hi), axis=-1)
+    if spec.top_k == 1:
+        e = jnp.argmax(pi, axis=-1).astype(jnp.int32)
+        return jnp.take_along_axis(pi, e[:, None], axis=-1)[:, 0], e
+    gate, e = lax.top_k(pi, spec.top_k)
+    if spec.renormalize:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return gate.T, e.T.astype(jnp.int32)
 
 
 def expert_counts(spec: MoE, e):
-    """Tokens per expert over ALL ``n_experts``, from the choices ``e``."""
+    """(Token, choice) pairs per expert over ALL ``n_experts``, from the
+    choices ``e``."""
     return jnp.zeros((spec.n_experts,), jnp.int32).at[e.reshape(-1)].add(1)
 
 
-@jax.named_scope("ffn")
-def moe_ffn(spec: MoE, p, u, dt):
-    """Normed activations ``u`` (B, T, E) -> ``(this chip's part of the
-    layer's output (B, T, E), e (B, T))``.  Every token of the batch is
-    grouped at once: tokens are sorted by the held expert they chose (those
-    routed elsewhere last), three grouped matmuls run over the sorted rows,
-    and the rows go back to their places weighted by the router's
-    probability.  No capacity, no dropped token, whatever the imbalance."""
-    shape = u.shape
-    u = u.reshape(-1, shape[-1]).astype(dt)
+def _one_choice(spec: MoE, p, u, gate, e, dt):
+    """This chip's experts' outputs ``(N, E)`` f32 for tokens ``u (N, E)``
+    each sent to ONE expert ``e (N,)`` with weight ``gate (N,)``: tokens are
+    sorted by the held expert they chose (those routed elsewhere last), three
+    grouped matmuls run over the sorted rows, and the rows go back to their
+    places weighted.  No capacity, no dropped token, whatever the imbalance."""
     n = u.shape[0]
     first, count = spec.held
-    gate, e = route(p["router"], u)
     with jax.named_scope("moe.dispatch"):
         local = (e >= first) & (e < first + count)
         slot = jnp.where(local, e - first, count)      # elsewhere: sorted last
@@ -358,8 +649,29 @@ def moe_ffn(spec: MoE, p, u, dt):
                   * grouped(xs, p["wu"])).astype(dt)
         ys = grouped(hidden, p["wdn"])
     with jax.named_scope("moe.dispatch"):
-        y = ys[back] * jnp.where(local, gate, 0.0)[:, None]
-    return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
+        return ys[back] * jnp.where(local, gate, 0.0)[:, None]
+
+
+@jax.named_scope("ffn")
+def moe_ffn(spec: MoE, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> ``(this chip's part of the
+    layer's output (B, T, E), e (B, T) or, above top-1, (B, T, top_k))``.
+    Every token of the batch is grouped at once.  A token's ``top_k`` choices
+    go through ``_one_choice`` one after the other (a scan, each choice
+    recomputed in the backward pass) and add up: a top-1 layer is the one
+    choice, with no loop around it, and a choice that lives on another chip
+    adds zero here."""
+    shape = u.shape
+    u = u.reshape(-1, shape[-1]).astype(dt)
+    gate, e = route(spec, p["router"], u)
+    if spec.top_k == 1:
+        y = _one_choice(spec, p, u, gate, e, dt)
+        return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
+    experts = {name: p[name] for name in ("wg", "wu", "wdn")}
+    one = jax.checkpoint(functools.partial(_one_choice, spec, dt=dt))
+    y, _ = lax.scan(lambda acc, ge: (acc + one(experts, u, *ge), None),
+                    jnp.zeros(u.shape, jnp.float32), (gate, e))
+    return y.astype(dt).reshape(shape), e.T.reshape(*shape[:-1], spec.top_k)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -392,7 +704,8 @@ def gated_mlp(spec: GatedMLP, p, u, dt):
 
 
 #: spec class -> the function that runs it
-MIXERS = {CCA: cca_mixer, Attention: attention_mixer}
+MIXERS = {CCA: cca_mixer, Attention: attention_mixer,
+          SparseAttention: sparse_attention_mixer}
 FFNS = {MoE: moe_ffn, GatedMLP: gated_mlp}
 
 
@@ -446,47 +759,61 @@ def rms_norm(x, w, eps):
 
 
 def block(lp, x, cfg: HybridConfig, i: int):
-    """Layer ``i``: ``(x + mixer + ffn, the ffn's expert choices)``, each
-    half's output normed before it is added where its spec says so."""
+    """Layer ``i``: ``(x + mixer + ffn, the ffn's expert choices, the mixer's
+    own loss (B,) or None)``, each half's output normed before it is added
+    where its spec says so."""
     mixer, ffn = cfg.layers[i]
     dt, eps = cfg.base.dtype, cfg.norm_eps
     a = MIXERS[type(mixer)](mixer, lp[mixer.key], rms_norm(x, lp["norm1"], eps), dt)
+    a, aux = a if mixer.aux_loss else (a, None)
     x = x + (rms_norm(a, lp["norm1_post"], eps) if mixer.post_norm else a)
     y, e = FFNS[type(ffn)](ffn, lp[ffn.key], rms_norm(x, lp["norm2"], eps), dt)
-    return x + (rms_norm(y, lp["norm2_post"], eps) if ffn.post_norm else y), e
+    return (x + (rms_norm(y, lp["norm2_post"], eps) if ffn.post_norm else y),
+            e, aux)
 
 
-def encode_steps(params, tokens, cfg: HybridConfig):
+def run_layers(params, tokens, cfg: HybridConfig):
     """``tokens`` (B, T) -> ``(every loop step's final normed hidden
-    (n_loops, B, T, E), [e per layer, each (n_loops, B, T) or None])``.  The
-    loop is one ``lax.scan`` whose body holds each layer once; ``n_loops == 1``
-    is the layers in line, with no loop around them."""
+    (n_loops, B, T, E), [e per layer, each (n_loops, B, T[, top_k]) or None],
+    the layers' own losses summed (B,), or None where no mixer has one)``.
+    The loop is one ``lax.scan`` whose body holds each layer once;
+    ``n_loops == 1`` is the layers in line, with no loop around them."""
     with jax.named_scope("embed"):
         x = jnp.take(params["tok_embed"], tokens, axis=0).astype(cfg.base.dtype)
     fn = block
     if cfg.base.remat:
-        fn = jax.checkpoint(block, static_argnums=(2, 3))
+        fn = jax.checkpoint(
+            block, static_argnums=(2, 3),
+            policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
     # counted while tracing, as attention.path.*: what one trace of the model
     # holds (layers) against what a step runs (layer applications)
     METRICS.increment("loop.steps", cfg.n_loops)
     METRICS.increment("loop.layer_applications", cfg.n_loops * len(cfg.layers))
 
     def step(x):
-        choices = []
+        choices, own = [], None
         for i, lp in enumerate(params["layers"]):
-            x, e = fn(lp, x, cfg, i)
+            x, e, aux = fn(lp, x, cfg, i)
             choices.append(e)
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), choices
+            if aux is not None:
+                own = aux if own is None else own + aux
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), choices, own
 
     if cfg.n_loops == 1:
-        h, choices = step(x)
-        return h[None], [None if e is None else e[None] for e in choices]
+        h, choices, own = step(x)
+        return h[None], [None if e is None else e[None] for e in choices], own
 
     def body(x, _):
-        h, choices = step(x)
-        return h, (h, choices)     # the normed state is the next step's input
+        h, choices, own = step(x)
+        return h, (h, choices, own)  # the normed state is the next step's input
 
-    return lax.scan(body, x, None, length=cfg.n_loops)[1]
+    hs, choices, own = lax.scan(body, x, None, length=cfg.n_loops)[1]
+    return hs, choices, None if own is None else own.sum(axis=0)
+
+
+def encode_steps(params, tokens, cfg: HybridConfig):
+    """``run_layers`` without the layers' own losses: ``(hs, choices)``."""
+    return run_layers(params, tokens, cfg)[:2]
 
 
 def encode(params, tokens, cfg: HybridConfig):
@@ -506,12 +833,22 @@ def forward(params, tokens, cfg: HybridConfig):
                           head.astype(cfg.base.dtype)).astype(jnp.float32)
 
 
+def loss_parts(params, tokens, targets, cfg: HybridConfig):
+    """``(each example's mean cross entropy (B,), the layers' own losses
+    summed (B,) or None)``: the two parts of the objective, whose gradients
+    never meet (a mixer's own loss reads its inputs behind a
+    ``stop_gradient``)."""
+    hs, _, own = run_layers(params, tokens, cfg)
+    return lm_head_loss(params, hs[-1], targets, cfg.base, per_example=True), own
+
+
 def lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
-    """Each example's mean cross entropy, ``(B,)``, with the whole batch's
-    tokens grouped together in the expert layers and chunked together in the
-    head: the loss a ``DataParallelTrainer(per_example_loss=True)`` takes."""
-    h, _ = encode(params, tokens, cfg)
-    return lm_head_loss(params, h, targets, cfg.base, per_example=True)
+    """Each example's mean cross entropy, ``(B,)``, plus the layers' own
+    losses where a mixer has one, with the whole batch's tokens grouped
+    together in the expert layers and chunked together in the head: the loss
+    a ``DataParallelTrainer(per_example_loss=True)`` takes."""
+    xent, own = loss_parts(params, tokens, targets, cfg)
+    return xent if own is None else xent + own
 
 
 def lm_loss(params, tokens, targets, cfg: HybridConfig):
@@ -586,8 +923,61 @@ def publish_exit_stats(mass, tokens_total: float) -> float:
     return steps / max(float(tokens_total), 1.0)
 
 
+def selections(params, tokens, cfg: HybridConfig):
+    """What every ``SparseAttention`` layer selects for ``tokens`` (B, T)
+    under ``params``: ``[bool (B, T, T) per layer]``, row ``t`` its query's
+    keys.  One forward pass, called outside the step."""
+    return _selection_pass(params, tokens, cfg, None)
+
+
+def selection_stats(params, tokens, cfg: HybridConfig):
+    """``(layers, 4)`` int32 over ``tokens`` (B, T): pairs selected, pairs
+    ``s <= t``, ``(q_chunk, kv_chunk)`` tiles on or below the diagonal that
+    hold no selected key, and such tiles in all.  One forward pass, called
+    outside the step; no ``T x T`` array is made."""
+    return jnp.stack([a.sum(axis=(0, 1))
+                      for a in _selection_pass(params, tokens, cfg, _tile_counts)])
+
+
+def _tile_counts(spec: SparseAttention, chosen, causal, start):
+    c, n_keys = chosen.shape
+    tile = spec.kv_chunk if n_keys % spec.kv_chunk == 0 else n_keys
+    below = jnp.arange(0, n_keys, tile) < start + c
+    empty = below & ~chosen.reshape(c, -1, tile).any(axis=(0, 2))
+    return jnp.stack([chosen.sum(), causal.sum(), empty.sum(), below.sum()]
+                     ).astype(jnp.int32)
+
+
+def _selection_pass(params, tokens, cfg: HybridConfig, reduce):
+    """``sparse_selection`` of every layer, each on the input the layers
+    before it give."""
+    with jax.named_scope("embed"):
+        x = jnp.take(params["tok_embed"], tokens, axis=0).astype(cfg.base.dtype)
+    out = []
+    for i, lp in enumerate(params["layers"]):
+        mixer = cfg.layers[i][0]
+        out.append(sparse_selection(
+            mixer, lp[mixer.key], rms_norm(x, lp["norm1"], cfg.norm_eps),
+            cfg.base.dtype, reduce and functools.partial(reduce, mixer)))
+        x = block(lp, x, cfg, i)[0]
+    return out
+
+
+def publish_selection_stats(counts) -> dict:
+    """Add ``counts`` (``selection_stats`` summed over any batches, already on
+    the host) to the counters ``dsa.pairs_selected``, ``dsa.pairs_causal``,
+    ``dsa.tiles_empty`` and ``dsa.tiles_total``; returns the two shares."""
+    selected, causal, empty, tiles = (float(v) for v in counts.sum(axis=0))
+    METRICS.increment("dsa.pairs_selected", selected)
+    METRICS.increment("dsa.pairs_causal", causal)
+    METRICS.increment("dsa.tiles_empty", empty)
+    METRICS.increment("dsa.tiles_total", tiles)
+    return {"selected_share": selected / max(causal, 1.0),
+            "empty_tile_share": empty / max(tiles, 1.0)}
+
+
 def routing_stats(params, tokens, cfg: HybridConfig):
-    """Tokens per expert, ``(layers, n_experts)`` int32, for ``tokens``
+    """(Token, choice) pairs per expert, ``(layers, n_experts)`` int32, for ``tokens``
     (B, T) under ``params``: one forward pass, called outside the step."""
     _, choices = encode(params, tokens, cfg)
     return jnp.stack([expert_counts(cfg.layers[i][1], e)
@@ -621,8 +1011,9 @@ def place_experts(params, tokens, cfg: HybridConfig):
             here = first // n
             order = sum(held[:here] + [held[here]] + held[here + 1:], [])
             held_here = layers[i][ffn.key]
-            router = dict(held_here["router"],
-                          w3=held_here["router"]["w3"][:, jnp.asarray(order)])
+            last = "w3" if ffn.router_hidden else "w"    # the output layer
+            router = dict(held_here["router"], **{
+                last: held_here["router"][last][:, jnp.asarray(order)]})
             layers[i] = dict(layers[i], **{ffn.key: dict(held_here, router=router)})
     return dict(params, layers=layers)
 
@@ -630,7 +1021,8 @@ def place_experts(params, tokens, cfg: HybridConfig):
 def publish_routing_stats(counts, cfg: HybridConfig) -> dict:
     """Add ``counts`` (``routing_stats`` summed over any batches, already on
     the host) to the counters ``moe.tokens_total``, ``moe.tokens_local`` and
-    ``moe.expert_load.l<layer>.e<expert>`` (held experts only); returns the
+    ``moe.expert_load.l<layer>.e<expert>`` (held experts only; the unit is a
+    (token, choice) pair, a token where the layer is top-1); returns the
     local share and the held experts' largest load over their mean."""
     first, n = cfg.layers[0][1].held
     total = float(counts.sum())

@@ -69,6 +69,33 @@ def pytest_configure(config):
         "explicitly, not inherit them from promotion rules)")
 
 
+#: Three tests of ``tests/benchmark_tests/test_benchmark_looped.py`` (PR 32)
+#: hold PR 32's entries to the LAST place of ``BENCHMARK.json``'s lists
+#: (``configs[-1]``, ``workloads[-1]``, ``per_layer[-3:]``).  The manifest's
+#: rule is that a PR APPENDS its entries, and that a file the benchmark
+#: already has is edited by no PR but a ``benchmark`` one: so the next PR that
+#: adds a configuration (PR 34) can neither keep these asserts true nor
+#: repair them.  They are expected to fail from PR 34 on; what they hold
+#: besides the position (the looped configuration's published numbers, its
+#: cell's sizes, its metrics) is tested again, without the position, in
+#: ``tests/benchmark_tests/test_benchmark_sparse.py``.  A ``benchmark`` PR
+#: takes the three asserts out of the accepted file and this list with them
+#: (PERF.md section 7).
+LAST_PLACE_ASSERTS = {
+    "tests/benchmark_tests/test_benchmark_looped.py::" + name for name in (
+        "test_configuration_keeps_the_published_numbers",
+        "test_cell_is_what_the_issue_names",
+        "test_cell_reports_the_common_metrics_and_its_own")}
+
+
+def pytest_collection_modifyitems(config, items):
+    for item in items:
+        if item.nodeid in LAST_PLACE_ASSERTS:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="asserts that PR 32's entries are the "
+                "LAST of BENCHMARK.json's lists; PR 34 appended its own"))
+
+
 @pytest.fixture(autouse=True)
 def _transfer_guard_marker(request):
     """Enforce the ``no_implicit_transfers`` marker: the whole test body

@@ -283,3 +283,51 @@ def test_looped_step_compiles_each_layer_once_at_4096(one_chip, monkeypatch):
     assert not re.findall(rf"(?:f32|bf16)\[[0-9,]*{t},{t}\]", hlo)
     assert re.search(r'op_name="[^"]*lm_head\.recompute', hlo)
     assert re.search(r'op_name="[^"]*loop\.exit', hlo)
+
+
+def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip):
+    """One layer of the sparse-attention family at its published widths (32
+    query heads over 4 KV heads of 128, a 16 x 64 indexer, top-2048; 16 of 128
+    experts of width 768, top-8 behind a linear router) at 1 x 8192, forward
+    and backward, compiled for the chip: the exact selection (a counting loop
+    over the scores' bits, no sort) and the chunks of 256 queries compile, no
+    array holds 8192 x 8192 scores or index scores, every ``dsa.*`` scope
+    sits under the sublayer the readers know with its whole path, and the
+    choices go through the compiler's ragged products inside one loop."""
+    import re
+
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.models.transformer import TransformerConfig
+    from deeplearning4j_tpu.observability import METRICS
+
+    t = 8192
+    base = TransformerConfig(vocab_size=2048, d_model=2048, n_heads=32,
+                             n_kv_heads=4, n_layers=1, d_ff=768, max_len=t,
+                             causal=True, tie_embeddings=False, remat=True,
+                             xent_chunk=2048)
+    cfg = hybrid.HybridConfig(base=base, norm_eps=1e-6, layers=((
+        hybrid.SparseAttention(),
+        hybrid.MoE(128, (0, 16), 0, 768, top_k=8, renormalize=True)),))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg)))
+    tokens = jax.ShapeDtypeStruct((1, t), I32, sharding=one_chip)
+    before = METRICS.snapshot()["counters"]
+    hlo = jax.jit(jax.value_and_grad(
+        lambda p, x, y: hybrid.lm_loss_per_example(p, x, y, cfg).mean())).lower(
+            params, tokens, tokens).compile().as_text()
+    after = METRICS.snapshot()["counters"]
+    moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "dsa.layers", "attention.path.kernel", "attention.path.xla")}
+    assert moved == {"dsa.layers": 1, "attention.path.kernel": 0,
+                     "attention.path.xla": 1}
+    assert not re.findall(rf"(?:f32|bf16|u32|pred)\[[0-9,]*{t},{t}\]", hlo)
+    assert not re.search(r'op_name="[^"]*attention\.fused', hlo)   # no kernel
+    assert not re.search(r' sort\([^\n]*dsa\.', hlo) and "ragged-dot" in hlo
+    paths = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for inner, outer in (("dsa.index_proj", "qkv_proj"), ("dsa.select", "attention"),
+                         ("dsa.index_scores", "attention"),
+                         ("dsa.index_loss", "attention"), ("moe.dispatch", "ffn")):
+        mine = [p for p in paths if f"/{inner}/" in p and p.startswith("jit(")]
+        assert mine and all(outer in p.split(inner)[0] for p in mine), (
+            inner, mine[:2])

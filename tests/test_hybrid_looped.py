@@ -198,7 +198,7 @@ def test_one_loop_step_without_a_gate_is_one_plain_pass():
     toks, tgts = batch()
     x = params["tok_embed"][toks]
     for i, lp in enumerate(params["layers"]):
-        x, e = hybrid.block(lp, x, cfg, i)
+        x, e, _ = hybrid.block(lp, x, cfg, i)
         assert e is None
     want = hybrid.rms_norm(x, params["final_norm"], cfg.norm_eps)
     got, choices = hybrid.encode(params, toks, cfg)
@@ -223,7 +223,7 @@ def test_loop_steps_chain_through_the_closing_norm(case):
     x = params["tok_embed"][toks]
     for t in range(LOOPS):
         for i, lp in enumerate(params["layers"]):
-            x, _ = hybrid.block(lp, x, once, i)
+            x, _, _ = hybrid.block(lp, x, once, i)
         x = hybrid.rms_norm(x, params["final_norm"], cfg.norm_eps)
         np.testing.assert_allclose(hs[t], x, rtol=1e-4, atol=1e-5)
     last, _ = hybrid.encode(params, toks, cfg)
@@ -408,11 +408,13 @@ def test_any_mixer_goes_with_any_ffn(pair, n_loops, tied):
 
 def test_registries_hold_two_specs_each_and_block_names_neither():
     import inspect
-    assert set(hybrid.MIXERS) == {hybrid.CCA, hybrid.Attention}
+    # (two each when PR 32 wrote this; PR 34 added the sparse mixer)
+    assert set(hybrid.MIXERS) == {hybrid.CCA, hybrid.Attention,
+                                  hybrid.SparseAttention}
     assert set(hybrid.FFNS) == {hybrid.MoE, hybrid.GatedMLP}
     source = inspect.getsource(hybrid.block) + inspect.getsource(hybrid.init_params)
-    assert not re.search(r"cca|moe|attn|mlp", source, re.I)
-    assert len({s.key for s in (*hybrid.MIXERS, *hybrid.FFNS)}) == 4
+    assert not re.search(r"cca|moe|attn|mlp|dsa", source, re.I)
+    assert len({s.key for s in (*hybrid.MIXERS, *hybrid.FFNS)}) == 5
 
 
 # ------------------------------------------------ the head under token weights
