@@ -44,9 +44,18 @@ behind a linear router, the Qwen3-MoE family's):
   kI[s])`` over ``index_heads`` heads of ``index_dim``, all read from the
   normed hidden state behind a ``stop_gradient``.  The selection is exact
   (the k-th largest score by bisection on the scores' bits, ties to the lower
-  position) and a constant of the backward pass.  Computed ``rows``
-  queries at a time against the keys so far, each chunk checkpointed, on the
-  XLA path.  Beside its output the mixer yields the indexer's own loss: the
+  position) and a constant of the backward pass.  Both paths compute ``rows``
+  queries at a time against the keys so far (at most four key lengths a
+  sequence, ``_key_spans``), and on both the index scores and the selection
+  are XLA's, ``rows x keys`` at a time.  On a TPU, for the shapes they take,
+  the kernels of ``ops/pallas/sparse_attention.py`` are handed each chunk's
+  selection and do the scores, the masked softmax, the value product and the
+  heads' mean probabilities with one tile of one head's scores in VMEM at a
+  time (``_kernel_attend``, a ``custom_vjp`` whose backward makes the
+  selection and the indexer's target again, and the attention's forward pass
+  not).  Elsewhere (the CPU, other shapes) ``_sparse_chunk`` is the XLA
+  path: a chunk's scores of every head in HBM, each chunk checkpointed.
+  Beside its output the mixer yields the indexer's own loss: the
   divergence of the head-summed attention probabilities from the softmax of
   the index scores over the selected keys, which reaches the indexer's
   parameters alone, as the language-model loss reaches everything else alone.
@@ -310,7 +319,9 @@ class SparseAttention:
     top_k: int = 2048
     q_chunk: int = 512               # with kv_chunk, the tile of dsa.tiles_*
     kv_chunk: int = 512
-    rows: int = 256                  # queries the XLA path computes at a time
+    rows: int = 256                  # queries either path selects for and
+    #                                  attends from at a time (the kernels'
+    #                                  tile of queries; the XLA path's chunk)
     norm_eps: float = 1e-6           # of the head norms and the key LayerNorm
     key = "dsa"
     post_norm = False
@@ -340,12 +351,15 @@ class SparseAttention:
 
 
 #: what a checkpointed block keeps of a sparse mixer besides its input: the
-#: heads' outputs (B, T, H, d) and the index loss (B,)
-KEPT = ("dsa.out", "dsa.loss")
+#: heads' outputs (B, T, H, d), the index loss (B,) and, on the kernel's path,
+#: the rows' log-sum-exp (B, chunks, G, rows, H / G) its backward reads
+KEPT = ("dsa.out", "dsa.loss", "dsa.lse")
 #: stands for "not selected" in a row of scores: finite, so that 0 x it is 0
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
-#: key lengths a sequence's query chunks are computed at: chunk ``c`` needs
-#: the keys up to its own end, and a static shape serves a run of chunks
+#: key lengths a sequence's query chunks are computed at, on both paths:
+#: chunk ``c`` needs the keys up to its own end, and a static shape serves a
+#: run of chunks (inside a run the kernels skip the key blocks past a chunk's
+#: last query; the index scores and the XLA path compute them masked)
 _KEY_SPANS = 4
 
 
@@ -442,10 +456,16 @@ def _sparse_chunk(spec: SparseAttention, k, v, ki, start, q, qi, w):
         # what the heads attend to, summed: the indexer's target, a constant
         target = lax.stop_gradient(jnp.einsum(
             "gtrs,gtr->ts", e, inv, preferred_element_type=jnp.float32) / h)
-        logp = jax.nn.log_softmax(jnp.where(chosen, scores, _MASKED), axis=-1)
-        loss = jnp.sum(jnp.where(
-            chosen, jax.scipy.special.xlogy(target, target) - target * logp, 0.0))
+        loss = _index_loss(scores, chosen, target)
     return out.reshape(c, h, d).astype(q.dtype), loss
+
+
+def _index_loss(scores, chosen, target):
+    """A chunk's sum over its queries of the divergence of ``target`` (C, L)
+    from the softmax of the index ``scores`` over the ``chosen`` keys."""
+    logp = jax.nn.log_softmax(jnp.where(chosen, scores, _MASKED), axis=-1)
+    return jnp.sum(jnp.where(
+        chosen, jax.scipy.special.xlogy(target, target) - target * logp, 0.0))
 
 
 def _key_spans(spec: SparseAttention, t: int):
@@ -474,13 +494,21 @@ def _over_chunks(spec: SparseAttention, t: int, fn, chunked, whole):
     return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
 
 
-def sparse_attend(spec: SparseAttention, q, k, v, qi, ki, w):
+def sparse_attend(spec: SparseAttention, q, k, v, qi, ki, w, asked="auto"):
     """``q (B, T, H, d)`` over ``k, v (B, T, G, d)`` under the selection the
     indexer's ``qi, ki, w`` make: ``(output (B, T, H, d), index loss (B,))``,
     the loss a mean over the example's positions.  One example and one chunk
-    of queries at a time, each chunk recomputed in the backward pass: no
-    more than ``rows x T`` scores a head are ever held."""
-    t = q.shape[1]
+    of queries at a time, on the path ``attention_candidate`` answers (counted
+    as ``attention.path.*``; ``asked`` as there): the Pallas kernels that take
+    the selection, where no score leaves VMEM, or the XLA path, each chunk
+    recomputed in the backward pass so that no more than ``rows x T`` scores
+    a head are ever held."""
+    from ..ops.pallas.attention import attention_candidate
+
+    t, h, d = q.shape[1:]
+    if attention_candidate(t, h, d, asked=asked,
+                           selection=(spec.n_kv_heads, _key_spans(spec, t)[0])):
+        return _kernel_attend(spec, q, k, v, qi, ki, w)
     chunk = jax.checkpoint(functools.partial(_sparse_chunk, spec))
 
     def example(args):
@@ -491,11 +519,115 @@ def sparse_attend(spec: SparseAttention, q, k, v, qi, ki, w):
     return lax.map(example, (q, k, v, qi, ki, w))
 
 
+def _kernel_chunk(spec: SparseAttention, k, v, ki, start, q, qi, w):
+    """``_sparse_chunk`` on the kernels that take the selection, heads merged
+    (``q (C, H*d)``, ``k, v (L, G*d)``): ``(output (C, H*d), the rows'
+    log-sum-exp, the chunk's sum of index losses)``."""
+    from ..ops.pallas import sparse_attention as kernel
+
+    c, g = q.shape[0], spec.n_kv_heads
+    causal, count = _chunk_rows(spec, start, c, k.shape[0])
+    scores = index_scores(qi, ki, w)
+    chosen = select_top_k(scores, causal, count)
+    out, lse = kernel.forward(q, k, v, chosen, start + c, kv_heads=g)
+    with jax.named_scope("dsa.index_loss"):
+        target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
+        loss = _index_loss(scores, chosen, target)
+    return out, lse, loss
+
+
+def _kernel_chunk_grads(spec: SparseAttention, k, v, ki, carry, x):
+    """One chunk's part of the backward pass: ``carry`` holds the f32 sums of
+    ``dk, dv (L, G*d)`` and ``dki (L, D)`` over the chunks so far, ``x`` the
+    chunk's ``(start, q, qi, w, out, lse, d out, d loss)``; the selection and
+    the indexer's target are made again, the kernel's forward pass is not."""
+    from ..ops.pallas import sparse_attention as kernel
+
+    dk, dv, dki = carry
+    start, q, qi, w, out, lse, d_out, d_loss = x
+    c, g = q.shape[0], spec.n_kv_heads
+    causal, count = _chunk_rows(spec, start, c, k.shape[0])
+    scores, index_grads = jax.vjp(index_scores, qi, ki, w)
+    chosen = select_top_k(scores, causal, count)
+    with jax.named_scope("dsa.index_loss"):
+        target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
+        d_scores = d_loss * jax.grad(_index_loss)(scores, chosen, target)
+    d_qi, d_ki, d_w = index_grads(d_scores)
+    d_q, dk, dv = kernel.backward(q, k, v, chosen, out, lse, d_out, dk, dv,
+                                  start + c, kv_heads=g)
+    return (dk, dv, dki + d_ki.astype(jnp.float32)), (d_q, d_qi, d_w)
+
+
+def _merged(x):
+    """``(T, heads, d)`` as ``(T, heads * d)``: how the kernels read it."""
+    return x.reshape(x.shape[0], -1)
+
+
+def _kernel_example(spec: SparseAttention, args):
+    """One example through ``_kernel_chunk``: ``(output (T, H, d), log-sum-exp
+    (chunks, G, rows, H / G), index loss)``."""
+    q, k, v, qi, ki, w = args
+    t = q.shape[0]
+    out, lse, loss = _over_chunks(
+        spec, t, functools.partial(_kernel_chunk, spec),
+        (_merged(q), qi, w), (_merged(k), _merged(v), ki))
+    return out.reshape(q.shape), lse, jnp.sum(loss) / t
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kernel_attend(spec: SparseAttention, q, k, v, qi, ki, w):
+    """``sparse_attend`` on the kernels.  Its backward pass is written out:
+    the forward kernel's ``(out, lse)`` are what it keeps (by name, so that a
+    checkpointed block keeps them too and recomputes no kernel), and the sums
+    over a sequence's chunks of ``dk``, ``dv`` and the index keys' gradient
+    are carried in float32 through the kernel's own accumulators."""
+    return _kernel_attend_fwd(spec, q, k, v, qi, ki, w)[0]
+
+
+def _kernel_attend_fwd(spec, q, k, v, qi, ki, w):
+    out, lse, loss = lax.map(functools.partial(_kernel_example, spec),
+                             (q, k, v, qi, ki, w))
+    out, lse = checkpoint_name(out, KEPT[0]), checkpoint_name(lse, KEPT[2])
+    return (out, loss), (q, k, v, qi, ki, w, out, lse)
+
+
+def _kernel_attend_bwd(spec, kept, cotangents):
+    t = kept[0].shape[1]
+    c, spans = _key_spans(spec, t)
+
+    def example(args):
+        *inputs, out, lse, d_out, d_loss = args
+        q, k, v, qi, ki, w = inputs
+        q2, k2, v2, out2, d_out2 = (_merged(a) for a in (q, k, v, out, d_out))
+        sums = (jnp.zeros(k2.shape, jnp.float32), jnp.zeros(v2.shape, jnp.float32),
+                jnp.zeros(ki.shape, jnp.float32))
+        per_chunk = []
+        for first, n, keys in spans:
+            rows = slice(first * c, (first + n) * c)
+            xs = (jnp.arange(first, first + n, dtype=jnp.int32) * c,
+                  *(a[rows].reshape(n, c, *a.shape[1:])
+                    for a in (q2, qi, w, out2)),
+                  lse[first:first + n], d_out2[rows].reshape(n, c, -1),
+                  jnp.full((n,), d_loss / t))
+            part, grads = lax.scan(
+                functools.partial(_kernel_chunk_grads, spec, k2[:keys], v2[:keys],
+                                  ki[:keys]),
+                tuple(a[:keys] for a in sums), xs)
+            sums = tuple(a.at[:keys].set(b) for a, b in zip(sums, part))
+            per_chunk.append(grads)
+        d_q, d_qi, d_w = (jnp.concatenate(a) for a in zip(*per_chunk))
+        return tuple(a.reshape(like.shape).astype(like.dtype)
+                     for a, like in zip((d_q, *sums[:2], d_qi, sums[2], d_w), inputs))
+
+    return lax.map(example, (*kept, *cotangents))
+
+
+_kernel_attend.defvjp(_kernel_attend_fwd, _kernel_attend_bwd)
+
+
 def sparse_attention_mixer(spec: SparseAttention, p, u, dt):
     """Normed activations ``u`` (B, T, E) -> ``(the mixer's output (B, T, E),
     the indexer's loss (B,))``."""
-    from ..ops.pallas.attention import attention_candidate
-
     b, t, _ = u.shape
     h, g, d = spec.n_heads, spec.n_kv_heads, spec.head_dim
     METRICS.increment("dsa.layers")      # per layer per trace, as attention.path
@@ -511,13 +643,12 @@ def sparse_attention_mixer(spec: SparseAttention, p, u, dt):
         q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
         qi, ki, w = index_inputs(spec, p["index"], u, dt)
     with jax.named_scope("attention"):
-        # no registered kernel takes a selection: always the XLA path, asked
-        # for where every block asks so that attention.path.* counts it
-        attention_candidate(t, h, d, asked="ring")
         out, loss = sparse_attend(spec, q, k, v, qi, ki, w)
         # kept across a checkpointed block (run_layers' policy): the block's
-        # recomputed forward then stops at the chunks' inputs, and each chunk
-        # is computed twice (forward, and once more in its own backward)
+        # recomputed forward then stops at the chunks' inputs.  On the XLA
+        # path each chunk is computed twice (forward, and once more in its own
+        # backward); on the kernel's the selection and the indexer's target
+        # are, and the attention's forward pass runs once
         out, loss = checkpoint_name(out, KEPT[0]), checkpoint_name(loss, KEPT[1])
     with jax.named_scope("attn_out"):
         return jnp.einsum("btf,fd->btd", out.reshape(b, t, h * d),

@@ -20,6 +20,9 @@ no kernel is ever adopted on faith:
 Kinds currently registered:
 
 - ``attention``             — ring (XLA) / fused (the default on a TPU)
+- ``sparse_attention``      — selected (attention over a selection of keys;
+  the default on a TPU for ``models/hybrid``'s sparse mixer, whose own XLA
+  path is the incumbent)
 - ``layernorm_residual``    — unfused (XLA) / fused
 - ``xent``                  — scan (XLA) / blocked
 - ``int8_matmul``           — f32 (XLA) / pallas_int8

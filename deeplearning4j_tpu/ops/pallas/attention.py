@@ -93,7 +93,8 @@ def kernel_takes(t: int, h: int, d: int) -> bool:
 
 
 def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
-                        asked: str = "auto") -> str | None:
+                        asked: str = "auto",
+                        selection: tuple[int, int] | None = None) -> str | None:
     """The registered attention candidate a block runs on ``(B, t, h, d)``
     queries, or ``None`` for the XLA path (``ring_attention``) — the one
     place that decides, for every block family.  By default the fused
@@ -103,11 +104,27 @@ def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
     how a parity test forces a side.  The sp ring is the collective and
     never a candidate.
 
+    A mixer that restricts each query to SELECTED keys says so with
+    ``selection=(KV heads, queries it selects for at a time)``.  Its
+    candidates are the ``sparse_attention`` kind's (``"selected"``, the
+    kernels of ``ops/pallas/sparse_attention.py``), by default where they
+    compile on the shapes they are built for, by name wherever the sequence
+    is whole chunks; ``None`` is the mixer's own XLA path.
+
     Counts its answer as ``attention.path.kernel`` / ``.xla``: callers ask
     once per block while tracing, so the counters tell a step on the kernel
     from one that fell back."""
     if asked == "ring" or n_sp != 1:
         name = None
+    elif selection is not None:
+        from . import sparse_attention
+
+        if asked == "auto":
+            on = (jax.default_backend() == "tpu"
+                  and sparse_attention.kernel_takes(t, h, d, *selection))
+            name = "selected" if on else None
+        else:
+            name = asked if t % selection[1] == 0 else None
     elif asked == "auto":
         on = jax.default_backend() == "tpu" and kernel_takes(t, h, d)
         name = "fused" if on else None

@@ -25,6 +25,7 @@ from typing import Any, Callable, Mapping
 #: degraded wheel
 _KERNEL_MODULES = (
     "deeplearning4j_tpu.ops.pallas.attention",
+    "deeplearning4j_tpu.ops.pallas.sparse_attention",
     "deeplearning4j_tpu.ops.pallas.layernorm",
     "deeplearning4j_tpu.ops.pallas.xent",
     "deeplearning4j_tpu.ops.pallas.matmul_int8",

@@ -285,28 +285,21 @@ def test_looped_step_compiles_each_layer_once_at_4096(one_chip, monkeypatch):
     assert re.search(r'op_name="[^"]*loop\.exit', hlo)
 
 
-def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip):
+def _sparse_layer(one_chip, t, **mixer):
     """One layer of the sparse-attention family at its published widths (32
     query heads over 4 KV heads of 128, a 16 x 64 indexer, top-2048; 16 of 128
-    experts of width 768, top-8 behind a linear router) at 1 x 8192, forward
-    and backward, compiled for the chip: the exact selection (a counting loop
-    over the scores' bits, no sort) and the chunks of 256 queries compile, no
-    array holds 8192 x 8192 scores or index scores, every ``dsa.*`` scope
-    sits under the sublayer the readers know with its whole path, and the
-    choices go through the compiler's ragged products inside one loop."""
-    import re
-
+    experts of width 768, top-8 behind a linear router) at 1 x ``t``, forward
+    and backward, compiled for the chip: ``(text, what the trace counted)``."""
     from deeplearning4j_tpu.models import hybrid
     from deeplearning4j_tpu.models.transformer import TransformerConfig
     from deeplearning4j_tpu.observability import METRICS
 
-    t = 8192
     base = TransformerConfig(vocab_size=2048, d_model=2048, n_heads=32,
                              n_kv_heads=4, n_layers=1, d_ff=768, max_len=t,
                              causal=True, tie_embeddings=False, remat=True,
                              xent_chunk=2048)
     cfg = hybrid.HybridConfig(base=base, norm_eps=1e-6, layers=((
-        hybrid.SparseAttention(),
+        hybrid.SparseAttention(**mixer),
         hybrid.MoE(128, (0, 16), 0, 768, top_k=8, renormalize=True)),))
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
@@ -317,17 +310,79 @@ def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip):
         lambda p, x, y: hybrid.lm_loss_per_example(p, x, y, cfg).mean())).lower(
             params, tokens, tokens).compile().as_text()
     after = METRICS.snapshot()["counters"]
-    moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
+    return hlo, {k: after.get(k, 0) - before.get(k, 0) for k in (
         "dsa.layers", "attention.path.kernel", "attention.path.xla")}
-    assert moved == {"dsa.layers": 1, "attention.path.kernel": 0,
-                     "attention.path.xla": 1}
-    assert not re.findall(rf"(?:f32|bf16|u32|pred)\[[0-9,]*{t},{t}\]", hlo)
-    assert not re.search(r'op_name="[^"]*attention\.fused', hlo)   # no kernel
+
+
+def _as_on_the_chip(monkeypatch):
+    from deeplearning4j_tpu.ops.pallas import registry
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(registry, "resolve_interpret",
+                        lambda interpret: bool(interpret))
+
+
+def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip, monkeypatch):
+    """The sparse layer at 1 x 16,384, the cell's length, compiled as
+    ``models/hybrid.py`` runs it on the chip: Mosaic takes the three kernels
+    that take the selection (forward once a span of chunks and NOT again in
+    the checkpointed block's backward, the backward once, the heads' mean
+    twice) inside VMEM; no f32 or bf16 array has a head extent, a chunk of
+    queries and a run of keys (the per-head scores are gone, forward and
+    backward), and none holds 16,384 x 16,384 of anything; the exact selection
+    is still a counting loop with no sort; every ``dsa.*`` scope sits under
+    the sublayer the readers know with its whole path, the heads' mean under
+    ``dsa.index_loss``; and the choices go through the compiler's ragged
+    products inside one loop."""
+    import math
+    import re
+
+    t, spans = 16384, 4
+    _as_on_the_chip(monkeypatch)
+    hlo, moved = _sparse_layer(one_chip, t)
+    assert moved == {"dsa.layers": 1, "attention.path.kernel": 1,
+                     "attention.path.xla": 0}
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "pallas_call" in line]
+    count = {name: sum(f"jit({name})" in p for p in calls)
+             for name in ("_sparse_fwd", "_sparse_bwd", "_sparse_headsum")}
+    assert count == {"_sparse_fwd": spans, "_sparse_bwd": spans,
+                     "_sparse_headsum": 2 * spans}, count
+    assert all("attention" in p for p in calls)
+    assert all(("dsa.index_loss" in p) == ("_sparse_headsum" in p) for p in calls)
+    # (the first span's 4096 keys are also 32 heads x 128: left out)
+    keys = {t * (i + 1) // spans for i in range(1, spans)}
+    for shape in set(re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", hlo)):
+        dims = [int(n) for n in shape.split(",")]
+        if 256 in dims and keys & set(dims):       # (.., queries, .., keys)
+            rest = math.prod(dims) // 256 // max(keys & set(dims))
+            # the indexer's 16 heads (or XLA's slices of them) may be there,
+            # the layer's 32 heads (4 x 8 on the XLA path) may not
+            assert rest % 32, shape
+    assert not re.findall(rf"(?:f32|bf16|u32|pred|s8)\[[0-9,]*{t},{t}\]", hlo)
+    assert not re.search(r'op_name="[^"]*attention\.fused', hlo)
     assert not re.search(r' sort\([^\n]*dsa\.', hlo) and "ragged-dot" in hlo
     paths = set(re.findall(r'op_name="([^"]*)"', hlo))
     for inner, outer in (("dsa.index_proj", "qkv_proj"), ("dsa.select", "attention"),
                          ("dsa.index_scores", "attention"),
                          ("dsa.index_loss", "attention"), ("moe.dispatch", "ffn")):
-        mine = [p for p in paths if f"/{inner}/" in p and p.startswith("jit(")]
+        # (a scope differentiated by hand reads jvp(<scope>), as the readers
+        # know: benchmark/trace_spans.py unwraps it)
+        mine = [p for p in paths if p.startswith("jit(")
+                and re.search(rf"[/(]{re.escape(inner)}[/)]", p)]
         assert mine and all(outer in p.split(inner)[0] for p in mine), (
             inner, mine[:2])
+        assert any("transpose(" in p for p in mine) or inner == "dsa.index_proj"
+
+
+def test_sparse_block_falls_back_where_the_kernel_does_not_take_the_shape(
+        one_chip, monkeypatch):
+    """Chunks of 192 queries are no whole blocks: the same layer at 1 x 3072
+    runs the mixer's XLA path on the chip too, and counts it."""
+    _as_on_the_chip(monkeypatch)
+    hlo, moved = _sparse_layer(one_chip, 3072, rows=192)
+    assert moved == {"dsa.layers": 1, "attention.path.kernel": 0,
+                     "attention.path.xla": 1}
+    assert "_sparse_fwd" not in hlo and "pallas_call" not in hlo

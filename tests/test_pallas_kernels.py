@@ -46,7 +46,8 @@ def _close(a, b, dtype):
 def test_registry_kinds_and_candidates_complete():
     assert registry.kinds() == ["attention", "int8_matmul",
                                 "layernorm_residual", "paged_attention",
-                                "paged_attention_int8", "xent"]
+                                "paged_attention_int8", "sparse_attention",
+                                "xent"]
     assert [c.name for c in registry.candidates("attention")] == [
         "fused", "ring"]
     # every pallas candidate ships a reference and documented tolerances
@@ -148,6 +149,22 @@ def _attention_check(cand):
                                   argnums=(0, 1, 2))}
 
 
+def _sparse_attention_check(cand):
+    """One chunk of 64 queries at position 128 over 192 keys, 4 heads over 2
+    KV heads, a random third of the causal keys selected."""
+    rng = np.random.default_rng(6)
+    q = jnp.asarray(rng.standard_normal((64, 4, 32)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((192, 2, 32)), jnp.float32)
+            for _ in range(2))
+    causal = np.arange(192)[None, :] <= 128 + np.arange(64)[:, None]
+    chosen = causal & (rng.random((64, 192)) < 0.33)
+    chosen[:, 0] = True
+    chosen = jnp.asarray(chosen)
+    fn, ref = (lambda *a, f=f: f(*a, chosen) for f in (cand.fn, cand.reference))
+    return {"max_err": _max_abs(fn(q, k, v), ref(q, k, v)),
+            "grad_err": _grad_err(fn, ref, q, k, v, argnums=(0, 1, 2))}
+
+
 def _ln_check(cand):
     rng = np.random.default_rng(1)
     x, r = (jnp.asarray(rng.standard_normal((101, 64)), jnp.float32)
@@ -197,6 +214,7 @@ def _paged_int8_check(cand):
 
 _CHECKS = {
     ("attention", "fused"): _attention_check,
+    ("sparse_attention", "selected"): _sparse_attention_check,
     ("layernorm_residual", "fused"): _ln_check,
     ("xent", "blocked"): _xent_check,
     ("int8_matmul", "pallas_int8"): _int8_check,

@@ -66,9 +66,12 @@ def _cases():
     pkq, pks = kvq.requantize_pool(pk, s0, jnp.int8)
     pvq, pvs = kvq.requantize_pool(pv, s0, jnp.int8)
 
+    chosen = jnp.tril(jnp.ones((s["T"], s["T"]), bool))
     calls = {
         ("attention", None): (lambda fn: fn(q, kk, v, causal=True),
                               (q, kk, v, q)),
+        ("sparse_attention", None): (lambda fn: fn(q[0], kk[0], v[0], chosen),
+                                     (q[0], kk[0], v[0], chosen, q[0])),
         ("layernorm_residual", None): (lambda fn: fn(x, r, scale, bias),
                                        (x, r, x, x)),
         ("xent", None): (lambda fn: fn(x, head, tgt), (x, head, tgt)),
