@@ -91,7 +91,14 @@ Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix`` or
 ``dsa.select``, ``dsa.index_loss`` inside), ``attn_out``, ``ffn`` (with
 ``moe.router``, ``moe.dispatch``, ``moe.experts`` inside), ``embed``,
 ``lm_head_loss`` (with ``loop.exit`` inside: gate, exit distribution,
-objective).
+objective).  Since PR 36 nothing a step traces here is under no name:
+``residual`` (the blocks' adds to the stream, a step's state handed on),
+``loss_reduce`` (the layers' own losses added to the rows'), ``dsa.attend``
+in ``attention`` (the attention over the selection; the kernels' entry
+points name it, beside the mask they take, which is ``dsa.select``'s) and
+``moe.combine`` in ``ffn`` (the sum over a token's choices, the layer's
+casts).  A ``dsa.*`` or ``moe.*`` name never encloses another: the readers
+take the first they meet on a path, so that a sublayer's parts add up.
 """
 
 from __future__ import annotations
@@ -423,6 +430,7 @@ def select_top_k(scores, allowed, count):
     return above | (tied & (jnp.cumsum(tied, axis=1) <= short[:, None]))
 
 
+@jax.named_scope("dsa.select")
 def _chunk_rows(spec: SparseAttention, start, c: int, n_keys: int):
     """For the ``c`` queries from ``start`` on against keys ``0..n_keys``:
     which keys are not later than the query ``(c, n_keys)``, and how many
@@ -441,23 +449,26 @@ def _sparse_chunk(spec: SparseAttention, k, v, ki, start, q, qi, w):
     causal, count = _chunk_rows(spec, start, c, n_keys)
     scores = index_scores(qi, ki, w)
     chosen = select_top_k(lax.stop_gradient(scores), causal, count)
-    # (KV head, query, query head of the group, key): the product's own order
-    s = jnp.einsum("tgrd,sgd->gtrs", q.reshape(c, g, h // g, d), k,
-                   preferred_element_type=jnp.float32) * d ** -0.5
-    s = jnp.where(chosen[None, :, None, :], s, _MASKED)
-    # exp once, in the values' dtype for the product; the rows' sums divide
-    # the product's output (C x H x d numbers, not C x H x L probabilities)
-    e = jnp.exp(s - lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
-    inv = 1.0 / jnp.sum(e, axis=-1)                           # (G, C, R)
-    e = e.astype(v.dtype)
-    out = jnp.einsum("gtrs,sgd->tgrd", e, v, preferred_element_type=jnp.float32
-                     ) * inv.transpose(1, 0, 2)[..., None]
+    with jax.named_scope("dsa.attend"):
+        # (KV head, query, query head of the group, key): the product's own order
+        s = jnp.einsum("tgrd,sgd->gtrs", q.reshape(c, g, h // g, d), k,
+                       preferred_element_type=jnp.float32) * d ** -0.5
+        s = jnp.where(chosen[None, :, None, :], s, _MASKED)
+        # exp once, in the values' dtype for the product; the rows' sums divide
+        # the product's output (C x H x d numbers, not C x H x L probabilities)
+        e = jnp.exp(s - lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+        inv = 1.0 / jnp.sum(e, axis=-1)                       # (G, C, R)
+        e = e.astype(v.dtype)
+        out = jnp.einsum("gtrs,sgd->tgrd", e, v,
+                         preferred_element_type=jnp.float32
+                         ) * inv.transpose(1, 0, 2)[..., None]
     with jax.named_scope("dsa.index_loss"):
         # what the heads attend to, summed: the indexer's target, a constant
         target = lax.stop_gradient(jnp.einsum(
             "gtrs,gtr->ts", e, inv, preferred_element_type=jnp.float32) / h)
         loss = _index_loss(scores, chosen, target)
-    return out.reshape(c, h, d).astype(q.dtype), loss
+    with jax.named_scope("dsa.attend"):
+        return out.reshape(c, h, d).astype(q.dtype), loss
 
 
 def _index_loss(scores, chosen, target):
@@ -487,11 +498,13 @@ def _over_chunks(spec: SparseAttention, t: int, fn, chunked, whole):
     outs = []
     for first, n, keys in spans:
         rows = slice(first * c, (first + n) * c)
-        xs = (jnp.arange(first, first + n, dtype=jnp.int32) * c,
-              *(a[rows].reshape(n, c, *a.shape[1:]) for a in chunked))
-        held = [a[:keys] for a in whole]
+        with jax.named_scope("dsa.attend"):   # cutting the sequence, and below
+            xs = (jnp.arange(first, first + n, dtype=jnp.int32) * c,
+                  *(a[rows].reshape(n, c, *a.shape[1:]) for a in chunked))
+            held = [a[:keys] for a in whole]
         outs.append(lax.map(lambda x: fn(*held, *x), xs))
-    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
+    with jax.named_scope("dsa.attend"):       # putting its chunks together
+        return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
 
 
 def sparse_attend(spec: SparseAttention, q, k, v, qi, ki, w, asked="auto"):
@@ -514,7 +527,10 @@ def sparse_attend(spec: SparseAttention, q, k, v, qi, ki, w, asked="auto"):
     def example(args):
         q, k, v, qi, ki, w = args
         out, loss = _over_chunks(spec, t, chunk, (q, qi, w), (k, v, ki))
-        return out.reshape(q.shape), jnp.sum(loss) / t
+        with jax.named_scope("dsa.attend"):
+            out = out.reshape(q.shape)
+        with jax.named_scope("dsa.index_loss"):
+            return out, jnp.sum(loss) / t
 
     return lax.map(example, (q, k, v, qi, ki, w))
 
@@ -529,9 +545,11 @@ def _kernel_chunk(spec: SparseAttention, k, v, ki, start, q, qi, w):
     causal, count = _chunk_rows(spec, start, c, k.shape[0])
     scores = index_scores(qi, ki, w)
     chosen = select_top_k(scores, causal, count)
+    # the kernels' entry points name their own passes (dsa.attend,
+    # dsa.index_loss) beside the mask they take (dsa.select)
     out, lse = kernel.forward(q, k, v, chosen, start + c, kv_heads=g)
+    target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
     with jax.named_scope("dsa.index_loss"):
-        target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
         loss = _index_loss(scores, chosen, target)
     return out, lse, loss
 
@@ -549,13 +567,15 @@ def _kernel_chunk_grads(spec: SparseAttention, k, v, ki, carry, x):
     causal, count = _chunk_rows(spec, start, c, k.shape[0])
     scores, index_grads = jax.vjp(index_scores, qi, ki, w)
     chosen = select_top_k(scores, causal, count)
+    target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
     with jax.named_scope("dsa.index_loss"):
-        target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
         d_scores = d_loss * jax.grad(_index_loss)(scores, chosen, target)
     d_qi, d_ki, d_w = index_grads(d_scores)
     d_q, dk, dv = kernel.backward(q, k, v, chosen, out, lse, d_out, dk, dv,
                                   start + c, kv_heads=g)
-    return (dk, dv, dki + d_ki.astype(jnp.float32)), (d_q, d_qi, d_w)
+    with jax.named_scope("dsa.index_scores"):
+        dki = dki + d_ki.astype(jnp.float32)
+    return (dk, dv, dki), (d_q, d_qi, d_w)
 
 
 def _merged(x):
@@ -568,10 +588,14 @@ def _kernel_example(spec: SparseAttention, args):
     (chunks, G, rows, H / G), index loss)``."""
     q, k, v, qi, ki, w = args
     t = q.shape[0]
+    with jax.named_scope("dsa.attend"):
+        q2, k2, v2 = _merged(q), _merged(k), _merged(v)
     out, lse, loss = _over_chunks(
-        spec, t, functools.partial(_kernel_chunk, spec),
-        (_merged(q), qi, w), (_merged(k), _merged(v), ki))
-    return out.reshape(q.shape), lse, jnp.sum(loss) / t
+        spec, t, functools.partial(_kernel_chunk, spec), (q2, qi, w), (k2, v2, ki))
+    with jax.named_scope("dsa.attend"):
+        out = out.reshape(q.shape)
+    with jax.named_scope("dsa.index_loss"):
+        return out, lse, jnp.sum(loss) / t
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -587,7 +611,8 @@ def _kernel_attend(spec: SparseAttention, q, k, v, qi, ki, w):
 def _kernel_attend_fwd(spec, q, k, v, qi, ki, w):
     out, lse, loss = lax.map(functools.partial(_kernel_example, spec),
                              (q, k, v, qi, ki, w))
-    out, lse = checkpoint_name(out, KEPT[0]), checkpoint_name(lse, KEPT[2])
+    with jax.named_scope("dsa.attend"):
+        out, lse = checkpoint_name(out, KEPT[0]), checkpoint_name(lse, KEPT[2])
     return (out, loss), (q, k, v, qi, ki, w, out, lse)
 
 
@@ -596,28 +621,63 @@ def _kernel_attend_bwd(spec, kept, cotangents):
     c, spans = _key_spans(spec, t)
 
     def example(args):
+        # what is cut, kept and put together here carries the name of the
+        # part it is made for: q, k, v, out and their gradients the
+        # attention's (dsa.attend), the indexer's the index scores'
         *inputs, out, lse, d_out, d_loss = args
         q, k, v, qi, ki, w = inputs
-        q2, k2, v2, out2, d_out2 = (_merged(a) for a in (q, k, v, out, d_out))
-        sums = (jnp.zeros(k2.shape, jnp.float32), jnp.zeros(v2.shape, jnp.float32),
-                jnp.zeros(ki.shape, jnp.float32))
+        with jax.named_scope("dsa.attend"):
+            q2, k2, v2, out2, d_out2 = (_merged(a) for a in (q, k, v, out, d_out))
+            dk, dv = (jnp.zeros(a.shape, jnp.float32) for a in (k2, v2))
+        with jax.named_scope("dsa.index_scores"):
+            dki = jnp.zeros(ki.shape, jnp.float32)
         per_chunk = []
         for first, n, keys in spans:
             rows = slice(first * c, (first + n) * c)
-            xs = (jnp.arange(first, first + n, dtype=jnp.int32) * c,
-                  *(a[rows].reshape(n, c, *a.shape[1:])
-                    for a in (q2, qi, w, out2)),
-                  lse[first:first + n], d_out2[rows].reshape(n, c, -1),
-                  jnp.full((n,), d_loss / t))
+
+            def cut(a):
+                return a[rows].reshape(n, c, *a.shape[1:])
+
+            with jax.named_scope("dsa.attend"):
+                starts = jnp.arange(first, first + n, dtype=jnp.int32) * c
+                q_c = cut(q2)
+            with jax.named_scope("dsa.index_scores"):
+                qi_c, w_c = cut(qi), cut(w)
+            with jax.named_scope("dsa.attend"):
+                out_c, lse_c = cut(out2), lse[first:first + n]
+                d_out_c = d_out2[rows].reshape(n, c, -1)
+            with jax.named_scope("dsa.index_loss"):
+                d_loss_c = jnp.full((n,), d_loss / t)
+            with jax.named_scope("dsa.attend"):
+                k_s, v_s = k2[:keys], v2[:keys]
+            with jax.named_scope("dsa.index_scores"):
+                ki_s = ki[:keys]
+            with jax.named_scope("dsa.attend"):
+                dk_s, dv_s = dk[:keys], dv[:keys]
+            with jax.named_scope("dsa.index_scores"):
+                dki_s = dki[:keys]
             part, grads = lax.scan(
-                functools.partial(_kernel_chunk_grads, spec, k2[:keys], v2[:keys],
-                                  ki[:keys]),
-                tuple(a[:keys] for a in sums), xs)
-            sums = tuple(a.at[:keys].set(b) for a, b in zip(sums, part))
+                functools.partial(_kernel_chunk_grads, spec, k_s, v_s, ki_s),
+                (dk_s, dv_s, dki_s),
+                (starts, q_c, qi_c, w_c, out_c, lse_c, d_out_c, d_loss_c))
+            with jax.named_scope("dsa.attend"):
+                dk, dv = dk.at[:keys].set(part[0]), dv.at[:keys].set(part[1])
+            with jax.named_scope("dsa.index_scores"):
+                dki = dki.at[:keys].set(part[2])
             per_chunk.append(grads)
-        d_q, d_qi, d_w = (jnp.concatenate(a) for a in zip(*per_chunk))
-        return tuple(a.reshape(like.shape).astype(like.dtype)
-                     for a, like in zip((d_q, *sums[:2], d_qi, sums[2], d_w), inputs))
+        d_q, d_qi, d_w = zip(*per_chunk)
+
+        def like(a, b):
+            return a.reshape(b.shape).astype(b.dtype)
+
+        with jax.named_scope("dsa.attend"):
+            d_q = jnp.concatenate(d_q)
+        with jax.named_scope("dsa.index_scores"):
+            d_qi, d_w = jnp.concatenate(d_qi), jnp.concatenate(d_w)
+        with jax.named_scope("dsa.attend"):
+            d_q, dk, dv = like(d_q, q), like(dk, k), like(dv, v)
+        with jax.named_scope("dsa.index_scores"):
+            return d_q, dk, dv, like(d_qi, qi), like(dki, ki), like(d_w, w)
 
     return lax.map(example, (*kept, *cotangents))
 
@@ -649,7 +709,10 @@ def sparse_attention_mixer(spec: SparseAttention, p, u, dt):
         # path each chunk is computed twice (forward, and once more in its own
         # backward); on the kernel's the selection and the indexer's target
         # are, and the attention's forward pass runs once
-        out, loss = checkpoint_name(out, KEPT[0]), checkpoint_name(loss, KEPT[1])
+        with jax.named_scope("dsa.attend"):
+            out = checkpoint_name(out, KEPT[0])
+        with jax.named_scope("dsa.index_loss"):
+            loss = checkpoint_name(loss, KEPT[1])
     with jax.named_scope("attn_out"):
         return jnp.einsum("btf,fd->btd", out.reshape(b, t, h * d),
                           p["wo"].astype(dt)), loss
@@ -793,16 +856,26 @@ def moe_ffn(spec: MoE, p, u, dt):
     choice, with no loop around it, and a choice that lives on another chip
     adds zero here."""
     shape = u.shape
-    u = u.reshape(-1, shape[-1]).astype(dt)
+    with jax.named_scope("moe.combine"):     # the layer's own shaping and sum
+        u = u.reshape(-1, shape[-1]).astype(dt)
     gate, e = route(spec, p["router"], u)
     if spec.top_k == 1:
         y = _one_choice(spec, p, u, gate, e, dt)
-        return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
+        with jax.named_scope("moe.combine"):
+            return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
     experts = {name: p[name] for name in ("wg", "wu", "wdn")}
     one = jax.checkpoint(functools.partial(_one_choice, spec, dt=dt))
-    y, _ = lax.scan(lambda acc, ge: (acc + one(experts, u, *ge), None),
-                    jnp.zeros(u.shape, jnp.float32), (gate, e))
-    return y.astype(dt).reshape(shape), e.T.reshape(*shape[:-1], spec.top_k)
+
+    def add_choice(acc, ge):
+        y = one(experts, u, *ge)
+        with jax.named_scope("moe.combine"):
+            return acc + y, None
+
+    with jax.named_scope("moe.combine"):
+        zero = jnp.zeros(u.shape, jnp.float32)
+    y, _ = lax.scan(add_choice, zero, (gate, e))
+    with jax.named_scope("moe.combine"):
+        return y.astype(dt).reshape(shape), e.T.reshape(*shape[:-1], spec.top_k)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -897,10 +970,15 @@ def block(lp, x, cfg: HybridConfig, i: int):
     dt, eps = cfg.base.dtype, cfg.norm_eps
     a = MIXERS[type(mixer)](mixer, lp[mixer.key], rms_norm(x, lp["norm1"], eps), dt)
     a, aux = a if mixer.aux_loss else (a, None)
-    x = x + (rms_norm(a, lp["norm1_post"], eps) if mixer.post_norm else a)
+    if mixer.post_norm:
+        a = rms_norm(a, lp["norm1_post"], eps)
+    with jax.named_scope("residual"):
+        x = x + a
     y, e = FFNS[type(ffn)](ffn, lp[ffn.key], rms_norm(x, lp["norm2"], eps), dt)
-    return (x + (rms_norm(y, lp["norm2_post"], eps) if ffn.post_norm else y),
-            e, aux)
+    if ffn.post_norm:
+        y = rms_norm(y, lp["norm2_post"], eps)
+    with jax.named_scope("residual"):
+        return x + y, e, aux
 
 
 def run_layers(params, tokens, cfg: HybridConfig):
@@ -927,19 +1005,23 @@ def run_layers(params, tokens, cfg: HybridConfig):
             x, e, aux = fn(lp, x, cfg, i)
             choices.append(e)
             if aux is not None:
-                own = aux if own is None else own + aux
+                with jax.named_scope("loss_reduce"):
+                    own = aux if own is None else own + aux
         return rms_norm(x, params["final_norm"], cfg.norm_eps), choices, own
 
     if cfg.n_loops == 1:
         h, choices, own = step(x)
-        return h[None], [None if e is None else e[None] for e in choices], own
+        with jax.named_scope("residual"):       # one step, stacked as a loop's
+            return (h[None], [None if e is None else e[None] for e in choices],
+                    own)
 
     def body(x, _):
         h, choices, own = step(x)
         return h, (h, choices, own)  # the normed state is the next step's input
 
     hs, choices, own = lax.scan(body, x, None, length=cfg.n_loops)[1]
-    return hs, choices, None if own is None else own.sum(axis=0)
+    with jax.named_scope("loss_reduce"):
+        return hs, choices, None if own is None else own.sum(axis=0)
 
 
 def encode_steps(params, tokens, cfg: HybridConfig):
@@ -951,7 +1033,8 @@ def encode(params, tokens, cfg: HybridConfig):
     """``tokens`` (B, T) -> ``(the last loop step's final normed hidden
     (B, T, E), [e per layer])``."""
     hs, choices = encode_steps(params, tokens, cfg)
-    return hs[-1], [None if e is None else e[-1] for e in choices]
+    with jax.named_scope("residual"):           # the last step's, handed on
+        return hs[-1], [None if e is None else e[-1] for e in choices]
 
 
 def forward(params, tokens, cfg: HybridConfig):
@@ -970,7 +1053,9 @@ def loss_parts(params, tokens, targets, cfg: HybridConfig):
     never meet (a mixer's own loss reads its inputs behind a
     ``stop_gradient``)."""
     hs, _, own = run_layers(params, tokens, cfg)
-    return lm_head_loss(params, hs[-1], targets, cfg.base, per_example=True), own
+    with jax.named_scope("residual"):           # the last step's, handed on
+        h = hs[-1]
+    return lm_head_loss(params, h, targets, cfg.base, per_example=True), own
 
 
 def lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
@@ -979,12 +1064,15 @@ def lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
     together in the expert layers and chunked together in the head: the loss
     a ``DataParallelTrainer(per_example_loss=True)`` takes."""
     xent, own = loss_parts(params, tokens, targets, cfg)
-    return xent if own is None else xent + own
+    with jax.named_scope("loss_reduce"):
+        return xent if own is None else xent + own
 
 
 def lm_loss(params, tokens, targets, cfg: HybridConfig):
     """Mean cross entropy over the batch."""
-    return lm_loss_per_example(params, tokens, targets, cfg).mean()
+    per = lm_loss_per_example(params, tokens, targets, cfg)
+    with jax.named_scope("loss_reduce"):
+        return per.mean()
 
 
 def exit_distribution(gate, hs):
@@ -1031,7 +1119,9 @@ def looped_lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
     """Each example's mean looped objective, ``(B,)``: the loss a
     ``DataParallelTrainer(per_example_loss=True)`` takes for a model with an
     exit gate."""
-    return looped_losses(params, tokens, targets, cfg)[0].mean(axis=1)
+    objective = looped_losses(params, tokens, targets, cfg)[0]
+    with jax.named_scope("loss_reduce"):
+        return objective.mean(axis=1)
 
 
 def exit_stats(params, tokens, cfg: HybridConfig):

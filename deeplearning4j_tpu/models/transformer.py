@@ -236,6 +236,9 @@ reduce_from_tp.defvjp(_reduce_fwd, _reduce_bwd)
 # decode share, so a profiler trace names device time by part of the model
 # (DESIGN.md §9): embed, layernorm, qkv_proj, attention, attn_out, ffn,
 # lm_head_loss (lm_head where only logits are made), kv_gather, kv_scatter.
+# ``residual`` names the block's two adds to the stream INSIDE ``attn_out``
+# and ``ffn``, where they have always been counted (models/hybrid.py's block
+# adds outside every sublayer, so there the name stands alone).
 # Scopes are HLO metadata: the compiled program does not change.
 
 @jax.named_scope("layernorm")
@@ -430,7 +433,8 @@ def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
         if tp_axis:
             proj = reduce_from_tp(proj, tp_axis)  # partial sums over local heads
         if not fuse_ln:
-            x = x + proj.astype(x.dtype)
+            with jax.named_scope("residual"):
+                x = x + proj.astype(x.dtype)
     if fuse_ln:
         from ..ops.pallas.layernorm import fused_residual_layernorm
         with jax.named_scope("layernorm"):
@@ -446,7 +450,8 @@ def _block(params, x, cfg: TransformerConfig, n_sp, sp_axis, tp_axis, t_local):
         down = reduce_from_tp(down, tp_axis)
     with jax.named_scope("ffn"):
         down = down + params["b2"].astype(dt)
-        return x + down.astype(x.dtype)
+        with jax.named_scope("residual"):
+            return x + down.astype(x.dtype)
 
 
 @jax.named_scope("embed")

@@ -24,12 +24,16 @@ The production observability layer (grown from the seed
   replica pool; ``TENANTS`` bounded tenant labels; ``ForecastEvaluator``
   time-to-breach extrapolation (``fleet``)
 - ``StatusServer`` — ``/healthz`` ``/metrics`` ``/metrics.prom`` ``/status``
+- ``scopemap`` (module) — what each operation of a compiled program holds,
+  by ``jax.named_scope`` path: ``register`` at a program's first dispatch
+  (shapes only), ``scope_map`` on request beside a trace (compiles)
 - ``sample_device_memory`` — per-device HBM gauges (no-op gauge on
   backends without memory stats)
 - ``enabled``/``enable``/``disable`` — process-global flag;
   zero-per-step-allocation when off (see ``core``)
 """
 
+from . import scopemap
 from . import tracing as trace
 from .core import NOOP_SPAN, disable, enable, enabled
 from .cost import COSTS, CostInfo, CostModel
@@ -66,5 +70,5 @@ __all__ = [
     "TimeSeriesStore", "Tracer",
     "default_serving_objectives", "default_training_objectives",
     "disable", "enable", "enabled", "parse_prometheus", "profiler_trace",
-    "sample_device_memory", "sample_state_bytes", "span", "trace",
+    "sample_device_memory", "sample_state_bytes", "scopemap", "span", "trace",
 ]

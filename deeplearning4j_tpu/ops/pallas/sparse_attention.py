@@ -317,15 +317,26 @@ def _frontier(frontier, n_keys: int):
     return jnp.full((1,), n_keys if frontier is None else frontier, jnp.int32)
 
 
+@jax.named_scope("dsa.select")
+def _mask(chosen):
+    """The selection as the kernels read it, int8: the selection's last
+    operation in a trace (XLA fuses what made ``chosen`` into it), so it
+    carries the selection's name; the three entry points below name their
+    kernel's pass (``dsa.attend``, ``dsa.index_loss``) themselves, side by
+    side with it and never around it."""
+    return chosen.astype(jnp.int8)
+
+
 def forward(q, k, v, chosen, frontier=None, *, kv_heads: int,
             interpret: bool | None = None):
     """One chunk of queries ``q (C, H*d)`` over ``k, v (L, G*d)`` under
     ``chosen`` (C, L) bool: ``(out (C, H*d), lse (G, C, R) f32)``.  No key
     at or past ``frontier`` is chosen (all may be, by default)."""
-    return _sparse_fwd(q, k, v, chosen.astype(jnp.int8),
-                       _frontier(frontier, k.shape[0]), kv_heads,
-                       k.shape[1] // kv_heads,
-                       registry.resolve_interpret(interpret))
+    mask = _mask(chosen)
+    with jax.named_scope("dsa.attend"):
+        return _sparse_fwd(q, k, v, mask, _frontier(frontier, k.shape[0]),
+                           kv_heads, k.shape[1] // kv_heads,
+                           registry.resolve_interpret(interpret))
 
 
 def backward(q, k, v, chosen, out, lse, do, dk_acc, dv_acc, frontier=None, *,
@@ -334,22 +345,27 @@ def backward(q, k, v, chosen, out, lse, do, dk_acc, dv_acc, frontier=None, *,
     of ``forward``'s ``out``; the accumulators are ``(L, G*d)`` f32."""
     c = q.shape[0]
     d = k.shape[1] // kv_heads
-    delta = jnp.sum((do.astype(jnp.float32) * out.astype(jnp.float32)
-                     ).reshape(c, kv_heads, -1, d), axis=-1)      # (C, G, R)
-    return _sparse_bwd(
-        q, k, v, chosen.astype(jnp.int8), do, lse.transpose(0, 2, 1),
-        delta.transpose(1, 2, 0), _frontier(frontier, k.shape[0]),
-        dk_acc, dv_acc, kv_heads, d, registry.resolve_interpret(interpret))
+    with jax.named_scope("dsa.attend"):
+        delta = jnp.sum((do.astype(jnp.float32) * out.astype(jnp.float32)
+                         ).reshape(c, kv_heads, -1, d), axis=-1)  # (C, G, R)
+    mask = _mask(chosen)
+    with jax.named_scope("dsa.attend"):
+        return _sparse_bwd(
+            q, k, v, mask, do, lse.transpose(0, 2, 1),
+            delta.transpose(1, 2, 0), _frontier(frontier, k.shape[0]),
+            dk_acc, dv_acc, kv_heads, d, registry.resolve_interpret(interpret))
 
 
 def head_mean(q, k, chosen, lse, frontier=None, *, kv_heads: int,
               interpret: bool | None = None):
     """``mean_h softmax_h[t, s]`` over the chosen keys, ``(C, L)`` f32, from
     ``forward``'s ``lse``; zero where nothing is chosen."""
-    return _sparse_headsum(q, k, chosen.astype(jnp.int8), lse,
-                           _frontier(frontier, k.shape[0]), kv_heads,
-                           k.shape[1] // kv_heads,
-                           registry.resolve_interpret(interpret))
+    mask = _mask(chosen)
+    with jax.named_scope("dsa.index_loss"):
+        return _sparse_headsum(q, k, mask, lse,
+                               _frontier(frontier, k.shape[0]), kv_heads,
+                               k.shape[1] // kv_heads,
+                               registry.resolve_interpret(interpret))
 
 
 def _flat(x):

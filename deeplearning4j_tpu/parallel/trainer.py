@@ -56,7 +56,8 @@ from ..analysis.shardguard import SHARDGUARD
 from ..datasets.dataset import DataSet
 from ..resilience.faults import FAULTS, DeviceLossError, DivergenceError
 from ..observability import COSTS, METRICS, NOOP_SPAN, enabled as _obs_enabled
-from ..observability import sample_device_memory, sample_state_bytes, trace
+from ..observability import sample_device_memory, sample_state_bytes
+from ..observability import scopemap, trace
 from ..optimize import transforms as tfm
 from . import collectives as clv
 from .compile_cache import setup_compile_cache
@@ -305,10 +306,12 @@ class DataParallelTrainer:
         chunked head loss need."""
         loss_fn = self.loss_fn
         if self.per_example_loss:
-            return loss_fn(params, x, y, key).reshape((x.shape[0],))
-        per = jax.vmap(
-            lambda xi, yi: loss_fn(params, xi[None], yi[None], key))(x, y)
-        return per.reshape((x.shape[0],))
+            per = loss_fn(params, x, y, key)
+        else:
+            per = jax.vmap(
+                lambda xi, yi: loss_fn(params, xi[None], yi[None], key))(x, y)
+        with jax.named_scope("loss_reduce"):
+            return per.reshape((x.shape[0],))
 
     def _masked_mean_loss(self, key_select):
         """Wrap ``loss_fn`` (a per-sample mean) into an exact masked mean:
@@ -319,7 +322,9 @@ class DataParallelTrainer:
         should avoid ragged batches."""
         def masked(params, x, y, key, mask, denom):
             per = self._per_example(params, x, y, key_select(key))
-            return jnp.sum(per * mask.astype(per.dtype)) / denom.astype(per.dtype)
+            with jax.named_scope("loss_reduce"):
+                return (jnp.sum(per * mask.astype(per.dtype))
+                        / denom.astype(per.dtype))
 
         return masked
 
@@ -330,7 +335,8 @@ class DataParallelTrainer:
         masked = self._masked_mean_loss(lambda k: k)
 
         def step(params, tstate, x, y, key, iteration, n_valid):
-            mask = jnp.arange(x.shape[0]) < n_valid
+            with jax.named_scope("loss_reduce"):
+                mask = jnp.arange(x.shape[0]) < n_valid
             loss, grads = jax.value_and_grad(masked)(
                 params, x, y, key, mask, n_valid)
             with jax.named_scope("optimizer"):
@@ -391,27 +397,34 @@ class DataParallelTrainer:
                     flat_full = jax.tree_util.tree_map(
                         lambda c: clv.all_gather_or_identity(c, DP, n_dp),
                         params)
-                nat = z.unflatten_like(flat_full, z.natural_params)
+                    nat = z.unflatten_like(flat_full, z.natural_params)
             else:
                 nat = params
-            idx = clv.axis_index(DP)
-            rows = idx * x.shape[0] + jnp.arange(x.shape[0])
-            mask = rows < n_valid
+            with jax.named_scope("loss_reduce"):
+                idx = clv.axis_index(DP)
+                rows = idx * x.shape[0] + jnp.arange(x.shape[0])
+                mask = rows < n_valid
 
             def local_sum(p):
                 per = self._per_example(p, x, y, key)
-                return jnp.sum(per * mask.astype(per.dtype))
+                with jax.named_scope("loss_reduce"):
+                    return jnp.sum(per * mask.astype(per.dtype))
 
             # vjp with a 1/n_valid cotangent == grad of the GLOBAL masked
             # mean: the division folds into the backward seed exactly where
             # pjit's autodiff puts it, so per-chip partial grads are the
             # same floats as the replicated step's pre-psum partials
             lsum, vjp_fn = jax.vjp(local_sum, nat)
-            denom = n_valid.astype(lsum.dtype)
-            (grads,) = vjp_fn(jnp.ones((), lsum.dtype) / denom)
-            loss = clv.psum(lsum, DP) / denom
-            gflat = z.flatten_tree(grads)
+            with jax.named_scope("loss_reduce"):
+                denom = n_valid.astype(lsum.dtype)
+                seed = jnp.ones((), lsum.dtype) / denom
+            (grads,) = vjp_fn(seed)
+            with jax.named_scope("loss_reduce"):
+                loss = clv.psum(lsum, DP) / denom
+            # the layout's reshapes, pads and slices are the exchange's own
+            # (zero.layout, ZeroLayout's name for them, nests in grad_sync)
             with jax.named_scope("grad_sync"):
+                gflat = z.flatten_tree(grads)
                 if stage == 1:
                     gfull = jax.tree_util.tree_map(
                         lambda g: clv.psum(g, DP), gflat)
@@ -423,7 +436,8 @@ class DataParallelTrainer:
             if stage >= 3:
                 pchunk = params  # already this chip's chunks
             else:
-                pchunk = z.chunk_tree(z.flatten_tree(nat), idx)
+                with jax.named_scope("grad_sync"):
+                    pchunk = z.chunk_tree(z.flatten_tree(nat), idx)
             # decay classification must come from the NATURAL shapes — on
             # the 1-D chunks of a leaf that flattens, the ndim >= 2
             # heuristic would decay nothing
@@ -437,7 +451,7 @@ class DataParallelTrainer:
             with jax.named_scope("grad_sync"):
                 pfull = jax.tree_util.tree_map(
                     lambda c: clv.all_gather_or_identity(c, DP, n_dp), pchunk)
-            return z.unflatten_like(pfull, z.natural_params), tstate, loss
+                return z.unflatten_like(pfull, z.natural_params), tstate, loss
 
         param_spec = P(DP) if stage >= 3 else P()
         smapped = shard_map(
@@ -463,9 +477,11 @@ class DataParallelTrainer:
             tstate = jax.tree_util.tree_map(
                 lambda a: a[0] if isinstance(a, jnp.ndarray) else a, tstate)
             # global row ids of this shard's slice -> local validity mask
-            rows = jax.lax.axis_index(DP) * x.shape[0] + jnp.arange(x.shape[0])
-            mask = rows < n_valid[0]
-            denom = jnp.maximum(jnp.sum(mask), 1)  # all-pad shard guard
+            with jax.named_scope("loss_reduce"):
+                rows = (jax.lax.axis_index(DP) * x.shape[0]
+                        + jnp.arange(x.shape[0]))
+                mask = rows < n_valid[0]
+                denom = jnp.maximum(jnp.sum(mask), 1)  # all-pad shard guard
             loss, grads = jax.value_and_grad(masked)(
                 params, x, y, key, mask, denom)
             with jax.named_scope("optimizer"):
@@ -575,6 +591,9 @@ class DataParallelTrainer:
                 # live train.mfu gauge at every resolution fence
                 self._step_cost = COSTS.capture(
                     f"train_step.b{bucket}", step_fn, *args)
+                # the shapes a reader of a trace compiles again to ask what
+                # each fusion of this step holds (nothing is compiled here)
+                scopemap.register(step_fn, *args)
             params, tstate, loss = step_fn(*args)
             if self.router != "iterative_reduce" \
                     and (state.step + 1) % self.average_every == 0:

@@ -163,12 +163,17 @@ class ZeroLayout:
         return v[:_size(shape)].reshape(shape)
 
     # ---------------------------------------------------------- tree ops
+    # In a trace the three views below are ``zero.layout``: reshapes, pads
+    # and slices that compute nothing.  The sharded step calls them inside
+    # ``grad_sync``, whose share of a step they are part of.
+    @jax.named_scope("zero.layout")
     def flatten_tree(self, tree):
         """Natural -> flat view, leaf by leaf (trace-safe).  Works on any
         tree whose array leaves carry natural shapes — params and the
         optimizer state both, since state leaves mirror param shapes."""
         return tree_map(self._flatten_leaf, tree)
 
+    @jax.named_scope("zero.layout")
     def unflatten_like(self, flat_tree, natural_template):
         """Flat view -> natural shapes (trace-safe): a leaf that split is
         already there; one that flattened has its pad sliced off and is
@@ -176,6 +181,7 @@ class ZeroLayout:
         return tree_map(lambda v, t: self._unflatten_leaf(v, t.shape),
                         flat_tree, natural_template)
 
+    @jax.named_scope("zero.layout")
     def chunk_tree(self, flat_tree, idx):
         """This chip's contiguous chunk of every flat leaf along axis 0
         (inside shard_map: ``idx = lax.axis_index(dp)``)."""

@@ -88,12 +88,33 @@ LAST_PLACE_ASSERTS = {
         "test_cell_reports_the_common_metrics_and_its_own")}
 
 
+#: Two tests of ``tests/benchmark_tests/test_benchmark_sparse.py`` (PR 34)
+#: hold the NUMBER of per-layer metrics the sparse and the looped cell report
+#: (``len(names) == 14 + 6 + 6``, ``== 14 + 1 + 3``) and the sparse cell's
+#: metrics beside ZAYA's to exactly PR 34's six.  A PR that appends a
+#: per-layer metric to either cell (PR 36: ``unnamed_share.train`` and six
+#: more) can neither keep those counts true nor repair an accepted file.  They
+#: are expected to fail from PR 36 on; everything else they hold (the entries'
+#: order and lists, the end-to-end metrics, the cells' own metrics) is tested
+#: again, with the counts as they stand now, in
+#: ``tests/benchmark_tests/test_benchmark_scope_rest.py``.  A ``benchmark`` PR
+#: takes the counts out of the accepted file and this list with them.
+METRIC_COUNT_ASSERTS = {
+    "tests/benchmark_tests/test_benchmark_sparse.py::" + name for name in (
+        "test_cell_reports_the_common_metrics_and_its_own",
+        "test_looped_cell_reports_the_common_metrics_and_its_own")}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.nodeid in LAST_PLACE_ASSERTS:
             item.add_marker(pytest.mark.xfail(
                 strict=True, reason="asserts that PR 32's entries are the "
                 "LAST of BENCHMARK.json's lists; PR 34 appended its own"))
+        if item.nodeid in METRIC_COUNT_ASSERTS:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="asserts the exact number of per-layer "
+                "metrics a cell reports; PR 36 appended its own"))
 
 
 @pytest.fixture(autouse=True)
