@@ -60,9 +60,11 @@ behind a linear router, the Qwen3-MoE family's):
   the index scores over the selected keys, which reaches the indexer's
   parameters alone, as the language-model loss reaches everything else alone.
 - ``MoE`` with ``top_k > 1`` and ``router_hidden = 0``: a linear softmax
-  router, the ``top_k`` largest probabilities renormalised, each choice sent
-  through the SAME dispatch as a top-1 layer's one choice (a scan over the
-  choices), so that memory stays what one choice needs.
+  router, the ``top_k`` largest probabilities renormalised; the (token,
+  choice) pairs are sorted ONCE by held expert and the pairs that have an
+  expert here run in blocks of sorted rows, the blocks past the last local
+  pair skipped (``_pair_blocks``), so that time follows the local share and
+  memory stays what one block needs.
 
 The looped layer (Ouro, arXiv:2510.25741):
 
@@ -96,9 +98,10 @@ objective).  Since PR 36 nothing a step traces here is under no name:
 ``loss_reduce`` (the layers' own losses added to the rows'), ``dsa.attend``
 in ``attention`` (the attention over the selection; the kernels' entry
 points name it, beside the mask they take, which is ``dsa.select``'s) and
-``moe.combine`` in ``ffn`` (the sum over a token's choices, the layer's
-casts).  A ``dsa.*`` or ``moe.*`` name never encloses another: the readers
-take the first they meet on a path, so that a sublayer's parts add up.
+``moe.combine`` in ``ffn`` (the expert layer's shaping, casts and the zeros
+its sums start from).  A ``dsa.*`` or ``moe.*`` name never encloses another:
+the readers take the first they meet on a path, so that a sublayer's parts
+add up.
 """
 
 from __future__ import annotations
@@ -746,7 +749,9 @@ def sparse_selection(spec: SparseAttention, p, u, dt, reduce=None):
 @dataclasses.dataclass(frozen=True)
 class MoE:
     """Top-k mixture of gated-SiLU experts behind a router MLP (or, with
-    ``router_hidden = 0``, one linear layer)."""
+    ``router_hidden = 0``, one linear layer).  ``top_k`` decides the dispatch:
+    one sort of the tokens at top-1 (``_one_choice``), one sort of the (token,
+    choice) pairs run in blocks above it (``_pair_blocks``)."""
     n_experts: int = 16              # the router's width, as published
     held: tuple[int, int] = (0, 8)   # (first, count): this chip's experts
     router_hidden: int = 256
@@ -816,7 +821,9 @@ def _one_choice(spec: MoE, p, u, gate, e, dt):
     each sent to ONE expert ``e (N,)`` with weight ``gate (N,)``: tokens are
     sorted by the held expert they chose (those routed elsewhere last), three
     grouped matmuls run over the sorted rows, and the rows go back to their
-    places weighted.  No capacity, no dropped token, whatever the imbalance."""
+    places weighted.  No capacity, no dropped token, whatever the imbalance.
+    The top-1 layer's whole dispatch; above top-1 ``_pair_blocks`` sorts the
+    pairs of all choices at once."""
     n = u.shape[0]
     first, count = spec.held
     with jax.named_scope("moe.dispatch"):
@@ -846,34 +853,186 @@ def _one_choice(spec: MoE, p, u, gate, e, dt):
         return ys[back] * jnp.where(local, gate, 0.0)[:, None]
 
 
+#: rows of sorted (token, choice) pairs that one block of a top-k layer holds.
+#: A block that runs costs about a millisecond whatever its rows (its rows'
+#: scatter-add, the weight-gradient sums), so few large blocks beat many small
+#: ones: 8192 against 4096 and 2048 on a v5e at 16,384 x 8 pairs (PERF.md §6)
+PAIR_BLOCK_ROWS = 8192
+
+
+def _pair_block_shape(pairs: int) -> tuple[int, int]:
+    """``(rows a block, blocks)`` for a layer of ``pairs`` (token, choice)
+    pairs: whole blocks, the last one padded."""
+    rows = min(pairs, PAIR_BLOCK_ROWS)
+    return rows, -(-pairs // rows)
+
+
+def _sorted_pairs(spec: MoE, e, n: int):
+    """The (token, choice) pairs of choices ``e (top_k, N)`` sorted ONCE by
+    the held expert they chose, those routed elsewhere (and the padding of the
+    last block) last, a stable sort as ``_one_choice``'s: ``(pair (blocks,
+    rows), token (blocks, rows), ends (count,))``.  ``pair`` indexes the
+    flattened ``(top_k, N)`` arrays and ``ends[g]`` is the sorted row past
+    held expert ``g``'s last pair, so ``ends[-1]`` pairs have an expert
+    here."""
+    first, count = spec.held
+    rows, blocks = _pair_block_shape(e.size)
+    e = e.reshape(-1)
+    slot = jnp.where((e >= first) & (e < first + count), e - first, count)
+    slot = jnp.pad(slot, (0, blocks * rows - e.size), constant_values=count)
+    pair = jnp.argsort(slot, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(slot[:, None] == jnp.arange(count), axis=0, dtype=jnp.int32)
+    # a padded row reads pair 0's token and weight; it lies past the last pair
+    pair = jnp.where(pair < e.size, pair, 0).reshape(blocks, rows)
+    return pair, pair % n, jnp.cumsum(sizes)
+
+
+def _over_blocks(run, init, pair, token, ends):
+    """``run(carry, pair (rows,), token (rows,), sizes (count,), live (rows,
+    1)) -> carry`` over the blocks of sorted rows in turn: ``sizes`` a block's
+    rows of each held expert's group, ``live`` which of its rows are a local
+    pair.  A block that starts past the last local pair is skipped, and what
+    is carried goes THROUGH the ``cond``: a skipped block touches none of it."""
+    rows = pair.shape[1]
+
+    def block(carry, x):
+        pair, token, start = x
+
+        def held(carry):
+            with jax.named_scope("moe.dispatch"):
+                hi = jnp.clip(ends, start, start + rows)
+                lo = jnp.clip(jnp.concatenate([ends[:1] * 0, ends[:-1]]),
+                              start, start + rows)
+                live = (start + jnp.arange(rows) < ends[-1])[:, None]
+            return run(carry, pair, token, hi - lo, live)
+
+        return lax.cond(start < ends[-1], held, lambda carry: carry, carry), None
+
+    with jax.named_scope("moe.dispatch"):
+        starts = jnp.arange(pair.shape[0], dtype=jnp.int32) * rows
+    return lax.scan(block, init, (pair, token, starts))[0]
+
+
+@jax.named_scope("moe.experts")
+def _block_experts(dt, sizes, live, w, xs, gate):
+    """A block's rows ``xs (rows, E)`` through their experts (weights ``w``
+    already in ``dt``), each weighted by its pair's ``gate (rows,)``: float32
+    ``(rows, E)``.  Rows that are no local pair are masked on the way in and
+    out, as ``_one_choice`` masks ``held_rows``."""
+    def grouped(x, weight):
+        out = lax.ragged_dot(jnp.where(live, x, 0), weight, sizes,
+                             preferred_element_type=jnp.float32)
+        return jnp.where(live, out, 0.0)
+
+    hidden = (jax.nn.silu(grouped(xs, w["wg"]))
+              * grouped(xs, w["wu"])).astype(dt)
+    return grouped(hidden, w["wdn"]) * jnp.where(live[:, 0], gate, 0.0)[:, None]
+
+
+@jax.named_scope("moe.experts")
+def _in_dtype(experts, dt):
+    """The experts' weights cast once a layer and pass, not once a block."""
+    return {name: w.astype(dt) for name, w in experts.items()}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _pair_blocks(spec: MoE, dt, experts, u, gate, e):
+    """This chip's experts' outputs ``(N, E)`` f32 for tokens ``u (N, E)``
+    each sent to ``top_k`` experts ``e (top_k, N)`` with weights ``gate
+    (top_k, N)``: the (token, choice) pairs are sorted once by held expert
+    (``_sorted_pairs``) and the sorted rows run in blocks of
+    ``PAIR_BLOCK_ROWS`` through one ``lax.scan`` (``_over_blocks``).  A block
+    gathers its rows of ``u`` by token, runs the three grouped products with
+    the group sizes clipped to the block and adds its weighted rows to the
+    layer's f32 output at their tokens; a block that starts past the last
+    local pair is skipped (``lax.cond`` on the traced count), so what runs
+    follows the share of pairs that have an expert HERE.  The scan has every
+    block a layer can fill: no capacity, no dropped token, whatever the
+    imbalance.  The backward pass is written out as the mirror image over the
+    same blocks, each block's forward recomputed in it, and keeps nothing of a
+    block but the layer's inputs: ``jax``'s own transpose of the scan would
+    add a skipped block's zeros to ``du`` and to the weights' gradients
+    OUTSIDE the ``cond`` (2.6 times the time of the layer on a v5e)."""
+    return _pair_blocks_fwd(spec, dt, experts, u, gate, e)[0]
+
+
+def _pair_blocks_fwd(spec, dt, experts, u, gate, e):
+    with jax.named_scope("moe.dispatch"):
+        pair, token, ends = _sorted_pairs(spec, e, u.shape[0])
+        flat = gate.reshape(-1)
+    w = _in_dtype(experts, dt)
+
+    def run(acc, pair, token, sizes, live):
+        with jax.named_scope("moe.dispatch"):
+            xs, g = u[token], flat[pair]
+        ys = _block_experts(dt, sizes, live, w, xs, g)
+        with jax.named_scope("moe.dispatch"):
+            return acc.at[token].add(ys)
+
+    with jax.named_scope("moe.combine"):
+        zero = jnp.zeros(u.shape, jnp.float32)
+    return (_over_blocks(run, zero, pair, token, ends),
+            (experts, u, gate, pair, token, ends))
+
+
+def _pair_blocks_bwd(spec, dt, kept, dy):
+    experts, u, gate, pair, token, ends = kept
+    with jax.named_scope("moe.dispatch"):
+        flat = gate.reshape(-1)
+    w = _in_dtype(experts, dt)
+
+    def run(sums, pair, token, sizes, live):
+        du, dgate, dw = sums
+        with jax.named_scope("moe.dispatch"):
+            xs, g, d_ys = u[token], flat[pair], dy[token]
+        _, pull = jax.vjp(
+            functools.partial(_block_experts, dt, sizes, live), w, xs, g)
+        d_w, d_xs, d_g = pull(d_ys)
+        with jax.named_scope("moe.dispatch"):
+            du = du.at[token].add(d_xs.astype(jnp.float32))
+            dgate = dgate.at[pair].add(d_g)
+        with jax.named_scope("moe.experts"):
+            dw = {name: dw[name] + d_w[name].astype(jnp.float32) for name in dw}
+        return du, dgate, dw
+
+    with jax.named_scope("moe.combine"):
+        zeros = (jnp.zeros(u.shape, jnp.float32),
+                 jnp.zeros(flat.shape, jnp.float32),
+                 {name: jnp.zeros(a.shape, jnp.float32)
+                  for name, a in experts.items()})
+    du, dgate, dw = _over_blocks(run, zeros, pair, token, ends)
+    with jax.named_scope("moe.combine"):
+        return ({name: dw[name].astype(a.dtype) for name, a in experts.items()},
+                du.astype(u.dtype), dgate.reshape(gate.shape).astype(gate.dtype),
+                None)
+
+
+_pair_blocks.defvjp(_pair_blocks_fwd, _pair_blocks_bwd)
+
+
 @jax.named_scope("ffn")
 def moe_ffn(spec: MoE, p, u, dt):
     """Normed activations ``u`` (B, T, E) -> ``(this chip's part of the
     layer's output (B, T, E), e (B, T) or, above top-1, (B, T, top_k))``.
-    Every token of the batch is grouped at once.  A token's ``top_k`` choices
-    go through ``_one_choice`` one after the other (a scan, each choice
-    recomputed in the backward pass) and add up: a top-1 layer is the one
-    choice, with no loop around it, and a choice that lives on another chip
-    adds zero here."""
+    Every token of the batch is grouped at once.  A top-1 layer is the one
+    choice through ``_one_choice``, with no loop around it; above top-1 the
+    (token, choice) pairs are sorted once and the pairs that have an expert
+    here run in blocks of sorted rows (``_pair_blocks``).  A choice that lives
+    on another chip adds zero here and, above top-1, moves no row."""
     shape = u.shape
-    with jax.named_scope("moe.combine"):     # the layer's own shaping and sum
+    with jax.named_scope("moe.combine"):     # the layer's own shaping and casts
         u = u.reshape(-1, shape[-1]).astype(dt)
     gate, e = route(spec, p["router"], u)
+    # counted while tracing, as attention.path.*: which dispatch a layer took
     if spec.top_k == 1:
+        METRICS.increment("moe.dispatch.path.choice")
         y = _one_choice(spec, p, u, gate, e, dt)
         with jax.named_scope("moe.combine"):
             return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
+    METRICS.increment("moe.dispatch.path.pairs")
+    METRICS.increment("moe.dispatch.blocks", _pair_block_shape(e.size)[1])
     experts = {name: p[name] for name in ("wg", "wu", "wdn")}
-    one = jax.checkpoint(functools.partial(_one_choice, spec, dt=dt))
-
-    def add_choice(acc, ge):
-        y = one(experts, u, *ge)
-        with jax.named_scope("moe.combine"):
-            return acc + y, None
-
-    with jax.named_scope("moe.combine"):
-        zero = jnp.zeros(u.shape, jnp.float32)
-    y, _ = lax.scan(add_choice, zero, (gate, e))
+    y = _pair_blocks(spec, dt, experts, u, gate, e)
     with jax.named_scope("moe.combine"):
         return y.astype(dt).reshape(shape), e.T.reshape(*shape[:-1], spec.top_k)
 
