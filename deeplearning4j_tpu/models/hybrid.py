@@ -15,9 +15,9 @@ shared, so a change to the head or to the ``attention`` registry's kernels
 moves every family.  The head is the embedding transposed or, untied,
 ``params["lm_head"]``.
 
-Three families are here, each with a plain reference whose parameter tree is
+Four families are here, each with a plain reference whose parameter tree is
 the one below (``models/reference/zaya.py``, ``models/reference/ouro.py``,
-``models/reference/keye.py``).
+``models/reference/keye.py``, ``models/reference/joyai.py``).
 
 The ZAYA1 layer (``CCA`` mixer, arXiv:2510.04476; ``MoE`` ffn,
 arXiv:2511.17127):
@@ -72,7 +72,38 @@ The looped layer (Ouro, arXiv:2510.25741):
   heads of width ``d``, rotary positions on ``rotary_factor`` of each head
   (the whole head by default), through the same kernel.
 - ``GatedMLP``: ``W_down(silu(W_gate u) * (W_up u))``, dense.
-- Both with ``post_norm``: ``x + N2(mixer(N1(x)))``, ``x + N4(ffn(N3(x)))``.
+- Both with ``post_norm`` (a field of each spec, on by default): ``x +
+  N2(mixer(N1(x)))``, ``x + N4(ffn(N3(x)))``.
+
+The latent-attention layer (DeepSeek-V3, arXiv:2412.19437, whose keys
+JoyAI-LLM-Flash's config carries):
+
+- ``MLA``: queries out of one normed low-rank latent and keys and values out
+  of another (``mla_qkv``); a head's query and key are a part without position
+  beside a rotary part (interleaved pairs), the rotary key ONE head that all
+  heads share; values of a width of their own.  No kernel takes queries wider
+  than values (``attention_candidate(d_v=...)`` answers ``None`` and counts
+  ``attention.path.xla``), so the scores run on XLA (``chunked_attend``): one
+  example and ``rows`` queries at a time against the keys so far, the
+  example and each of its chunks checkpointed, the heads' outputs kept
+  across a checkpointed block.
+- ``GatedMLP(post_norm=False)`` in the leading layer, then ``MoE`` with
+  ``scoring="sigmoid"``, ``bias_rate``, ``scale`` and ``shared_ff``: each
+  expert's own sigmoid; the ``top_k`` experts with the largest score PLUS a
+  bias per expert chosen, the scores themselves renormalised and scaled their
+  weights; one shared expert added to the routed sum (``_with_shared``); the
+  choices kept across a checkpointed block by name (``moe.chosen``), so that
+  its backward pass reads the forward pass's decisions.  No
+  gradient reaches the bias and the optimizer leaves it alone: the loss
+  ``lm_loss_and_moves`` hands the trainer, beside the rows' losses, what the
+  step adds to it (``bias_moves``: ``bias_rate * sign(mean(count) - count)``
+  from the step's own counts; DESIGN.md section 28).
+- A prediction module behind the trunk (``HybridConfig.mtp``,
+  ``mtp_hidden``): the next token's embedding and the trunk's last state,
+  each normed, merged by ``eh_proj``, one more block (index ``len(layers)``
+  of ``layer_specs``), a norm, the MAIN model's head.  Its cross entropy of
+  the token after the next joins the objective at ``mtp_weight``; both head
+  passes are one weighted call of the chunked head (``objective_parts``).
 
 The loop (``HybridConfig.n_loops``, ``exit_beta``): ``encode_steps`` runs the
 SAME layers ``n_loops`` times as one traced body (``lax.scan`` over loop steps
@@ -88,18 +119,21 @@ token by token, and weights the steps' cross entropies by it, less
 ``dh`` and ``dW`` once.  ``n_loops == 1`` without a gate is one plain
 pass.
 
-Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix`` or
-``dsa.index_proj`` inside), ``attention`` (with ``dsa.index_scores``,
-``dsa.select``, ``dsa.index_loss`` inside), ``attn_out``, ``ffn`` (with
-``moe.router``, ``moe.dispatch``, ``moe.experts`` inside), ``embed``,
-``lm_head_loss`` (with ``loop.exit`` inside: gate, exit distribution,
-objective).  Since PR 36 nothing a step traces here is under no name:
+Sublayer names in a trace: ``layernorm``, ``qkv_proj`` (with ``cca.mix``,
+``dsa.index_proj`` or ``mla.down``, ``mla.up``, ``mla.rope`` inside),
+``attention`` (with ``dsa.index_scores``, ``dsa.select``, ``dsa.index_loss``
+or ``mla.attend`` inside), ``attn_out``, ``ffn`` (with ``moe.router``,
+``moe.dispatch``, ``moe.experts``, ``moe.shared`` inside), ``embed`` (with
+``mtp.merge`` inside), ``optimizer`` (with ``moe.bias_update`` inside: the
+counts and the sign rule), ``lm_head_loss`` (with ``loop.exit`` inside: gate,
+exit distribution, objective).  Since PR 36 nothing a step traces here is under no name:
 ``residual`` (the blocks' adds to the stream, a step's state handed on),
 ``loss_reduce`` (the layers' own losses added to the rows'), ``dsa.attend``
 in ``attention`` (the attention over the selection; the kernels' entry
 points name it, beside the mask they take, which is ``dsa.select``'s) and
 ``moe.combine`` in ``ffn`` (the expert layer's shaping, casts and the zeros
-its sums start from).  A ``dsa.*`` or ``moe.*`` name never encloses another:
+its sums start from).  A ``dsa.*``, ``mla.*`` or ``moe.*`` name never
+encloses another of its family:
 the readers take the first they meet on a path, so that a sublayer's parts
 add up.
 """
@@ -168,14 +202,22 @@ def _shift(x, n: int):
     return jnp.pad(x, pad)[:, :x.shape[1]]
 
 
-def _rope(x, theta: float, rotary: int):
+def _rope(x, theta: float, rotary: int, interleaved: bool = False):
     """``x`` (B, T, heads, d) f32: rotate the first ``rotary`` features of
-    each head by position, feature ``i`` paired with ``i + rotary // 2``."""
+    each head by position, feature ``i`` paired with ``i + rotary // 2`` or,
+    ``interleaved``, feature ``2i`` with ``2i + 1`` (pair ``i`` turns at the
+    same rate either way: the two differ by a permutation of the features)."""
     half = rotary // 2
     inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
                   * (-2.0 * math.log(theta) / rotary))
     ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv
     cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    if interleaved:
+        pairs = x[..., :rotary].reshape(*x.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return jnp.concatenate(
+            [turned.reshape(*x.shape[:-1], rotary), x[..., rotary:]], axis=-1)
     x1, x2 = x[..., :half], x[..., half:rotary]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotary:]], axis=-1)
@@ -280,8 +322,8 @@ class Attention:
     head_dim: int = 128
     rope_theta: float = 1_000_000.0
     rotary_factor: float = 1.0
+    post_norm: bool = True  # normed once more before the residual (sandwich)
     key = "attn"
-    post_norm = True       # normed once more before the residual (sandwich)
     aux_loss = False
 
     def init(self, key, d_model: int, dtype) -> Params:
@@ -362,8 +404,10 @@ class SparseAttention:
 
 #: what a checkpointed block keeps of a sparse mixer besides its input: the
 #: heads' outputs (B, T, H, d), the index loss (B,) and, on the kernel's path,
-#: the rows' log-sum-exp (B, chunks, G, rows, H / G) its backward reads
-KEPT = ("dsa.out", "dsa.loss", "dsa.lse")
+#: the rows' log-sum-exp (B, chunks, G, rows, H / G) its backward reads; of a
+#: latent-attention mixer the heads' outputs (B, T, H, d_v); and of a router
+#: that selects on a biased score its choices (N, top_k)
+KEPT = ("dsa.out", "dsa.loss", "dsa.lse", "mla.out", "moe.chosen")
 #: stands for "not selected" in a row of scores: finite, so that 0 x it is 0
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 #: key lengths a sequence's query chunks are computed at, on both paths:
@@ -482,10 +526,10 @@ def _index_loss(scores, chosen, target):
         chosen, jax.scipy.special.xlogy(target, target) - target * logp, 0.0))
 
 
-def _key_spans(spec: SparseAttention, t: int):
+def _key_spans(spec, t: int):
     """``(chunk rows, [(first chunk, chunks, keys)])``: the sequence cut into
-    chunks of ``rows`` queries (one chunk where it does not divide), in at
-    most ``_KEY_SPANS`` runs of chunks that share a key length."""
+    chunks of ``spec.rows`` queries (one chunk where it does not divide), in
+    at most ``_KEY_SPANS`` runs of chunks that share a key length."""
     c = spec.rows if t % spec.rows == 0 else t
     n = t // c
     per = -(-n // min(_KEY_SPANS, n))
@@ -493,20 +537,21 @@ def _key_spans(spec: SparseAttention, t: int):
                for a in range(0, n, per)]
 
 
-def _over_chunks(spec: SparseAttention, t: int, fn, chunked, whole):
+def _over_chunks(spec, t: int, fn, chunked, whole, scope="dsa.attend"):
     """``fn(*[a[:keys] for a in whole], start, *[a's chunk for a in chunked])``
     for every chunk of queries of one example, in order, one at a time; its
-    results stacked over the chunks."""
+    results stacked over the chunks.  ``scope`` names the cutting and the
+    stacking: the part of the mixer they are made for."""
     c, spans = _key_spans(spec, t)
     outs = []
     for first, n, keys in spans:
         rows = slice(first * c, (first + n) * c)
-        with jax.named_scope("dsa.attend"):   # cutting the sequence, and below
+        with jax.named_scope(scope):          # cutting the sequence, and below
             xs = (jnp.arange(first, first + n, dtype=jnp.int32) * c,
                   *(a[rows].reshape(n, c, *a.shape[1:]) for a in chunked))
             held = [a[:keys] for a in whole]
         outs.append(lax.map(lambda x: fn(*held, *x), xs))
-    with jax.named_scope("dsa.attend"):       # putting its chunks together
+    with jax.named_scope(scope):              # putting its chunks together
         return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
 
 
@@ -744,6 +789,149 @@ def sparse_selection(spec: SparseAttention, p, u, dt, reduce=None):
     return out if reduce is not None else out.reshape(u.shape[0], t, t)
 
 
+@dataclasses.dataclass(frozen=True)
+class MLA:
+    """Multi-head latent attention: queries and, together, keys and values
+    come up out of normed low-rank latents; a head's query and key are a part
+    that carries no position beside a rotary part, the rotary key ONE head
+    that every query head shares; the values have a width of their own."""
+    n_heads: int = 32
+    q_rank: int = 1536               # the queries' latent
+    kv_rank: int = 512               # the keys' and values' latent
+    nope_dim: int = 128              # of each head's q and k, without position
+    rope_dim: int = 64               # of each head's q, and the shared key
+    v_dim: int = 128
+    rope_theta: float = 10_000.0
+    rope_interleave: bool = True     # pairs (2i, 2i + 1), not (i, i + d / 2)
+    rows: int = 512                  # queries the XLA path attends from at a time
+    norm_eps: float = 1e-6           # of the two latents' norms
+    key = "mla"
+    post_norm = False
+    aux_loss = False
+
+    def init(self, key, d_model: int, dtype) -> Params:
+        h, rq, rkv = self.n_heads, self.q_rank, self.kv_rank
+        dn, dr, dv = self.nope_dim, self.rope_dim, self.v_dim
+        ks = jax.random.split(key, 5)
+        return {
+            "wqa": _normal(ks[0], (d_model, rq), d_model ** -0.5, dtype),
+            "q_norm": jnp.ones((rq,), dtype),
+            "wqb": _normal(ks[1], (rq, h * (dn + dr)), rq ** -0.5, dtype),
+            "wkva": _normal(ks[2], (d_model, rkv + dr), d_model ** -0.5, dtype),
+            "kv_norm": jnp.ones((rkv,), dtype),
+            "wkvb": _normal(ks[3], (rkv, h * (dn + dv)), rkv ** -0.5, dtype),
+            "wo": _normal(ks[4], (h * dv, d_model), (h * dv) ** -0.5, dtype),
+        }
+
+
+def mla_qkv(spec: MLA, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> the heads' parts in ``dt``:
+    ``(q_nope (B, T, H, nope), q_rope (B, T, H, rope), k_nope (B, T, H, nope),
+    k_rope (B, T, rope), v (B, T, H, v_dim))``.  The training form: every
+    head's keys and values are up-projected, nothing is absorbed into the
+    queries.  Each part comes out of its own columns of the up-projection, so
+    that a part's gradient goes back into its own product and no cotangent of
+    the joined width is ever made."""
+    b, t, _ = u.shape
+    h, dn = spec.n_heads, spec.nope_dim
+    with jax.named_scope("mla.down"):
+        x = u.astype(dt)
+        cq = rms_norm(_project(x, p["wqa"], dt), p["q_norm"], spec.norm_eps)
+        down = _project(x, p["wkva"], dt)                # f32 (B, T, rank + rope)
+        ckv = rms_norm(down[..., :spec.kv_rank], p["kv_norm"], spec.norm_eps)
+        cq, ckv = cq.astype(dt), ckv.astype(dt)
+    with jax.named_scope("mla.up"):
+        def up(c, w, columns):
+            w = w.reshape(w.shape[0], h, -1)[..., columns].astype(dt)
+            return jnp.einsum("btr,rhd->bthd", c, w,
+                              preferred_element_type=jnp.float32)
+
+        q_nope, q_rope = (up(cq, p["wqb"], c)
+                          for c in (slice(None, dn), slice(dn, None)))
+        k_nope, v = (up(ckv, p["wkvb"], c)
+                     for c in (slice(None, dn), slice(dn, None)))
+    with jax.named_scope("mla.rope"):
+        turn = functools.partial(_rope, theta=spec.rope_theta,
+                                 rotary=spec.rope_dim,
+                                 interleaved=spec.rope_interleave)
+        k_rope = turn(down[:, :, None, spec.kv_rank:])[:, :, 0]
+        return tuple(a.astype(dt)
+                     for a in (q_nope, turn(q_rope), k_nope, k_rope, v))
+
+
+def mla_heads(q_nope, q_rope, k_nope, k_rope, v):
+    """``mla_qkv``'s parts (any leading axes before ``(T, ...)``) joined into
+    ``q, k (..., T, H, nope + rope)`` and ``v``: the one rotary key handed to
+    every head."""
+    k_rope = jnp.broadcast_to(k_rope[..., None, :],
+                              (*k_nope.shape[:-1], k_rope.shape[-1]))
+    return (jnp.concatenate([q_nope, q_rope], axis=-1),
+            jnp.concatenate([k_nope, k_rope], axis=-1), v)
+
+
+def _causal_chunk(k, v, start, q):
+    """One chunk of queries ``q (C, H, d)`` (the first at position ``start``)
+    against the keys so far ``k (L, H, d)``, ``v (L, H, d_v)``: ``(C, H,
+    d_v)``.  As ``_sparse_chunk``: one exponential, in the values' dtype for
+    the product, and the rows' sums divide the product's output."""
+    tq = start + jnp.arange(q.shape[0], dtype=jnp.int32)
+    causal = jnp.arange(k.shape[0], dtype=jnp.int32)[None, :] <= tq[:, None]
+    s = jnp.einsum("thd,shd->hts", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    s = jnp.where(causal[None], s, _MASKED)
+    e = jnp.exp(s - lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+    inv = 1.0 / jnp.sum(e, axis=-1)                           # (H, C)
+    out = jnp.einsum("hts,shd->thd", e.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32) * inv.T[..., None]
+    return out.astype(q.dtype)
+
+
+def chunked_attend(spec, operands, scope: str, join=lambda *a: a):
+    """Causal attention of ``q (B, T, H, d)`` over ``k (B, T, H, d)`` and
+    ``v (B, T, H, d_v)`` on XLA for the shapes no kernel takes (``d_v`` other
+    than ``d``, more rows than a kernel holds): one example and ``spec.rows``
+    queries at a time against the keys so far (``_key_spans``), each chunk
+    recomputed in the backward pass, so that no more than ``rows x T`` scores
+    a head are ever held.  ``operands`` are ``(q, k, v)`` or, with ``join``,
+    what ``join`` makes one example's ``(q, k, v)`` of (a mixer whose heads
+    share a part joins them an example at a time)."""
+    chunk = jax.checkpoint(_causal_chunk)
+
+    def example(parts):
+        with jax.named_scope(scope):
+            q, k, v = join(*parts)
+        out = _over_chunks(spec, q.shape[0], chunk, (q,), (k, v), scope=scope)
+        with jax.named_scope(scope):
+            return out.reshape(q.shape[0], *out.shape[-2:])
+
+    # an example's joined heads and its spans' slices of them are made again
+    # in its backward pass, not kept for every example of the batch
+    return lax.map(jax.checkpoint(example), tuple(operands))
+
+
+def mla_mixer(spec: MLA, p, u, dt):
+    """Normed activations ``u`` (B, T, E) -> the mixer's output (B, T, E)."""
+    from ..ops.pallas.attention import attention_candidate
+
+    b, t, _ = u.shape
+    METRICS.increment("mla.layers")      # per layer per trace, as attention.path
+    with jax.named_scope("qkv_proj"):
+        parts = mla_qkv(spec, p, u, dt)
+    with jax.named_scope("attention"), jax.named_scope("mla.attend"):
+        # asked for its count (attention.path.xla): no kernel takes queries
+        # and keys wider than the values
+        if attention_candidate(t, spec.n_heads, spec.nope_dim + spec.rope_dim,
+                               d_v=spec.v_dim):
+            raise NotImplementedError("latent attention has no kernel path")
+        out = chunked_attend(spec, parts, "mla.attend", join=mla_heads)
+        # kept across a checkpointed block, as the sparse mixer's: the block's
+        # recomputed forward stops at the chunks' inputs
+        out = checkpoint_name(out, KEPT[3])
+    with jax.named_scope("attn_out"):
+        return jnp.einsum("btf,fd->btd", out.reshape(b, t, -1),
+                          p["wo"].astype(dt))
+
+
 # --------------------------------------------------------------------------- ffn
 
 @dataclasses.dataclass(frozen=True)
@@ -751,19 +939,41 @@ class MoE:
     """Top-k mixture of gated-SiLU experts behind a router MLP (or, with
     ``router_hidden = 0``, one linear layer).  ``top_k`` decides the dispatch:
     one sort of the tokens at top-1 (``_one_choice``), one sort of the (token,
-    choice) pairs run in blocks above it (``_pair_blocks``)."""
+    choice) pairs run in blocks above it (``_pair_blocks``).  The scores are a
+    softmax over the experts or (``scoring="sigmoid"``) each expert's own
+    sigmoid; with a ``bias_rate`` the experts are CHOSEN by score plus a bias
+    per expert that no gradient reaches (``router["bias"]``, moved by
+    ``bias_moves`` at ``bias_rate`` a step; 0: a bias that stays where it is)
+    and WEIGHTED by the score alone.
+    ``shared_ff`` is the width of one more expert that every token takes."""
     n_experts: int = 16              # the router's width, as published
     held: tuple[int, int] = (0, 8)   # (first, count): this chip's experts
     router_hidden: int = 256
     d_ff: int = 2048
     top_k: int = 1                   # experts a token
     renormalize: bool = False        # the chosen weights divided by their sum
+    scoring: str = "softmax"         # or "sigmoid"
+    bias_rate: float | None = None   # None: no selection bias; what a step moves it by
+    scale: float = 1.0               # the chosen weights times this
+    shared_ff: int = 0
     key = "moe"
     post_norm = False
+
+    @property
+    def select_bias(self) -> bool:
+        return self.bias_rate is not None
+
+    @property
+    def router_columns(self) -> tuple[str, ...]:
+        """The router's leaves whose LAST axis is the experts: what a
+        placement of the experts reorders together."""
+        return (("w3" if self.router_hidden else "w"),
+                *(("bias",) if self.select_bias else ()))
 
     def init(self, key, d_model: int, dtype) -> Params:
         r, f, n = self.router_hidden, self.d_ff, self.held[1]
         assert self.top_k > 1 or not self.renormalize, "one weight is its own sum"
+        assert self.scoring in ("softmax", "sigmoid")
         ks = jax.random.split(key, 7)
         router = {
             "wd": _normal(ks[0], (d_model, r), d_model ** -0.5, dtype),
@@ -774,12 +984,21 @@ class MoE:
             "w3": _normal(ks[3], (r, self.n_experts), r ** -0.5, dtype),
         } if r else {
             "w": _normal(ks[0], (d_model, self.n_experts), d_model ** -0.5, dtype)}
-        return {
+        if self.select_bias:
+            router["bias"] = jnp.zeros((self.n_experts,), dtype)
+        p = {
             "router": router,
             "wg": _normal(ks[4], (n, d_model, f), d_model ** -0.5, dtype),
             "wu": _normal(ks[5], (n, d_model, f), d_model ** -0.5, dtype),
             "wdn": _normal(ks[6], (n, f, d_model), f ** -0.5, dtype),
         }
+        if self.shared_ff:
+            # a stream of its own off the layer's key: an eighth split would
+            # redraw every model that has no shared expert
+            p["shared"] = GatedMLP(self.shared_ff).init(
+                jax.random.fold_in(key, 7),  # graftlint: disable=RNG01
+                d_model, dtype)
+        return p
 
 
 @jax.named_scope("moe.router")
@@ -796,17 +1015,30 @@ def route(spec: MoE, r, u):
                         + r["b1"])
         b = jax.nn.gelu(jnp.dot(a, r["w2"].astype(jnp.float32), precision=hi)
                         + r["b2"])
-        pi = jax.nn.softmax(
-            jnp.dot(b, r["w3"].astype(jnp.float32), precision=hi), axis=-1)
+        logits = jnp.dot(b, r["w3"].astype(jnp.float32), precision=hi)
     else:
-        pi = jax.nn.softmax(
-            jnp.dot(u, r["w"].astype(jnp.float32), precision=hi), axis=-1)
+        logits = jnp.dot(u, r["w"].astype(jnp.float32), precision=hi)
+    pi = (jax.nn.sigmoid(logits) if spec.scoring == "sigmoid"
+          else jax.nn.softmax(logits, axis=-1))
+    # chosen by the biased score, weighted by the score itself
+    chosen_by = (pi + lax.stop_gradient(r["bias"].astype(jnp.float32))
+                 if spec.select_bias else pi)
     if spec.top_k == 1:
-        e = jnp.argmax(pi, axis=-1).astype(jnp.int32)
-        return jnp.take_along_axis(pi, e[:, None], axis=-1)[:, 0], e
-    gate, e = lax.top_k(pi, spec.top_k)
+        e = jnp.argmax(chosen_by, axis=-1).astype(jnp.int32)
+        gate = jnp.take_along_axis(pi, e[:, None], axis=-1)[:, 0]
+        return (gate if spec.scale == 1.0 else gate * spec.scale), e
+    if spec.select_bias:
+        # kept across a checkpointed block by name: the backward pass reads
+        # the choices the forward pass made, and does not decide the near-ties
+        # a second time on activations it has made again
+        e = checkpoint_name(lax.top_k(chosen_by, spec.top_k)[1], KEPT[4])
+        gate = jnp.take_along_axis(pi, e, axis=-1)
+    else:
+        gate, e = lax.top_k(pi, spec.top_k)
     if spec.renormalize:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if spec.scale != 1.0:
+        gate = gate * spec.scale
     return gate.T, e.T.astype(jnp.int32)
 
 
@@ -1026,23 +1258,33 @@ def moe_ffn(spec: MoE, p, u, dt):
     # counted while tracing, as attention.path.*: which dispatch a layer took
     if spec.top_k == 1:
         METRICS.increment("moe.dispatch.path.choice")
-        y = _one_choice(spec, p, u, gate, e, dt)
+        y = _with_shared(spec, p, u, _one_choice(spec, p, u, gate, e, dt), dt)
         with jax.named_scope("moe.combine"):
             return y.astype(dt).reshape(shape), e.reshape(shape[:-1])
     METRICS.increment("moe.dispatch.path.pairs")
     METRICS.increment("moe.dispatch.blocks", _pair_block_shape(e.size)[1])
     experts = {name: p[name] for name in ("wg", "wu", "wdn")}
-    y = _pair_blocks(spec, dt, experts, u, gate, e)
+    y = _with_shared(spec, p, u, _pair_blocks(spec, dt, experts, u, gate, e), dt)
     with jax.named_scope("moe.combine"):
         return y.astype(dt).reshape(shape), e.T.reshape(*shape[:-1], spec.top_k)
+
+
+def _with_shared(spec: MoE, p, u, y, dt):
+    """The routed experts' sum ``y (N, E)`` f32 plus, where the layer has
+    one, the shared expert's output for every token ``u (N, E)``: the same on
+    every chip that shares the layer, so counted ONCE when shares are added."""
+    if not spec.shared_ff:
+        return y
+    with jax.named_scope("moe.shared"):
+        return y + _gated_silu(p["shared"], u, dt, jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
 class GatedMLP:
     """One gated-SiLU MLP for every token."""
     d_ff: int = 5632
+    post_norm: bool = True           # as ``Attention.post_norm``
     key = "mlp"
-    post_norm = True
 
     def init(self, key, d_model: int, dtype) -> Params:
         f = self.d_ff
@@ -1056,19 +1298,24 @@ class GatedMLP:
 def gated_mlp(spec: GatedMLP, p, u, dt):
     """Normed activations ``u`` (B, T, E) -> ``(the layer's output, None)``:
     there are no expert choices to report."""
-    u = u.astype(dt)
+    return _gated_silu(p, u.astype(dt), dt), None
 
+
+def _gated_silu(p, u, dt, out_dtype=None):
+    """``W_down(silu(W_gate u) * (W_up u))`` of ``u (..., E)`` in ``dt``; the
+    last product leaves in ``out_dtype`` (``dt`` by default)."""
     def up(w):
-        return jnp.einsum("btd,df->btf", u, w.astype(dt),
+        return jnp.einsum("...d,df->...f", u, w.astype(dt),
                           preferred_element_type=jnp.float32)
 
     hidden = (jax.nn.silu(up(p["wg"])) * up(p["wu"])).astype(dt)
-    return jnp.einsum("btf,fd->btd", hidden, p["wdn"].astype(dt)), None
+    return jnp.einsum("...f,fd->...d", hidden, p["wdn"].astype(dt),
+                      preferred_element_type=out_dtype)
 
 
 #: spec class -> the function that runs it
 MIXERS = {CCA: cca_mixer, Attention: attention_mixer,
-          SparseAttention: sparse_attention_mixer}
+          SparseAttention: sparse_attention_mixer, MLA: mla_mixer}
 FFNS = {MoE: moe_ffn, GatedMLP: gated_mlp}
 
 
@@ -1083,26 +1330,50 @@ class HybridConfig:
     spec, ffn spec)`` per layer; ``n_loops`` how many times the layers run
     over the same weights; ``exit_beta`` the weight of the exit
     distribution's entropy in the looped objective, and None for a model
-    without an exit gate."""
+    without an exit gate; ``mtp`` the ``(mixer spec, ffn spec)`` of ONE more
+    block behind the trunk that predicts the token after the next
+    (``mtp_hidden``), its cross entropy weighted ``mtp_weight`` in the
+    objective, and None for a model without it."""
     base: TransformerConfig
     layers: tuple[tuple[Any, Any], ...]
     norm_eps: float = 1e-5
     n_loops: int = 1
     exit_beta: float | None = None
+    mtp: tuple[Any, Any] | None = None
+    mtp_weight: float = 0.3
+
+
+def layer_specs(cfg: HybridConfig) -> tuple[tuple[Any, Any], ...]:
+    """Every block's ``(mixer, ffn)``: the trunk's layers and, last (index
+    ``len(cfg.layers)``), the prediction module's block where there is one."""
+    return cfg.layers + ((cfg.mtp,) if cfg.mtp is not None else ())
+
+
+def layer_path(cfg: HybridConfig, i: int) -> tuple:
+    """Where block ``i`` of ``layer_specs`` keeps its parameters."""
+    return ("layers", i) if i < len(cfg.layers) else ("mtp", "block")
+
+
+def layer_params(params, cfg: HybridConfig, i: int):
+    a, b = layer_path(cfg, i)
+    return params[a][b]
+
+
+def _init_layer(key, mixer, ffn, d: int, pd) -> Params:
+    km, kf = jax.random.split(key)
+    lp = {"norm1": jnp.ones((d,), pd), mixer.key: mixer.init(km, d, pd),
+          "norm2": jnp.ones((d,), pd), ffn.key: ffn.init(kf, d, pd)}
+    for spec, name in ((mixer, "norm1_post"), (ffn, "norm2_post")):
+        if spec.post_norm:
+            lp[name] = jnp.ones((d,), pd)
+    return lp
 
 
 def init_params(key, cfg: HybridConfig) -> Params:
     pd, d = cfg.base.param_dtype, cfg.base.d_model
     keys = jax.random.split(key, len(cfg.layers) + 1)
-    layers = []
-    for (mixer, ffn), k in zip(cfg.layers, keys[:-1]):
-        km, kf = jax.random.split(k)
-        lp = {"norm1": jnp.ones((d,), pd), mixer.key: mixer.init(km, d, pd),
-              "norm2": jnp.ones((d,), pd), ffn.key: ffn.init(kf, d, pd)}
-        for spec, name in ((mixer, "norm1_post"), (ffn, "norm2_post")):
-            if spec.post_norm:
-                lp[name] = jnp.ones((d,), pd)
-        layers.append(lp)
+    layers = [_init_layer(k, mixer, ffn, d, pd)
+              for (mixer, ffn), k in zip(cfg.layers, keys[:-1])]
     params = {"tok_embed": _normal(keys[-1], (cfg.base.vocab_size, d), 0.02, pd),
               "final_norm": jnp.ones((d,), pd), "layers": layers}
     kh, kg = jax.random.split(jax.random.fold_in(keys[-1], 1))
@@ -1111,6 +1382,14 @@ def init_params(key, cfg: HybridConfig) -> Params:
     if cfg.exit_beta is not None:
         params["exit_gate"] = {"w": _normal(kg, (d,), d ** -0.5, pd),
                                "b": jnp.zeros((), pd)}
+    if cfg.mtp is not None:
+        assert cfg.n_loops == 1, "the module reads ONE pass's last state"
+        kp, kb = jax.random.split(jax.random.fold_in(keys[-1], 2))
+        params["mtp"] = {
+            "enorm": jnp.ones((d,), pd), "hnorm": jnp.ones((d,), pd),
+            "eh_proj": _normal(kp, (2 * d, d), (2 * d) ** -0.5, pd),
+            "block": _init_layer(kb, *cfg.mtp, d, pd),
+            "norm": jnp.ones((d,), pd)}
     return params
 
 
@@ -1122,10 +1401,10 @@ def rms_norm(x, w, eps):
 
 
 def block(lp, x, cfg: HybridConfig, i: int):
-    """Layer ``i``: ``(x + mixer + ffn, the ffn's expert choices, the mixer's
-    own loss (B,) or None)``, each half's output normed before it is added
-    where its spec says so."""
-    mixer, ffn = cfg.layers[i]
+    """Block ``i`` of ``layer_specs``: ``(x + mixer + ffn, the ffn's expert
+    choices, the mixer's own loss (B,) or None)``, each half's output normed
+    before it is added where its spec says so."""
+    mixer, ffn = layer_specs(cfg)[i]
     dt, eps = cfg.base.dtype, cfg.norm_eps
     a = MIXERS[type(mixer)](mixer, lp[mixer.key], rms_norm(x, lp["norm1"], eps), dt)
     a, aux = a if mixer.aux_loss else (a, None)
@@ -1140,19 +1419,30 @@ def block(lp, x, cfg: HybridConfig, i: int):
         return x + y, e, aux
 
 
+def _block_fn(cfg: HybridConfig):
+    """``block``, checkpointed where the configuration says so."""
+    if not cfg.base.remat:
+        return block
+    return jax.checkpoint(
+        block, static_argnums=(2, 3),
+        policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+
+
 def run_layers(params, tokens, cfg: HybridConfig):
     """``tokens`` (B, T) -> ``(every loop step's final normed hidden
     (n_loops, B, T, E), [e per layer, each (n_loops, B, T[, top_k]) or None],
     the layers' own losses summed (B,), or None where no mixer has one)``.
     The loop is one ``lax.scan`` whose body holds each layer once;
     ``n_loops == 1`` is the layers in line, with no loop around them."""
+    return _run_layers(params, tokens, cfg)[:3]
+
+
+def _run_layers(params, tokens, cfg: HybridConfig):
+    """``run_layers`` and, fourth, the last layer's output BEFORE the final
+    norm ``(B, T, E)`` (None under a loop): what a prediction module reads."""
     with jax.named_scope("embed"):
         x = jnp.take(params["tok_embed"], tokens, axis=0).astype(cfg.base.dtype)
-    fn = block
-    if cfg.base.remat:
-        fn = jax.checkpoint(
-            block, static_argnums=(2, 3),
-            policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+    fn = _block_fn(cfg)
     # counted while tracing, as attention.path.*: what one trace of the model
     # holds (layers) against what a step runs (layer applications)
     METRICS.increment("loop.steps", cfg.n_loops)
@@ -1166,21 +1456,21 @@ def run_layers(params, tokens, cfg: HybridConfig):
             if aux is not None:
                 with jax.named_scope("loss_reduce"):
                     own = aux if own is None else own + aux
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), choices, own
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), choices, own, x
 
     if cfg.n_loops == 1:
-        h, choices, own = step(x)
+        h, choices, own, last = step(x)
         with jax.named_scope("residual"):       # one step, stacked as a loop's
             return (h[None], [None if e is None else e[None] for e in choices],
-                    own)
+                    own, last)
 
     def body(x, _):
-        h, choices, own = step(x)
+        h, choices, own, _ = step(x)
         return h, (h, choices, own)  # the normed state is the next step's input
 
     hs, choices, own = lax.scan(body, x, None, length=cfg.n_loops)[1]
     with jax.named_scope("loss_reduce"):
-        return hs, choices, None if own is None else own.sum(axis=0)
+        return hs, choices, None if own is None else own.sum(axis=0), None
 
 
 def encode_steps(params, tokens, cfg: HybridConfig):
@@ -1206,25 +1496,123 @@ def forward(params, tokens, cfg: HybridConfig):
                           head.astype(cfg.base.dtype)).astype(jnp.float32)
 
 
-def loss_parts(params, tokens, targets, cfg: HybridConfig):
-    """``(each example's mean cross entropy (B,), the layers' own losses
-    summed (B,) or None)``: the two parts of the objective, whose gradients
-    never meet (a mixer's own loss reads its inputs behind a
-    ``stop_gradient``)."""
-    hs, _, own = run_layers(params, tokens, cfg)
+def mtp_hidden(params, last, targets, cfg: HybridConfig):
+    """The prediction module (DeepSeek-V3 section 2.2, depth 1): ``(its normed
+    state (B, T, E), its ffn's expert choices)``.  Position ``i`` merges the
+    embedding of the NEXT token ``targets[i]`` with the trunk's last state
+    ``last[i]`` (before the final norm), each under a norm of its own,
+    through ``eh_proj`` (embedding first), runs block ``len(cfg.layers)`` of
+    ``layer_specs`` and a norm of its own; the MAIN model's head then reads
+    the token after the next from it.  The embedding is the main model's."""
+    m, eps, dt = params["mtp"], cfg.norm_eps, cfg.base.dtype
+    METRICS.increment("mtp.modules")
+    with jax.named_scope("embed"):
+        emb = jnp.take(params["tok_embed"], targets, axis=0).astype(dt)
+        with jax.named_scope("mtp.merge"):
+            both = jnp.concatenate([rms_norm(emb, m["enorm"], eps),
+                                    rms_norm(last, m["hnorm"], eps)], axis=-1)
+            x = jnp.einsum("btf,fd->btd", both, m["eh_proj"].astype(dt))
+    x, e, _ = _block_fn(cfg)(m["block"], x, cfg, len(cfg.layers))
+    return rms_norm(x, m["norm"], eps), e
+
+
+def objective_parts(params, tokens, targets, cfg: HybridConfig):
+    """``({"objective", "lm", "own", "mtp"}, choices)``: each example's
+    objective ``(B,)``, differentiable, beside its parts (``lm`` the mean
+    next-token cross entropy; ``own`` the layers' own losses summed, whose
+    gradients never meet the others': a mixer's own loss reads its inputs
+    behind a ``stop_gradient``; ``mtp`` the prediction module's mean cross
+    entropy over the positions that have a target; None for a part the model
+    lacks), and every block's expert choices, ``layer_specs``' order.
+
+    With a prediction module both head passes are ONE call of the chunked
+    head over ``2 B`` rows, the objective's weights inside it (``1 / T`` a
+    main position; ``mtp_weight / (T - 1)`` a module position and 0 at the
+    last one, which has no token after the next): one ``dh``, one ``dW``.
+    ``lm`` and ``mtp`` are then values that carry no gradient."""
+    hs, choices, own, last = _run_layers(params, tokens, cfg)
     with jax.named_scope("residual"):           # the last step's, handed on
         h = hs[-1]
-    return lm_head_loss(params, h, targets, cfg.base, per_example=True), own
+    if cfg.mtp is None:
+        lm = lm_head_loss(params, h, targets, cfg.base, per_example=True)
+        with jax.named_scope("loss_reduce"):
+            total = lm if own is None else lm + own
+        return {"objective": total, "lm": lm, "own": own, "mtp": None}, choices
+    h2, e2 = mtp_hidden(params, last, targets, cfg)
+    b, t = targets.shape
+    with jax.named_scope("lm_head_loss"):
+        # the token after the next; the last column wraps and weighs nothing
+        later = jnp.roll(targets, -1, axis=1)
+        has_target = jnp.arange(t) < t - 1
+        weights = jnp.concatenate([
+            jnp.full((b, t), 1.0 / t, jnp.float32),
+            jnp.broadcast_to(jnp.where(has_target, cfg.mtp_weight / (t - 1), 0.0
+                                       ).astype(jnp.float32), (b, t))])
+        weighted, xent = lm_head_token_loss(
+            params, jnp.concatenate([h, h2]), jnp.concatenate([targets, later]),
+            cfg.base, weights=weights)
+        lm = xent[:b].mean(axis=1)
+        mtp = jnp.sum(jnp.where(has_target, xent[b:], 0.0), axis=1) / (t - 1)
+    with jax.named_scope("loss_reduce"):
+        total = weighted[:b].sum(axis=1) + weighted[b:].sum(axis=1)
+        if own is not None:
+            total = total + own
+    return ({"objective": total, "lm": lm, "own": own, "mtp": mtp},
+            choices + [None if e2 is None else e2[None]])
+
+
+def loss_parts(params, tokens, targets, cfg: HybridConfig):
+    """``(each example's mean cross entropy (B,), the layers' own losses
+    summed (B,) or None)``: two parts of the objective whose gradients never
+    meet (``objective_parts`` has them all)."""
+    parts = objective_parts(params, tokens, targets, cfg)[0]
+    return parts["lm"], parts["own"]
 
 
 def lm_loss_per_example(params, tokens, targets, cfg: HybridConfig):
-    """Each example's mean cross entropy, ``(B,)``, plus the layers' own
-    losses where a mixer has one, with the whole batch's tokens grouped
-    together in the expert layers and chunked together in the head: the loss
-    a ``DataParallelTrainer(per_example_loss=True)`` takes."""
-    xent, own = loss_parts(params, tokens, targets, cfg)
-    with jax.named_scope("loss_reduce"):
-        return xent if own is None else xent + own
+    """Each example's objective, ``(B,)``: its mean cross entropy, plus the
+    layers' own losses where a mixer has one, plus the prediction module's
+    weighted cross entropy where there is one, with the whole batch's tokens
+    grouped together in the expert layers and chunked together in the head:
+    the loss a ``DataParallelTrainer(per_example_loss=True)`` takes."""
+    return objective_parts(params, tokens, targets, cfg)[0]["objective"]
+
+
+def bias_moves(cfg: HybridConfig, choices) -> dict:
+    """What one step adds to the leaves its gradient does not reach, ``{leaf
+    path: rows' validity (B,) -> array}``: for every expert layer whose
+    ``bias_rate`` is not zero, from this step's counts ``c`` of the VALID
+    rows' (token, choice) pairs over ALL its experts, ``bias_rate *
+    sign(mean(c) - c)`` for its ``router["bias"]`` (the auxiliary-loss-free
+    balancing of DeepSeek-V3 section 2.1.2: an expert that got more than its
+    share is chosen a little less next step).  The trainer calls each with
+    the mask it weighs the rows' losses by, so that the rows a ragged batch
+    was padded with count for nothing.  ``choices`` as ``objective_parts``
+    gives them: a block's ``(steps, B, T, top_k)``."""
+    moves = {}
+    for i, ((_, ffn), e) in enumerate(zip(layer_specs(cfg), choices)):
+        if e is None or not getattr(ffn, "bias_rate", None):
+            continue
+        METRICS.increment("moe.bias_updates")    # per layer per trace
+
+        def delta(valid, e=e, ffn=ffn):
+            with jax.named_scope("optimizer"), jax.named_scope("moe.bias_update"):
+                chose = e[..., None] == jnp.arange(ffn.n_experts)
+                c = jnp.sum(chose & valid[None, :, None, None, None],
+                            axis=(0, 1, 2, 3), dtype=jnp.float32)
+                return ffn.bias_rate * jnp.sign(jnp.mean(c) - c)
+
+        moves["/".join(map(str, (*layer_path(cfg, i), ffn.key, "router",
+                                 "bias")))] = delta
+    return moves
+
+
+def lm_loss_and_moves(params, tokens, targets, cfg: HybridConfig):
+    """``(lm_loss_per_example, bias_moves)`` of one pass: the loss a
+    ``DataParallelTrainer(per_example_loss=True)`` takes for a model some of
+    whose leaves a step moves by a rule of their own."""
+    parts, choices = objective_parts(params, tokens, targets, cfg)
+    return parts["objective"], bias_moves(cfg, choices)
 
 
 def lm_loss(params, tokens, targets, cfg: HybridConfig):
@@ -1356,32 +1744,56 @@ def publish_selection_stats(counts) -> dict:
             "empty_tile_share": empty / max(tiles, 1.0)}
 
 
-def routing_stats(params, tokens, cfg: HybridConfig):
-    """(Token, choice) pairs per expert, ``(layers, n_experts)`` int32, for ``tokens``
-    (B, T) under ``params``: one forward pass, called outside the step."""
-    _, choices = encode(params, tokens, cfg)
-    return jnp.stack([expert_counts(cfg.layers[i][1], e)
-                      for i, e in enumerate(choices)])
+def _first_moe(cfg: HybridConfig) -> MoE:
+    return next(ffn for _, ffn in layer_specs(cfg) if isinstance(ffn, MoE))
 
 
-def place_experts(params, tokens, cfg: HybridConfig):
+def expert_choices(params, tokens, cfg: HybridConfig, targets=None):
+    """Every block's expert choices for ``tokens`` (B, T), ``layer_specs``'
+    order, each ``(n_loops, B, T[, top_k])`` or None for a block without
+    experts: the layers' pass and, where there is a prediction module, its
+    block's, which reads ``targets`` (B, T); no head runs."""
+    _, choices, _, last = _run_layers(params, tokens, cfg)
+    if cfg.mtp is not None:
+        e = mtp_hidden(params, last, targets, cfg)[1]
+        choices = choices + [None if e is None else e[None]]
+    return choices
+
+
+def routing_stats(params, tokens, cfg: HybridConfig, targets=None):
+    """(Token, choice) pairs per expert, ``(blocks, n_experts)`` int32, for
+    ``tokens`` (B, T) under ``params``, a row per block of ``layer_specs``
+    (zeros for one without experts): one forward pass, called outside the
+    step.  A prediction module's block reads ``targets`` (B, T)."""
+    none = jnp.zeros((_first_moe(cfg).n_experts,), jnp.int32)
+    return jnp.stack([none if e is None else expert_counts(ffn, e)
+                      for (_, ffn), e in zip(
+                          layer_specs(cfg),
+                          expert_choices(params, tokens, cfg, targets))])
+
+
+def place_experts(params, tokens, cfg: HybridConfig, targets=None):
     """Choose WHICH experts this chip holds, layer by layer, from the routing
     statistics of ``tokens`` (B, T): the experts are dealt to the chips that
     share a layer heaviest first, each to the chip with the lighter load so
     far (as expert-parallel deployments place experts by load), and the
-    router's output columns are reordered so that this chip's are its
-    ``held`` range.  The experts' own weights are drawn alike, so the
-    reordering is the whole placement.  Without it a randomly initialised
-    router sends anything from a third to two thirds of the tokens here
-    (PERF.md section 6, PR 28).  Layers are placed in order, each on the
-    statistics the placed layers before it give.  Returns the parameters."""
+    router's leaves that have a column an expert (``MoE.router_columns``: its
+    output layer, and the selection bias where it has one) are reordered so
+    that this chip's are its ``held`` range.  The experts' own weights are
+    drawn alike, so the reordering is the whole placement.  Without it a
+    randomly initialised router sends anything from a third to two thirds of
+    the tokens here (PERF.md section 6, PR 28).  Expert layers are placed in
+    ``layer_specs``' order, each on the statistics the placed layers before it
+    give (``targets`` as ``routing_stats``').  Returns the parameters."""
     import numpy as np
 
-    stats = jax.jit(lambda p: routing_stats(p, tokens, cfg))
-    layers = list(params["layers"])
-    with trace.span("moe.place_experts", layers=len(layers)):
-        for i, (_, ffn) in enumerate(cfg.layers):
-            counts = np.asarray(stats(dict(params, layers=layers)))[i]
+    stats = jax.jit(lambda p: routing_stats(p, tokens, cfg, targets))
+    specs = layer_specs(cfg)
+    with trace.span("moe.place_experts", layers=len(specs)):
+        for i, (_, ffn) in enumerate(specs):
+            if not isinstance(ffn, MoE):
+                continue
+            counts = np.asarray(stats(params))[i]
             first, n = ffn.held
             chips = ffn.n_experts // n
             held = [[] for _ in range(chips)]
@@ -1389,30 +1801,57 @@ def place_experts(params, tokens, cfg: HybridConfig):
                 open_ = [c for c in range(chips) if len(held[c]) < n]
                 held[min(open_, key=lambda c: counts[held[c]].sum())].append(int(e))
             here = first // n
-            order = sum(held[:here] + [held[here]] + held[here + 1:], [])
-            held_here = layers[i][ffn.key]
-            last = "w3" if ffn.router_hidden else "w"    # the output layer
-            router = dict(held_here["router"], **{
-                last: held_here["router"][last][:, jnp.asarray(order)]})
-            layers[i] = dict(layers[i], **{ffn.key: dict(held_here, router=router)})
-    return dict(params, layers=layers)
+            order = jnp.asarray(
+                sum(held[:here] + [held[here]] + held[here + 1:], []))
+            lp = layer_params(params, cfg, i)
+            router = dict(lp[ffn.key]["router"], **{
+                name: lp[ffn.key]["router"][name][..., order]
+                for name in ffn.router_columns})
+            params = _with_layer(params, cfg, i, dict(
+                lp, **{ffn.key: dict(lp[ffn.key], router=router)}))
+    return params
+
+
+def _with_layer(params, cfg: HybridConfig, i: int, lp):
+    """``params`` with block ``i``'s parameters replaced by ``lp``."""
+    a, b = layer_path(cfg, i)
+    if a == "layers":
+        return dict(params, layers=[lp if j == b else old
+                                    for j, old in enumerate(params[a])])
+    return dict(params, **{a: dict(params[a], **{b: lp})})
 
 
 def publish_routing_stats(counts, cfg: HybridConfig) -> dict:
     """Add ``counts`` (``routing_stats`` summed over any batches, already on
     the host) to the counters ``moe.tokens_total``, ``moe.tokens_local`` and
-    ``moe.expert_load.l<layer>.e<expert>`` (held experts only; the unit is a
-    (token, choice) pair, a token where the layer is top-1); returns the
-    local share and the held experts' largest load over their mean."""
-    first, n = cfg.layers[0][1].held
+    ``moe.expert_load.l<block>.e<expert>`` (held experts of the expert layers
+    only; the unit is a (token, choice) pair, a token where the layer is
+    top-1); returns the local share and the held experts' largest load over
+    their mean."""
+    first, n = _first_moe(cfg).held
     total = float(counts.sum())
     held = counts[:, first:first + n]
     METRICS.increment("moe.tokens_total", total)
     METRICS.increment("moe.tokens_local", float(held.sum()))
-    for li, row in enumerate(held):
+    for li, ((_, ffn), row) in enumerate(zip(layer_specs(cfg), held)):
+        if not isinstance(ffn, MoE):
+            continue
         for j, c in enumerate(row):
             METRICS.increment(f"moe.expert_load.l{li}.e{first + j}", float(c))
     per_expert = held.sum(axis=0)
     return {"local_share": float(held.sum()) / max(total, 1.0),
             "load_max_over_mean": float(per_expert.max())
             / max(float(per_expert.mean()), 1e-30)}
+
+
+def publish_bias_stats(params, cfg: HybridConfig) -> float:
+    """Set the gauge ``moe.bias_abs_max`` to the largest magnitude among the
+    expert layers' selection biases under ``params`` (0 where no layer has
+    one): how far the balancing rule has moved them.  Called outside the
+    step."""
+    biases = [layer_params(params, cfg, i)[ffn.key]["router"]["bias"]
+              for i, (_, ffn) in enumerate(layer_specs(cfg))
+              if getattr(ffn, "select_bias", False)]
+    value = max((float(jnp.max(jnp.abs(b))) for b in biases), default=0.0)
+    METRICS.gauge("moe.bias_abs_max", value)
+    return value

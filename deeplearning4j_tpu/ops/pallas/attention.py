@@ -94,7 +94,8 @@ def kernel_takes(t: int, h: int, d: int) -> bool:
 
 def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
                         asked: str = "auto",
-                        selection: tuple[int, int] | None = None) -> str | None:
+                        selection: tuple[int, int] | None = None,
+                        d_v: int | None = None) -> str | None:
     """The registered attention candidate a block runs on ``(B, t, h, d)``
     queries, or ``None`` for the XLA path (``ring_attention``) — the one
     place that decides, for every block family.  By default the fused
@@ -111,10 +112,14 @@ def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
     compile on the shapes they are built for, by name wherever the sequence
     is whole chunks; ``None`` is the mixer's own XLA path.
 
+    ``d_v`` is the values' width where it is not the queries' and keys'
+    ``d`` (latent attention's heads): no kernel here takes unequal widths, so
+    the answer is ``None``, the caller's own XLA path.
+
     Counts its answer as ``attention.path.kernel`` / ``.xla``: callers ask
     once per block while tracing, so the counters tell a step on the kernel
     from one that fell back."""
-    if asked == "ring" or n_sp != 1:
+    if asked == "ring" or n_sp != 1 or d_v not in (None, d):
         name = None
     elif selection is not None:
         from . import sparse_attention

@@ -304,45 +304,96 @@ class DataParallelTrainer:
         the rows' losses itself (``per_example_loss=True``) and so sees the
         whole batch at once — what an expert layer that groups tokens and a
         chunked head loss need."""
+        return self._per_example_and_moves(params, x, y, key, moved=False)[0]
+
+    def _per_example_and_moves(self, params, x, y, key, moved: bool = True):
+        """``_per_example`` and, second, what the step adds to leaves the
+        gradient does not reach: a ``per_example_loss`` function may return
+        ``(rows' losses, {leaf path: array})`` (the path the leaf's keys
+        joined by ``/``, as ``layers/1/moe/router/bias``; in an array's place
+        a function of the rows' validity ``(B,)`` bool, for a rule that
+        counts rows: a padded row then counts for nothing, as in the loss),
+        and None from one that returns the losses alone.  ``moved=False`` is a step program
+        that cannot apply them (the shard-local ones, which would see one
+        chip's rows where the rule wants the batch's) and says so."""
         loss_fn = self.loss_fn
+        moves = None
         if self.per_example_loss:
             per = loss_fn(params, x, y, key)
+            if isinstance(per, tuple):
+                per, moves = per
+                if not moved:
+                    raise NotImplementedError(
+                        "a loss that moves leaves without a gradient needs the "
+                        "replicated step (router='iterative_reduce', "
+                        "zero_stage=0): it sees the whole batch there")
         else:
             per = jax.vmap(
                 lambda xi, yi: loss_fn(params, xi[None], yi[None], key))(x, y)
         with jax.named_scope("loss_reduce"):
-            return per.reshape((x.shape[0],))
+            return per.reshape((x.shape[0],)), moves
 
-    def _masked_mean_loss(self, key_select):
+    def _masked_mean_loss(self, key_select, with_moves: bool = False):
         """Wrap ``loss_fn`` (a per-sample mean) into an exact masked mean:
         per-example losses via a singleton-batch vmap, zero weight for
-        padded rows, normalized by the REAL sample count.  Decomposable
+        padded rows, normalized by the REAL sample count (``with_moves``:
+        ``(that mean, _per_example_and_moves' second)``, for a step that
+        differentiates it with ``has_aux``).  Decomposable
         (per-row) losses — every loss in this repo — are exact under this
         rewrite; batch-coupled losses (cross-batch statistics) are not and
         should avoid ragged batches."""
         def masked(params, x, y, key, mask, denom):
-            per = self._per_example(params, x, y, key_select(key))
+            per, moves = self._per_example_and_moves(
+                params, x, y, key_select(key), moved=with_moves)
             with jax.named_scope("loss_reduce"):
-                return (jnp.sum(per * mask.astype(per.dtype))
+                loss = (jnp.sum(per * mask.astype(per.dtype))
                         / denom.astype(per.dtype))
+            if moves:
+                moves = {leaf: move(mask) if callable(move) else move
+                         for leaf, move in moves.items()}
+            return (loss, moves) if with_moves else loss
 
         return masked
+
+    @staticmethod
+    def _moved(before, after, moves):
+        """``after`` (the parameters the optimizer made of ``before``) with
+        the leaves ``moves`` names ({leaf path: array}) set to their value
+        ``before`` plus what the loss handed back for them: such a leaf takes
+        its own rule's step in place of the optimizer's (whose moments, fed a
+        zero gradient, stay where they are, and whose decay never reaches
+        it).  A path that names no leaf is an error, not a no-op."""
+        if not moves:
+            return after
+        left = dict(moves)
+
+        def one(path, old, new):
+            name = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                            for k in path)
+            return old + left.pop(name).astype(old.dtype) if name in left else new
+
+        after = jax.tree_util.tree_map_with_path(one, before, after)
+        if left:
+            raise KeyError(f"the loss moves leaves the parameters lack: "
+                           f"{sorted(left)}")
+        return after
 
     def _build_sync_step(self):
         mesh = self.mesh
         batch_sh = NamedSharding(mesh, P(DP))
         rep = NamedSharding(mesh, P())
-        masked = self._masked_mean_loss(lambda k: k)
+        masked = self._masked_mean_loss(lambda k: k, with_moves=True)
 
         def step(params, tstate, x, y, key, iteration, n_valid):
             with jax.named_scope("loss_reduce"):
                 mask = jnp.arange(x.shape[0]) < n_valid
-            loss, grads = jax.value_and_grad(masked)(
+            (loss, moves), grads = jax.value_and_grad(masked, has_aux=True)(
                 params, x, y, key, mask, n_valid)
             with jax.named_scope("optimizer"):
                 updates, tstate = self.transform.update(
                     grads, tstate, params, iteration)
-                params = tfm.apply_updates(params, updates)
+                params = self._moved(
+                    params, tfm.apply_updates(params, updates), moves)
             return params, tstate, loss
 
         # shardguard (off by default: one flag check per dispatch) diffs

@@ -105,8 +105,34 @@ METRIC_COUNT_ASSERTS = {
         "test_looped_cell_reports_the_common_metrics_and_its_own")}
 
 
+#: Three tests of ``tests/benchmark_tests/test_benchmark_scope_rest.py`` (PR 36)
+#: hold PR 36's six per-layer entries to the END of ``per_layer``
+#: (``order[first + 6:] == [...]``) and to exact ``workloads`` lists (the five
+#: cells of ``unnamed_share.train``, the two of ``moe_share.combine.train``).
+#: A PR that appends a cell which reports those metrics, and per-layer metrics
+#: of its own (PR 38), can neither keep these asserts true nor repair an
+#: accepted file.  They are expected to fail from PR 38 on; everything else
+#: they hold (the six entries' order and fields, their accepted cells in
+#: order, the sparse cell's own metrics) is tested again, without the
+#: position and the lists' length, in
+#: ``tests/benchmark_tests/test_benchmark_mla.py``.  A ``benchmark`` PR takes
+#: the asserts out of the accepted file and this list with them.
+APPENDED_AFTER_PR_36 = {
+    "tests/benchmark_tests/test_benchmark_scope_rest.py::" + name for name in (
+        "test_the_sparse_cell_reports_what_it_did_and_this_prs",
+        "test_metric_files_and_manifest_entries[unnamed_share.train-scope_rest-"
+        "args0-cells0]",
+        "test_metric_files_and_manifest_entries[moe_share.combine.train-"
+        "scope_split-args3-cells3]")}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
+        if item.nodeid in APPENDED_AFTER_PR_36:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="asserts that PR 36's entries close "
+                "per_layer and list exactly the cells PR 36 knew; PR 38 "
+                "appended a cell and its own metrics"))
         if item.nodeid in LAST_PLACE_ASSERTS:
             item.add_marker(pytest.mark.xfail(
                 strict=True, reason="asserts that PR 32's entries are the "

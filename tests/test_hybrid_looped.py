@@ -311,31 +311,19 @@ def test_a_larger_beta_raises_the_entropy_after_a_step_of_descent(case):
 
 # -------------------------------------------------- each part moves the output
 
-@dataclasses.dataclass(frozen=True)
-class BareAttention(hybrid.Attention):
-    post_norm = False
-
-
-@dataclasses.dataclass(frozen=True)
-class BareMLP(hybrid.GatedMLP):
-    post_norm = False
-
-
 def without(part):
     """The configuration with ``part`` switched off.  The sandwich norms are
-    constants of their specs, so a spec without one is another spec."""
+    what a spec says (``post_norm``, a field of both since PR 38)."""
     if part == "second norm":
-        return config(mixer=BareAttention(H, G, D, 1e6))
+        return config(mixer=hybrid.Attention(H, G, D, 1e6, post_norm=False))
     if part == "fourth norm":
-        return config(ffn=BareMLP(F))
+        return config(ffn=hybrid.GatedMLP(F, post_norm=False))
     return config(mixer=hybrid.Attention(H, G, D, 1e6, rotary_factor=0.5))
 
 
 @pytest.mark.parametrize("part", ["second norm", "fourth norm",
                                   "whole-head rotary"])
-def test_each_part_moves_the_output(case, part, monkeypatch):
-    monkeypatch.setitem(hybrid.MIXERS, BareAttention, hybrid.attention_mixer)
-    monkeypatch.setitem(hybrid.FFNS, BareMLP, hybrid.gated_mlp)
+def test_each_part_moves_the_output(case, part):
     params, toks = case["params"], case["toks"]
     want, _ = hybrid.encode_steps(params, toks, case["cfg"])
     got, _ = hybrid.encode_steps(params, toks, without(part))
@@ -408,13 +396,14 @@ def test_any_mixer_goes_with_any_ffn(pair, n_loops, tied):
 
 def test_registries_hold_two_specs_each_and_block_names_neither():
     import inspect
-    # (two each when PR 32 wrote this; PR 34 added the sparse mixer)
+    # (two each when PR 32 wrote this; PR 34 added the sparse mixer, PR 38
+    # the latent-attention one)
     assert set(hybrid.MIXERS) == {hybrid.CCA, hybrid.Attention,
-                                  hybrid.SparseAttention}
+                                  hybrid.SparseAttention, hybrid.MLA}
     assert set(hybrid.FFNS) == {hybrid.MoE, hybrid.GatedMLP}
     source = inspect.getsource(hybrid.block) + inspect.getsource(hybrid.init_params)
     assert not re.search(r"cca|moe|attn|mlp|dsa", source, re.I)
-    assert len({s.key for s in (*hybrid.MIXERS, *hybrid.FFNS)}) == 5
+    assert len({s.key for s in (*hybrid.MIXERS, *hybrid.FFNS)}) == 6
 
 
 # ------------------------------------------------ the head under token weights
