@@ -46,15 +46,17 @@ behind a linear router, the Qwen3-MoE family's):
   (the k-th largest score by bisection on the scores' bits, ties to the lower
   position) and a constant of the backward pass.  Both paths compute ``rows``
   queries at a time against the keys so far (at most four key lengths a
-  sequence, ``_key_spans``), and on both the index scores and the selection
-  are XLA's, ``rows x keys`` at a time.  On a TPU, for the shapes they take,
-  the kernels of ``ops/pallas/sparse_attention.py`` are handed each chunk's
-  selection and do the scores, the masked softmax, the value product and the
-  heads' mean probabilities with one tile of one head's scores in VMEM at a
-  time (``_kernel_attend``, a ``custom_vjp`` whose backward makes the
-  selection and the indexer's target again, and the attention's forward pass
-  not).  Elsewhere (the CPU, other shapes) ``_sparse_chunk`` is the XLA
-  path: a chunk's scores of every head in HBM, each chunk checkpointed.
+  sequence, ``_key_spans``), and on both the selection is XLA's, ``rows x
+  keys`` at a time.  On a TPU, for the shapes they take, the kernels of
+  ``ops/pallas/sparse_attention.py`` make each chunk's index scores a key
+  tile at a time (forward, and backward with the indexer's gradients), are
+  handed the chunk's selection and do the scores, the masked softmax, the
+  value product and the heads' mean probabilities with one tile of one
+  head's scores in VMEM at a time (``_kernel_attend``, a ``custom_vjp``
+  whose backward makes the index scores, the selection and the indexer's
+  target again, and the attention's forward pass not).  Elsewhere (the CPU,
+  other shapes) ``_sparse_chunk`` is the XLA path: a chunk's scores of
+  every head, and of every index head, in HBM, each chunk checkpointed.
   Beside its output the mixer yields the indexer's own loss: the
   divergence of the head-summed attention probabilities from the softmax of
   the index scores over the selected keys, which reaches the indexer's
@@ -446,9 +448,27 @@ def index_inputs(spec: SparseAttention, p, u, dt):
 def index_scores(qi, ki, w):
     """``I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s])``: ``(C, L)`` f32 from
     ``qi (C, J, D)``, ``ki (L, D)`` and ``w (C, J)``; the products take their
-    operands as they come and accumulate in float32."""
+    operands as they come and accumulate in float32.  The XLA path's, and
+    the kernels' path's where the index kernels do not take the shape: it
+    writes every head's ``(C, L)`` pre-activations to HBM, forward and again
+    in its ``jax.vjp``.  Where they do (``_chunk_index_scores``), the same
+    mathematics runs in ``ops/pallas/sparse_attention.index_scores``."""
     pre = jnp.einsum("tjd,sd->tjs", qi, ki, preferred_element_type=jnp.float32)
     return jnp.sum(w[:, :, None] * jax.nn.relu(pre), axis=1)
+
+
+def _chunk_index_scores(qi, ki, w, frontier):
+    """One chunk's index scores on the attention kernels' path: the index
+    kernels where they take the chunk's shape (``index_takes``), zero from
+    the key block of ``frontier`` on, else ``index_scores``.  The step's
+    forward pass and ``sparse_selection`` come here and its backward pass
+    (``_kernel_chunk_grads``) decides alike, so that the three run one
+    kernel and select alike, bit for bit."""
+    from ..ops.pallas import sparse_attention as kernel
+
+    if kernel.index_takes(qi.shape[0], ki.shape[0], qi.shape[2]):
+        return kernel.index_scores(qi, ki, w, frontier)
+    return index_scores(qi, ki, w)
 
 
 @jax.named_scope("dsa.select")
@@ -563,12 +583,21 @@ def sparse_attend(spec: SparseAttention, q, k, v, qi, ki, w, asked="auto"):
     as ``attention.path.*``; ``asked`` as there): the Pallas kernels that take
     the selection, where no score leaves VMEM, or the XLA path, each chunk
     recomputed in the backward pass so that no more than ``rows x T`` scores
-    a head are ever held."""
+    a head are ever held.  Which path the index scores took is counted too,
+    per layer per trace: ``dsa.index_path.kernel`` where the index kernels
+    take them (``_chunk_index_scores``), ``.xla`` elsewhere."""
+    from ..ops.pallas import sparse_attention as kernel
     from ..ops.pallas.attention import attention_candidate
 
     t, h, d = q.shape[1:]
-    if attention_candidate(t, h, d, asked=asked,
-                           selection=(spec.n_kv_heads, _key_spans(spec, t)[0])):
+    c = _key_spans(spec, t)[0]
+    on = attention_candidate(t, h, d, asked=asked,
+                             selection=(spec.n_kv_heads, c))
+    METRICS.increment(
+        "dsa.index_path.kernel"
+        if on and kernel.index_takes(c, t, spec.index_dim)
+        else "dsa.index_path.xla")
+    if on:
         return _kernel_attend(spec, q, k, v, qi, ki, w)
     chunk = jax.checkpoint(functools.partial(_sparse_chunk, spec))
 
@@ -591,10 +620,10 @@ def _kernel_chunk(spec: SparseAttention, k, v, ki, start, q, qi, w):
 
     c, g = q.shape[0], spec.n_kv_heads
     causal, count = _chunk_rows(spec, start, c, k.shape[0])
-    scores = index_scores(qi, ki, w)
+    scores = _chunk_index_scores(qi, ki, w, start + c)
     chosen = select_top_k(scores, causal, count)
-    # the kernels' entry points name their own passes (dsa.attend,
-    # dsa.index_loss) beside the mask they take (dsa.select)
+    # the kernels' entry points name their own passes (dsa.index_scores,
+    # dsa.attend, dsa.index_loss) beside the mask they take (dsa.select)
     out, lse = kernel.forward(q, k, v, chosen, start + c, kv_heads=g)
     target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
     with jax.named_scope("dsa.index_loss"):
@@ -604,26 +633,40 @@ def _kernel_chunk(spec: SparseAttention, k, v, ki, start, q, qi, w):
 
 def _kernel_chunk_grads(spec: SparseAttention, k, v, ki, carry, x):
     """One chunk's part of the backward pass: ``carry`` holds the f32 sums of
-    ``dk, dv (L, G*d)`` and ``dki (L, D)`` over the chunks so far, ``x`` the
-    chunk's ``(start, q, qi, w, out, lse, d out, d loss)``; the selection and
-    the indexer's target are made again, the kernel's forward pass is not."""
+    ``dk, dv (L, G*d)`` and of the index keys' gradient ``dki_t (D, L)``
+    (keys on the lanes) over the chunks so far, ``x`` the chunk's ``(start,
+    q, qi, w, out, lse, d out, d loss)``; the index scores, the selection and
+    the indexer's target are made again, the attention kernel's forward pass
+    is not.  Where the index kernels take the chunk (``_chunk_index_scores``)
+    their backward adds the chunk's ``d ki`` to ``dki_t`` itself, in f32;
+    elsewhere ``jax.vjp`` of ``index_scores`` gives it in ``ki``'s dtype and
+    it is added here."""
     from ..ops.pallas import sparse_attention as kernel
 
-    dk, dv, dki = carry
+    dk, dv, dki_t = carry
     start, q, qi, w, out, lse, d_out, d_loss = x
     c, g = q.shape[0], spec.n_kv_heads
     causal, count = _chunk_rows(spec, start, c, k.shape[0])
-    scores, index_grads = jax.vjp(index_scores, qi, ki, w)
+    on_kernel = kernel.index_takes(c, k.shape[0], qi.shape[2])
+    if on_kernel:
+        scores = kernel.index_scores(qi, ki, w, start + c)
+    else:
+        scores, index_grads = jax.vjp(index_scores, qi, ki, w)
     chosen = select_top_k(scores, causal, count)
     target = kernel.head_mean(q, k, chosen, lse, start + c, kv_heads=g)
     with jax.named_scope("dsa.index_loss"):
         d_scores = d_loss * jax.grad(_index_loss)(scores, chosen, target)
-    d_qi, d_ki, d_w = index_grads(d_scores)
+    if on_kernel:
+        d_qi, dki_t, d_w = kernel.index_backward(qi, ki, w, d_scores, dki_t,
+                                                 start + c)
+    else:
+        d_qi, d_ki, d_w = index_grads(d_scores)
     d_q, dk, dv = kernel.backward(q, k, v, chosen, out, lse, d_out, dk, dv,
                                   start + c, kv_heads=g)
-    with jax.named_scope("dsa.index_scores"):
-        dki = dki + d_ki.astype(jnp.float32)
-    return (dk, dv, dki), (d_q, d_qi, d_w)
+    if not on_kernel:
+        with jax.named_scope("dsa.index_scores"):
+            dki_t = dki_t + d_ki.T.astype(jnp.float32)
+    return (dk, dv, dki_t), (d_q, d_qi, d_w)
 
 
 def _merged(x):
@@ -678,7 +721,7 @@ def _kernel_attend_bwd(spec, kept, cotangents):
             q2, k2, v2, out2, d_out2 = (_merged(a) for a in (q, k, v, out, d_out))
             dk, dv = (jnp.zeros(a.shape, jnp.float32) for a in (k2, v2))
         with jax.named_scope("dsa.index_scores"):
-            dki = jnp.zeros(ki.shape, jnp.float32)
+            dki = jnp.zeros(ki.shape[::-1], jnp.float32)       # (D, T)
         per_chunk = []
         for first, n, keys in spans:
             rows = slice(first * c, (first + n) * c)
@@ -703,7 +746,7 @@ def _kernel_attend_bwd(spec, kept, cotangents):
             with jax.named_scope("dsa.attend"):
                 dk_s, dv_s = dk[:keys], dv[:keys]
             with jax.named_scope("dsa.index_scores"):
-                dki_s = dki[:keys]
+                dki_s = dki[:, :keys]
             part, grads = lax.scan(
                 functools.partial(_kernel_chunk_grads, spec, k_s, v_s, ki_s),
                 (dk_s, dv_s, dki_s),
@@ -711,7 +754,7 @@ def _kernel_attend_bwd(spec, kept, cotangents):
             with jax.named_scope("dsa.attend"):
                 dk, dv = dk.at[:keys].set(part[0]), dv.at[:keys].set(part[1])
             with jax.named_scope("dsa.index_scores"):
-                dki = dki.at[:keys].set(part[2])
+                dki = dki.at[:, :keys].set(part[2])
             per_chunk.append(grads)
         d_q, d_qi, d_w = zip(*per_chunk)
 
@@ -725,7 +768,7 @@ def _kernel_attend_bwd(spec, kept, cotangents):
         with jax.named_scope("dsa.attend"):
             d_q, dk, dv = like(d_q, q), like(dk, k), like(dv, v)
         with jax.named_scope("dsa.index_scores"):
-            return d_q, dk, dv, like(d_qi, qi), like(dki, ki), like(d_w, w)
+            return d_q, dk, dv, like(d_qi, qi), like(dki.T, ki), like(d_w, w)
 
     return lax.map(example, (*kept, *cotangents))
 
@@ -771,15 +814,25 @@ def sparse_selection(spec: SparseAttention, p, u, dt, reduce=None):
     ``(B, T, T)``, row ``t`` its query's keys; or, with ``reduce(chosen (C,
     L), causal (C, L), start)``, that function's results stacked ``(B,
     chunks, ...)`` with no ``T x T`` array made.  The indexer's half of the
-    mixer alone, outside any step: for comparisons and counters."""
+    mixer alone, outside any step: for comparisons and counters.  The index
+    scores take the path the mixer's would (``attention_candidate``'s
+    answer, not counted)."""
+    from ..ops.pallas.attention import attention_candidate
+
     t = u.shape[1]
+    on_kernel = attention_candidate(
+        t, spec.n_heads, spec.head_dim,
+        selection=(spec.n_kv_heads, _key_spans(spec, t)[0]), count=False)
     qi, ki, w = index_inputs(spec, p["index"], u, dt)
     if reduce is not None:            # counted over whole tiles of queries
         spec = dataclasses.replace(spec, rows=spec.q_chunk)
 
     def chunk(ki, start, qi, w):
-        causal, count = _chunk_rows(spec, start, qi.shape[0], ki.shape[0])
-        chosen = select_top_k(index_scores(qi, ki, w), causal, count)
+        c = qi.shape[0]
+        causal, count = _chunk_rows(spec, start, c, ki.shape[0])
+        scores = (_chunk_index_scores(qi, ki, w, start + c) if on_kernel
+                  else index_scores(qi, ki, w))
+        chosen = select_top_k(scores, causal, count)
         if reduce is not None:
             return reduce(chosen, causal, start)
         return jnp.pad(chosen, ((0, 0), (0, t - ki.shape[0])))

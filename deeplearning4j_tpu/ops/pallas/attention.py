@@ -95,7 +95,7 @@ def kernel_takes(t: int, h: int, d: int) -> bool:
 def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
                         asked: str = "auto",
                         selection: tuple[int, int] | None = None,
-                        d_v: int | None = None) -> str | None:
+                        d_v: int | None = None, count: bool = True) -> str | None:
     """The registered attention candidate a block runs on ``(B, t, h, d)``
     queries, or ``None`` for the XLA path (``ring_attention``) — the one
     place that decides, for every block family.  By default the fused
@@ -118,7 +118,9 @@ def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
 
     Counts its answer as ``attention.path.kernel`` / ``.xla``: callers ask
     once per block while tracing, so the counters tell a step on the kernel
-    from one that fell back."""
+    from one that fell back.  ``count=False`` is for a caller outside any
+    block that must follow the blocks' answer
+    (``models/hybrid.sparse_selection``)."""
     if asked == "ring" or n_sp != 1 or d_v not in (None, d):
         name = None
     elif selection is not None:
@@ -135,8 +137,9 @@ def attention_candidate(t: int, h: int, d: int, *, n_sp: int = 1,
         name = "fused" if on else None
     else:
         name = asked if t % 128 == 0 else None
-    METRICS.increment(
-        "attention.path.kernel" if name else "attention.path.xla")
+    if count:
+        METRICS.increment(
+            "attention.path.kernel" if name else "attention.path.xla")
     return name
 
 

@@ -46,6 +46,13 @@ fetched, and their share of ``head_mean`` written as zeros.
 Precision is the XLA path's: ``q, k, v`` enter the MXU in their own dtype
 (bf16 in training) with f32 accumulation; ``m``, ``l``, ``lse``, ``acc`` and
 the accumulators are f32; ``p`` is cast to ``v.dtype`` for the value product.
+
+The indexer's scores that make the selection, ``I[t, s] = sum_j w[t, j]
+relu(qi[t, j] . ki[s])``, are a pair of kernels of their own over the key
+blocks (``index_forward``, ``index_backward``; ``index_scores`` is the pair
+as one differentiable function): a program holds the chunk's index queries
+and head weights and one key tile, and no ``heads x queries x keys`` array
+of pre-activations, relu mask or products ever reaches HBM.
 """
 
 from __future__ import annotations
@@ -67,6 +74,8 @@ _NT = (((1,), (1,)), ((), ()))        # a @ b.T, contracting the lane axes
 
 #: keys a sequence may have on one chip (ROADMAP M8 is the second chip)
 MAX_KEYS = 16384
+#: queries in a chunk: one to four whole 128-row blocks
+CHUNK_ROWS = (128, 256, 384, 512)
 
 
 def kernel_takes(t: int, h: int, d: int, kv_heads: int, rows: int) -> bool:
@@ -74,8 +83,16 @@ def kernel_takes(t: int, h: int, d: int, kv_heads: int, rows: int) -> bool:
     whole 128-row blocks (a chunk's queries are ONE tile: measured at 256,
     compiled at 128 and 512) that tile the sequence, heads of 128 lanes,
     whole groups of query heads a KV head."""
-    return (d == 128 and h % kv_heads == 0 and rows in (128, 256, 384, 512)
+    return (d == 128 and h % kv_heads == 0 and rows in CHUNK_ROWS
             and t % rows == 0 and t <= MAX_KEYS)
+
+
+def index_takes(c: int, n_keys: int, dim: int) -> bool:
+    """Shapes the index kernels are built for: a chunk of ``CHUNK_ROWS``
+    queries over whole 128-row blocks of keys, index heads of 64 or 128
+    lanes."""
+    return (dim in (64, 128) and c in CHUNK_ROWS and n_keys % 128 == 0
+            and n_keys <= MAX_KEYS)
 
 
 def _block_k(n_keys: int, largest: int = 512) -> int:
@@ -313,6 +330,131 @@ def _sparse_headsum(q, k, mask, lse, frontier, kv_heads, head_dim, interpret):
     )(frontier, q, k, mask, lse)
 
 
+def _index_fwd_kernel(frontier_ref, qi_ref, k_ref, w_ref, s_ref):
+    needed = _needed(frontier_ref, k_ref.shape[0])
+
+    @pl.when(pl.program_id(0) < needed)
+    def _():
+        k, w = k_ref[...], w_ref[...]
+        total = None
+        for j in range(qi_ref.shape[0]):
+            pre = lax.dot_general(qi_ref[j], k, _NT,
+                                  preferred_element_type=jnp.float32)
+            part = w[:, j:j + 1] * jnp.maximum(pre, 0.0)
+            total = part if total is None else total + part
+        s_ref[...] = total
+
+    @pl.when(pl.program_id(0) >= needed)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+
+def _index_bwd_kernel(frontier_ref, qi_ref, qi_t_ref, k_ref, w_ref, ds_ref,
+                      dk_in_ref, dqi_ref, dk_ref, dw_ref, dqi_acc, dw_acc):
+    i, n_k = pl.program_id(0), pl.num_programs(0)
+    block_k = k_ref.shape[0]
+
+    @pl.when(i == 0)
+    def _():
+        dqi_acc[...] = jnp.zeros_like(dqi_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(i < _needed(frontier_ref, block_k))
+    def _():
+        k, w, ds = k_ref[...], w_ref[...], ds_ref[...]
+        dk = None
+        for j in range(qi_ref.shape[0]):
+            pre = lax.dot_general(qi_ref[j], k, _NT,
+                                  preferred_element_type=jnp.float32)
+            g = jnp.where(pre > 0, ds, 0.0)          # d relu(pre) . d scores
+            d_pre = (g * w[:, j:j + 1]).astype(k.dtype)
+            # d w: relu(pre) x d scores summed over the keys, folded to one
+            # vreg column a row here and across the lanes once, at the end
+            prod = pre * g
+            fold = prod[:, :128]
+            for a in range(128, block_k, 128):
+                fold = fold + prod[:, a:a + 128]
+            dw_acc[j] += fold
+            dqi_acc[j] += jnp.dot(d_pre, k, preferred_element_type=jnp.float32)
+            part = jnp.dot(qi_t_ref[j], d_pre,
+                           preferred_element_type=jnp.float32)   # (D, BK)
+            dk = part if dk is None else dk + part
+        dk_ref[...] = dk_in_ref[...] + dk
+
+    @pl.when(i == n_k - 1)
+    def _():
+        dqi_ref[...] = dqi_acc[...].astype(dqi_ref.dtype)
+        for j in range(qi_ref.shape[0]):
+            dw_ref[:, j:j + 1] = dw_acc[j].sum(axis=1, keepdims=True)
+
+
+def _index_layout(qi_h, k):
+    """How the two index kernels walk a chunk: ``(grid, block specs by
+    name)`` over the key blocks, a skipped step naming the last block below
+    the frontier so that nothing is fetched for it."""
+    heads, c, dim = qi_h.shape
+    block_k = _block_k(k.shape[0])
+
+    def spec(shape, at):
+        return pl.BlockSpec(shape, lambda i, f: at(_last_needed(i, f, block_k)))
+
+    return (k.shape[0] // block_k,), {
+        "queries": spec((heads, c, dim), lambda last: (0, 0, 0)),
+        "queries_t": spec((heads, dim, c), lambda last: (0, 0, 0)),
+        "weights": spec((c, heads), lambda last: (0, 0)),
+        "keys": spec((block_k, dim), lambda last: (last, 0)),
+        "keys_t": spec((dim, block_k), lambda last: (0, last)),
+        "scores_in": spec((c, block_k), lambda last: (0, last)),
+        "scores": pl.BlockSpec((c, block_k), lambda i, f: (0, i))}
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _index_fwd(qi_h, k, w, frontier, interpret):
+    """qi_h (J, C, D), k (L, D), w (C, J) f32, frontier (1,) int32 -> the
+    scores (C, L) f32, zero from the frontier's key block on."""
+    grid, at = _index_layout(qi_h, k)
+    return pl.pallas_call(
+        _index_fwd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[at["queries"], at["keys"], at["weights"]],
+            out_specs=at["scores"]),
+        out_shape=jax.ShapeDtypeStruct((qi_h.shape[1], k.shape[0]),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )(frontier, qi_h, k, w)
+
+
+@functools.partial(jax.jit, static_argnums=(7,))
+def _index_bwd(qi_h, qi_t, k, w, ds, dk_acc, frontier, interpret):
+    """qi_h (J, C, D), qi_t (J, D, C), ds (C, L) f32, dk_acc (D, L) f32 ->
+    (d qi (J, C, D), dk_acc + d k^T, d w (C, J) f32)."""
+    grid, at = _index_layout(qi_h, k)
+    heads, c, dim = qi_h.shape
+    return pl.pallas_call(
+        _index_bwd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[at["queries"], at["queries_t"], at["keys"],
+                      at["weights"], at["scores_in"], at["keys_t"]],
+            out_specs=[at["queries"], at["keys_t"], at["weights"]],
+            scratch_shapes=[pltpu.VMEM((heads, c, dim), jnp.float32),
+                            pltpu.VMEM((heads, c, 128), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(qi_h.shape, qi_h.dtype),
+                   jax.ShapeDtypeStruct(dk_acc.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+        # operand indices count the prefetched scalar
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )(frontier, qi_h, qi_t, k, w, ds, dk_acc)
+
+
 def _frontier(frontier, n_keys: int):
     return jnp.full((1,), n_keys if frontier is None else frontier, jnp.int32)
 
@@ -366,6 +508,62 @@ def head_mean(q, k, chosen, lse, frontier=None, *, kv_heads: int,
                                _frontier(frontier, k.shape[0]), kv_heads,
                                k.shape[1] // kv_heads,
                                registry.resolve_interpret(interpret))
+
+
+def index_forward(qi, ki, w, frontier=None, *, interpret: bool | None = None):
+    """The index scores ``I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s])``,
+    ``(C, L)`` f32, of ``qi (C, J, D)``, ``ki (L, D)`` and ``w (C, J)`` f32,
+    zero from the key block that holds ``frontier`` on.  ``qi . ki`` enters
+    the MXU in the operands' dtype (bf16 in training) and accumulates in
+    f32; ``w``, the pre-activations and the sum over the heads (in head
+    order) are f32, as on the XLA path (``models/hybrid.index_scores``)."""
+    with jax.named_scope("dsa.index_scores"):
+        return _index_fwd(qi.transpose(1, 0, 2), ki, w,
+                          _frontier(frontier, ki.shape[0]),
+                          registry.resolve_interpret(interpret))
+
+
+def index_backward(qi, ki, w, d_scores, dki_t, frontier=None, *,
+                   interpret: bool | None = None):
+    """``(d qi (C, J, D) in qi's dtype, dki_t + d ki^T, d w (C, J) f32)`` for
+    the cotangent ``d_scores (C, L)`` f32 of ``index_forward``; the index
+    keys' gradient ADDS to ``dki_t (D, L)`` f32, keys on the lanes (an
+    ``(L, 64)`` f32 array would pad its lanes to 128).
+
+    Each key tile's pre-activations are made again in VMEM, ``d pre = d
+    scores x w[:, j] x (pre > 0)``.  The products take what XLA:TPU's
+    compiled ``jax.vjp`` of the XLA path takes (read off its optimised HLO
+    for a v5e): the f32 ``d pre`` at DEFAULT precision, one bf16 pass, so
+    ``d pre`` rounded to bf16, against the bf16 ``qi`` / ``ki``, accumulated
+    in f32.  That path rounds each chunk's ``d ki`` to bf16 before its f32
+    sum over the chunks; here it adds to the f32 accumulator unrounded."""
+    with jax.named_scope("dsa.index_scores"):
+        d_qi, dki_t, d_w = _index_bwd(
+            qi.transpose(1, 0, 2), qi.transpose(1, 2, 0), ki, w, d_scores,
+            dki_t, _frontier(frontier, ki.shape[0]),
+            registry.resolve_interpret(interpret))
+        return d_qi.transpose(1, 0, 2), dki_t, d_w
+
+
+@jax.custom_vjp
+def index_scores(qi, ki, w, frontier=None):
+    """``index_forward`` with ``index_backward`` as its gradient: what
+    ``models/hybrid.index_scores`` computes, in the kernels."""
+    return index_forward(qi, ki, w, frontier)
+
+
+def _index_scores_fwd(qi, ki, w, frontier=None):
+    return index_forward(qi, ki, w, frontier), (qi, ki, w, frontier)
+
+
+def _index_scores_bwd(res, d_scores):
+    qi, ki, w, frontier = res
+    zero = jnp.zeros(ki.shape[::-1], jnp.float32)
+    d_qi, dki_t, d_w = index_backward(qi, ki, w, d_scores, zero, frontier)
+    return d_qi, dki_t.T.astype(ki.dtype), d_w.astype(w.dtype), None
+
+
+index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
 
 
 def _flat(x):

@@ -311,7 +311,8 @@ def _sparse_layer(one_chip, t, **mixer):
             params, tokens, tokens).compile().as_text()
     after = METRICS.snapshot()["counters"]
     return hlo, {k: after.get(k, 0) - before.get(k, 0) for k in (
-        "dsa.layers", "attention.path.kernel", "attention.path.xla")}
+        "dsa.layers", "attention.path.kernel", "attention.path.xla",
+        "dsa.index_path.kernel", "dsa.index_path.xla")}
 
 
 def _as_on_the_chip(monkeypatch):
@@ -341,26 +342,30 @@ def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip, monkeypatch)
     _as_on_the_chip(monkeypatch)
     hlo, moved = _sparse_layer(one_chip, t)
     assert moved == {"dsa.layers": 1, "attention.path.kernel": 1,
-                     "attention.path.xla": 0}
+                     "attention.path.xla": 0, "dsa.index_path.kernel": 1,
+                     "dsa.index_path.xla": 0}
     calls = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line
              and "pallas_call" in line]
     count = {name: sum(f"jit({name})" in p for p in calls)
-             for name in ("_sparse_fwd", "_sparse_bwd", "_sparse_headsum")}
+             for name in ("_sparse_fwd", "_sparse_bwd", "_sparse_headsum",
+                          "_index_fwd", "_index_bwd")}
     assert count == {"_sparse_fwd": spans, "_sparse_bwd": spans,
-                     "_sparse_headsum": 2 * spans}, count
+                     "_sparse_headsum": 2 * spans, "_index_fwd": 2 * spans,
+                     "_index_bwd": spans}, count
     assert all("attention" in p for p in calls)
     assert all(("dsa.index_loss" in p) == ("_sparse_headsum" in p) for p in calls)
+    assert all(("dsa.index_scores" in p) == ("_index_" in p) for p in calls)
     # (the first span's 4096 keys are also 32 heads x 128: left out)
     keys = {t * (i + 1) // spans for i in range(1, spans)}
     for shape in set(re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", hlo)):
         dims = [int(n) for n in shape.split(",")]
         if 256 in dims and keys & set(dims):       # (.., queries, .., keys)
             rest = math.prod(dims) // 256 // max(keys & set(dims))
-            # the indexer's 16 heads (or XLA's slices of them) may be there,
-            # the layer's 32 heads (4 x 8 on the XLA path) may not
-            assert rest % 32, shape
+            # neither the layer's 32 heads (4 x 8 on the XLA path) nor,
+            # since the index kernels, the indexer's 16
+            assert rest % 16, shape
     assert not re.findall(rf"(?:f32|bf16|u32|pred|s8)\[[0-9,]*{t},{t}\]", hlo)
     assert not re.search(r'op_name="[^"]*attention\.fused', hlo)
     assert not re.search(r' sort\([^\n]*dsa\.', hlo) and "ragged-dot" in hlo
@@ -384,5 +389,6 @@ def test_sparse_block_falls_back_where_the_kernel_does_not_take_the_shape(
     _as_on_the_chip(monkeypatch)
     hlo, moved = _sparse_layer(one_chip, 3072, rows=192)
     assert moved == {"dsa.layers": 1, "attention.path.kernel": 0,
-                     "attention.path.xla": 1}
+                     "attention.path.xla": 1, "dsa.index_path.kernel": 0,
+                     "dsa.index_path.xla": 1}
     assert "_sparse_fwd" not in hlo and "pallas_call" not in hlo

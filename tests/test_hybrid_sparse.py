@@ -6,7 +6,9 @@ parts of the objective, every gradient leaf, the selected keys and the expert
 choices, at a length where the selection bites; the mixer without a selection
 to make is plain attention; the selection is exact under planted ties; the two
 parts' gradients never meet; the eight shares add up to the uncut layer; a
-top-1 layer through the new dispatch is the parent's program, text and all.
+top-1 layer through the new dispatch is the parent's program, text and all;
+which path the index scores took is counted, and on the index kernels the
+selection made outside the step is the one the mixer attends over.
 """
 
 import dataclasses
@@ -23,6 +25,7 @@ from deeplearning4j_tpu.models import hybrid
 from deeplearning4j_tpu.models.reference import keye as ref
 from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.observability import METRICS
+from deeplearning4j_tpu.ops.pallas import attention as pallas_attention
 
 E, H, G, D, F, V, SEQ, BATCH = 64, 4, 2, 16, 32, 512, 64, 2
 J, C, TOP, ROWS = 4, 8, 8, 16
@@ -809,6 +812,70 @@ def test_counters_say_what_one_trace_held(case):
     assert c["dsa.layers"] == 2 and c["attention.path.xla"] == 2
     assert "attention.path.kernel" not in c
     assert (c["loop.steps"], c["loop.layer_applications"]) == (1, 2)
+
+
+def kernel_chunks(**kw):
+    """What the index kernels take: 128 queries at a time, index heads of 64."""
+    return dict(dict(index_dim=64, rows=128, top_k=48, q_chunk=128, kv_chunk=128),
+                **kw)
+
+
+@pytest.mark.parametrize("asked,mixer_kw,want", [
+    ("auto", kernel_chunks(), (0, 2)),
+    ("selected", kernel_chunks(), (2, 0)),
+    ("selected", kernel_chunks(index_dim=32), (0, 2)),
+    ("selected", {}, (0, 2)),
+    ("ring", kernel_chunks(), (0, 2))],
+    ids=["cpu", "kernels", "index-width-32", "chunks-of-16", "xla-attention"])
+def test_counters_say_which_path_the_index_scores_took(monkeypatch, asked, mixer_kw,
+                                                       want):
+    """``dsa.index_path.kernel`` / ``.xla`` once per layer per trace: the
+    kernel where the attention takes the selection kernels and the index
+    kernels take the chunk, else the XLA path."""
+    monkeypatch.setattr(hybrid, "sparse_attend", functools.partial(
+        hybrid.sparse_attend, asked=asked))
+    jax.clear_caches()
+    cfg = config(**mixer_kw)
+    params = hybrid.init_params(jax.random.key(0), cfg)
+    toks = jnp.zeros((1, 256), jnp.int32)
+    METRICS.reset()
+    jax.jit(jax.grad(lambda p: objective(p, toks, toks, cfg))).lower(params)
+    monkeypatch.undo()
+    jax.clear_caches()
+    c = METRICS.snapshot()["counters"]
+    assert (c.get("dsa.index_path.kernel", 0), c.get("dsa.index_path.xla", 0)) == want
+    assert c["dsa.layers"] == 2
+
+
+def test_the_selection_outside_the_step_is_the_one_the_mixer_attends_over(monkeypatch):
+    """On the kernels' path, ``sparse_selection`` scores in the same index
+    kernel as the mixer's chunks (once a span of chunks), and the reference
+    attending over ITS keys gives the mixer's output and index loss."""
+    spec = mixer(**kernel_chunks())
+    p, u = mixer_case(spec, t=256)
+    # both ask the one predicate: forced to the kernels, as a parity test does
+    candidate = pallas_attention.attention_candidate
+    monkeypatch.setattr(pallas_attention, "attention_candidate",
+                        lambda *a, asked="auto", **k: candidate(*a, asked="selected", **k))
+    jax.clear_caches()
+    with jax.default_matmul_precision("highest"):
+        got, loss = hybrid.sparse_attention_mixer(spec, p, u, jnp.float32)
+        picked = hybrid.sparse_selection(spec, p, u, jnp.float32)
+    text = jax.jit(lambda u: hybrid.sparse_selection(
+        spec, p, u, jnp.float32)).lower(u).as_text()
+    monkeypatch.undo()
+    jax.clear_caches()
+    spans = len(hybrid._key_spans(spec, 256)[1])
+    assert len(re.findall(r"call @_index_fwd(_\d+)?\(", text)) == spans
+    m = dict(model(top=48), sa_config=dict(model()["sa_config"], topk=48,
+                                           indexer_head_dim=64))
+    with jax.default_matmul_precision("highest"):
+        for i in range(BATCH):
+            want, want_loss, own = ref.sparse_attention(p, u[i], m, jnp.matmul,
+                                                        given=picked[i])
+            np.testing.assert_allclose(got[i], want, atol=3e-5)
+            assert float(loss[i]) == pytest.approx(float(want_loss), rel=1e-4)
+            assert bool((own == picked[i]).all())
 
 
 def zaya_config(n_layers=4):

@@ -1,10 +1,13 @@
 """``ops/pallas/sparse_attention.py`` (the kernels that take a selection of
 keys) interpreted on the CPU: the three passes against plain jnp at shapes
 with several key blocks, grouped heads, rows that select nothing in a tile
-and a frontier that skips blocks; then ``models/hybrid``'s sparse mixer with
-the kernel forced by name against its own XLA path (output, index loss, the
+and a frontier that skips blocks; the indexer's score kernels against
+``models/hybrid.index_scores`` and its ``jax.vjp`` (the keys' gradient added
+to a held accumulator); then ``models/hybrid``'s sparse mixer with the
+kernel forced by name against its own XLA path (output, index loss, the
 gradients of q, k, v and of the indexer's parameters), under ties in the
-index scores and where a first chunk selects every causal key; who chooses
+index scores and where a first chunk selects every causal key, with the
+index scores in XLA and in their kernels; who chooses
 (``attention_candidate``); and the other families' training steps, which
 lower to the parent's text byte for byte.
 """
@@ -113,6 +116,88 @@ def test_key_blocks_tile_the_keys(n_keys, largest, want):
     assert kernel._block_k(n_keys, largest) == want
 
 
+# ------------------------------------------------------------ the indexer's scores
+
+#: (C, L, J, D, frontier): a chunk of queries over its keys, the index heads
+INDEX_SHAPES = {
+    "frontier-in-the-second-of-three-blocks": (128, 384, 4, 64, 228),
+    "cell-like-16-heads": (256, 512, 16, 64, 512),
+    "one-key-block-128-wide": (128, 128, 2, 128, 128),
+    "blocks-of-512-the-second-skipped": (256, 1024, 4, 64, 256),
+}
+
+
+def index_case(shape, dtype=jnp.float32, seed=0):
+    c, n_keys, heads, dim, frontier = shape
+    rng = np.random.default_rng(seed)
+    qi = jnp.asarray(rng.standard_normal((c, heads, dim)), dtype)
+    ki = jnp.asarray(rng.standard_normal((n_keys, dim)), dtype)
+    w = jnp.asarray(rng.standard_normal((c, heads)), jnp.float32)
+    block = kernel._block_k(n_keys)
+    computed = -(-frontier // block) * block       # keys of the blocks it reads
+    d_scores = jnp.asarray(rng.standard_normal((c, n_keys)), jnp.float32)
+    return qi, ki, w, d_scores.at[:, computed:].set(0.0), frontier, computed
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", list(INDEX_SHAPES.values()), ids=list(INDEX_SHAPES))
+def test_index_forward_is_the_xla_index_scores(shape, dtype):
+    """The products of bf16 operands are exact in f32: both dtypes agree to
+    f32 rounding; the key blocks from the frontier's on are zeros."""
+    qi, ki, w, _, frontier, computed = index_case(shape, dtype)
+    with jax.default_matmul_precision("highest"):
+        want = hybrid.index_scores(qi, ki, w)
+    got = kernel.index_forward(qi, ki, w, frontier)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    close(got[:, :computed], want[:, :computed], jnp.float32)
+    assert not np.asarray(got[:, computed:]).any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", list(INDEX_SHAPES.values()), ids=list(INDEX_SHAPES))
+def test_index_backward_adds_to_a_held_accumulator(shape, dtype):
+    """``d qi``, ``d w`` and ``d ki`` against ``jax.vjp`` of the XLA path;
+    ``d ki`` ADDS to a non-zero accumulator, whose key blocks from the
+    frontier's on are neither read nor written."""
+    qi, ki, w, d_scores, frontier, computed = index_case(shape, dtype)
+    with jax.default_matmul_precision("highest"):
+        _, pull = jax.vjp(hybrid.index_scores, qi, ki, w)
+        want_qi, want_ki, want_w = pull(d_scores)
+    held = jnp.asarray(np.random.default_rng(1).standard_normal(ki.shape[::-1]),
+                       jnp.float32)
+    d_qi, dki_t, d_w = kernel.index_backward(qi, ki, w, d_scores, held, frontier)
+    assert (d_qi.shape, d_qi.dtype) == (qi.shape, qi.dtype)
+    assert dki_t.dtype == d_w.dtype == jnp.float32 and d_w.shape == w.shape
+    close(d_qi, want_qi, dtype)
+    close((dki_t - held).T, want_ki, dtype)
+    close(d_w, want_w, jnp.float32 if dtype == jnp.float32 else dtype)
+    assert (np.asarray(dki_t)[:, computed:] == np.asarray(held)[:, computed:]).all()
+
+
+def test_index_scores_differentiate_through_their_own_backward():
+    qi, ki, w, d_scores, frontier, _ = index_case(INDEX_SHAPES[
+        "frontier-in-the-second-of-three-blocks"])
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * d_scores), argnums=(0, 1, 2))(
+            qi, ki, w)
+
+    with jax.default_matmul_precision("highest"):
+        want = grads(hybrid.index_scores)
+    for a, b in zip(grads(lambda *a: kernel.index_scores(*a, frontier)), want):
+        close(a, b, jnp.float32)
+
+
+@pytest.mark.parametrize("c,n_keys,dim,want", [
+    (256, 16384, 64, True), (128, 128, 128, True), (512, 4096, 64, True),
+    (256, 16384, 32, False), (16, 64, 64, False), (256, 640, 64, True),
+    (256, 200, 64, False), (256, 32768, 64, False)],
+    ids=["cell", "one-block", "chunk-of-512", "width-32", "chunk-of-16",
+         "five-blocks", "partial-block", "a-second-chips"])
+def test_index_kernels_take_the_attention_kernels_chunks(c, n_keys, dim, want):
+    assert kernel.index_takes(c, n_keys, dim) is want
+
+
 # ------------------------------------------------------------ the mixer's two paths
 
 E, SEQ, BATCH, J, DI = 64, 64, 2, 4, 8
@@ -130,8 +215,9 @@ def mixer_case(spec, seed=3, tied=False):
     return p, jax.random.normal(jax.random.key(seed + 1), (BATCH, SEQ, E))
 
 
-def both_paths(monkeypatch, fn, *args):
-    """``fn(*args)`` with the mixer on its XLA path and on the kernel."""
+def both_paths(monkeypatch, fn, *args, index="xla"):
+    """``fn(*args)`` with the mixer on its XLA path and on the kernel, whose
+    index scores take the ``index`` path."""
     out = []
     for asked in ("ring", "selected"):
         monkeypatch.setattr(hybrid, "sparse_attend", functools.partial(
@@ -141,8 +227,10 @@ def both_paths(monkeypatch, fn, *args):
         with jax.default_matmul_precision("highest"):
             out.append(fn(*args))
         c = METRICS.snapshot()["counters"]
-        assert c.get("attention.path.kernel" if asked == "selected"
-                     else "attention.path.xla", 0) >= 1, (asked, c)
+        on = asked == "selected"
+        assert c.get("attention.path.kernel" if on else "attention.path.xla", 0) >= 1
+        want = f"dsa.index_path.{index if on else 'xla'}"
+        assert c.get(want, 0) == c["dsa.layers"] >= 1, (asked, c)
     monkeypatch.undo()
     jax.clear_caches()
     return out
@@ -177,6 +265,42 @@ def test_mixer_on_the_kernel_is_the_mixer_on_the_xla_path(monkeypatch, case):
     np.testing.assert_allclose(got_loss, want_loss, rtol=2e-5)
     assert float(jnp.abs(want_g[0]["index"]["wq" if case != "tied-scores" else "ww"]
                          ).max()) > 1e-6        # the indexer has a gradient
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
+                            jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(
+            a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())), err_msg=str(path))
+
+
+def index_mixer(**kw):
+    """A mixer whose chunks the index kernels take: 128 queries at a time,
+    index heads of 64."""
+    return dataclasses.replace(hybrid.SparseAttention(
+        H, G, D, 1e7, True, J, 64, top_k=48, q_chunk=128, kv_chunk=128, rows=128),
+        **kw)
+
+
+@pytest.mark.parametrize("case", ["two-chunks", "one-chunk-of-256", "tied-scores"])
+def test_mixer_on_the_index_kernels_is_the_mixer_on_the_xla_path(monkeypatch, case):
+    """As above, with the index scores (and, backward, the indexer's
+    gradients) in the index kernels: 256 positions, ``top_k = 48``."""
+    spec = index_mixer(rows=256) if case == "one-chunk-of-256" else index_mixer()
+    p = spec.init(jax.random.key(5), E, jnp.float32)
+    if case == "tied-scores":
+        p = dict(p, index=dict(p["index"], ww=jnp.zeros_like(p["index"]["ww"])))
+    u = jax.random.normal(jax.random.key(6), (1, 256, E))
+
+    def run(p, u):
+        def f(p, u):
+            out, loss = hybrid.sparse_attention_mixer(spec, p, u, jnp.float32)
+            return jnp.sum(jnp.sin(out)) + 2.0 * jnp.sum(loss), (out, loss)
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(p, u)
+
+    ((_, (want, want_loss)), want_g), ((_, (got, got_loss)), got_g) = both_paths(
+        monkeypatch, run, p, u, index="kernel")
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=2e-5)
+    assert float(jnp.abs(want_g[0]["index"]["wq" if case != "tied-scores" else "ww"]
+                         ).max()) > 1e-6
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
                             jax.tree_util.tree_leaves(want_g)):
         np.testing.assert_allclose(
@@ -236,6 +360,31 @@ def test_a_checkpointed_block_runs_the_forward_kernel_once(monkeypatch):
         "_sparse_fwd", "_sparse_bwd", "_sparse_headsum")}
     assert calls == {"_sparse_fwd": spans, "_sparse_bwd": spans,
                      "_sparse_headsum": 2 * spans}, calls
+
+
+def test_a_checkpointed_block_scores_the_index_twice(monkeypatch):
+    """On the index kernels the compiled gradient of a checkpointed layer
+    holds the index forward twice a span of chunks (the forward pass, and
+    the backward's selection made again) and the index backward once: the
+    block's recomputed forward makes no index score."""
+    monkeypatch.setattr(hybrid, "sparse_attend", functools.partial(
+        _sparse_attend, asked="selected"))
+    jax.clear_caches()
+    base = TransformerConfig(
+        vocab_size=256, d_model=E, n_heads=H, n_kv_heads=G, n_layers=1, d_ff=32,
+        max_len=256, causal=True, tie_embeddings=False, remat=True, xent_chunk=32)
+    cfg = hybrid.HybridConfig(base=base, norm_eps=1e-6, layers=((
+        index_mixer(), hybrid.GatedMLP(32)),))
+    params = hybrid.init_params(jax.random.key(0), cfg)
+    toks = jnp.zeros((1, 256), jnp.int32)
+    text = jax.jit(jax.value_and_grad(lambda p: hybrid.lm_loss(p, toks, toks, cfg))
+                   ).lower(params).as_text()
+    jax.clear_caches()
+    spans = len(hybrid._key_spans(index_mixer(), 256)[1])
+    calls = {name: len(re.findall(rf"call @{name}(_\d+)?\(", text)) for name in (
+        "_index_fwd", "_index_bwd", "_sparse_fwd")}
+    assert calls == {"_index_fwd": 2 * spans, "_index_bwd": spans,
+                     "_sparse_fwd": spans}, calls
 
 
 # ------------------------------------------------------------------- who chooses
