@@ -13,6 +13,16 @@ here.
 The persistent compilation cache is switched off through JAX's own flag
 (exported for subprocesses too), so no test writes into the checkout's
 ``.cache/xla`` — see ``deeplearning4j_tpu/parallel/compile_cache.py``.
+
+The suite runs on six workers that are dealt whole files (``-n 6 --dist
+loadfile``): no file may set the suite's clock alone, so a file whose own
+total passes 300 s is split in two by concern (but ``test_chip_compile.py``:
+the tests that load libtpu stay in one file).  On the CPU the time is spent
+compiling, and an eager call compiles every operation it meets anew: a
+model's tests share their compiled programs through module fixtures (``case``
+in ``test_hybrid_*.py``: one jitted program gives what every test reads),
+jitted functions defined once at module level, or ``jax.eval_shape`` where a
+test reads shapes alone.  A program is compiled once per file.
 """
 
 import os
@@ -67,80 +77,6 @@ def pytest_configure(config):
         "promotion (e.g. a python float silently widening bf16 to fp32) "
         "inside the test body fails it (parity tests must pin dtypes "
         "explicitly, not inherit them from promotion rules)")
-
-
-#: Three tests of ``tests/benchmark_tests/test_benchmark_looped.py`` (PR 32)
-#: hold PR 32's entries to the LAST place of ``BENCHMARK.json``'s lists
-#: (``configs[-1]``, ``workloads[-1]``, ``per_layer[-3:]``).  The manifest's
-#: rule is that a PR APPENDS its entries, and that a file the benchmark
-#: already has is edited by no PR but a ``benchmark`` one: so the next PR that
-#: adds a configuration (PR 34) can neither keep these asserts true nor
-#: repair them.  They are expected to fail from PR 34 on; what they hold
-#: besides the position (the looped configuration's published numbers, its
-#: cell's sizes, its metrics) is tested again, without the position, in
-#: ``tests/benchmark_tests/test_benchmark_sparse.py``.  A ``benchmark`` PR
-#: takes the three asserts out of the accepted file and this list with them
-#: (PERF.md section 7).
-LAST_PLACE_ASSERTS = {
-    "tests/benchmark_tests/test_benchmark_looped.py::" + name for name in (
-        "test_configuration_keeps_the_published_numbers",
-        "test_cell_is_what_the_issue_names",
-        "test_cell_reports_the_common_metrics_and_its_own")}
-
-
-#: Two tests of ``tests/benchmark_tests/test_benchmark_sparse.py`` (PR 34)
-#: hold the NUMBER of per-layer metrics the sparse and the looped cell report
-#: (``len(names) == 14 + 6 + 6``, ``== 14 + 1 + 3``) and the sparse cell's
-#: metrics beside ZAYA's to exactly PR 34's six.  A PR that appends a
-#: per-layer metric to either cell (PR 36: ``unnamed_share.train`` and six
-#: more) can neither keep those counts true nor repair an accepted file.  They
-#: are expected to fail from PR 36 on; everything else they hold (the entries'
-#: order and lists, the end-to-end metrics, the cells' own metrics) is tested
-#: again, with the counts as they stand now, in
-#: ``tests/benchmark_tests/test_benchmark_scope_rest.py``.  A ``benchmark`` PR
-#: takes the counts out of the accepted file and this list with them.
-METRIC_COUNT_ASSERTS = {
-    "tests/benchmark_tests/test_benchmark_sparse.py::" + name for name in (
-        "test_cell_reports_the_common_metrics_and_its_own",
-        "test_looped_cell_reports_the_common_metrics_and_its_own")}
-
-
-#: Three tests of ``tests/benchmark_tests/test_benchmark_scope_rest.py`` (PR 36)
-#: hold PR 36's six per-layer entries to the END of ``per_layer``
-#: (``order[first + 6:] == [...]``) and to exact ``workloads`` lists (the five
-#: cells of ``unnamed_share.train``, the two of ``moe_share.combine.train``).
-#: A PR that appends a cell which reports those metrics, and per-layer metrics
-#: of its own (PR 38), can neither keep these asserts true nor repair an
-#: accepted file.  They are expected to fail from PR 38 on; everything else
-#: they hold (the six entries' order and fields, their accepted cells in
-#: order, the sparse cell's own metrics) is tested again, without the
-#: position and the lists' length, in
-#: ``tests/benchmark_tests/test_benchmark_mla.py``.  A ``benchmark`` PR takes
-#: the asserts out of the accepted file and this list with them.
-APPENDED_AFTER_PR_36 = {
-    "tests/benchmark_tests/test_benchmark_scope_rest.py::" + name for name in (
-        "test_the_sparse_cell_reports_what_it_did_and_this_prs",
-        "test_metric_files_and_manifest_entries[unnamed_share.train-scope_rest-"
-        "args0-cells0]",
-        "test_metric_files_and_manifest_entries[moe_share.combine.train-"
-        "scope_split-args3-cells3]")}
-
-
-def pytest_collection_modifyitems(config, items):
-    for item in items:
-        if item.nodeid in APPENDED_AFTER_PR_36:
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="asserts that PR 36's entries close "
-                "per_layer and list exactly the cells PR 36 knew; PR 38 "
-                "appended a cell and its own metrics"))
-        if item.nodeid in LAST_PLACE_ASSERTS:
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="asserts that PR 32's entries are the "
-                "LAST of BENCHMARK.json's lists; PR 34 appended its own"))
-        if item.nodeid in METRIC_COUNT_ASSERTS:
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="asserts the exact number of per-layer "
-                "metrics a cell reports; PR 36 appended its own"))
 
 
 @pytest.fixture(autouse=True)

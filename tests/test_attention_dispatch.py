@@ -28,8 +28,8 @@ def _loss_and_grad(cfg, t=128, seed=0):
     toks = jax.random.randint(jax.random.key(seed + 1), (2, t), 0,
                               cfg.vocab_size)
     tgts = jnp.roll(toks, -1, axis=1)
-    return jax.value_and_grad(
-        lambda p: lm_loss_local(p, toks, tgts, cfg))(params)
+    return jax.jit(jax.value_and_grad(
+        lambda p: lm_loss_local(p, toks, tgts, cfg)))(params)
 
 
 def _paths():

@@ -51,6 +51,17 @@ def one_chip():
     cc.reset_cache()
 
 
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """Code that asks for the backend still sees the CPU here: steer it to
+    the chip, and the kernels to Mosaic, not the interpreter."""
+    from deeplearning4j_tpu.ops.pallas import registry
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(registry, "resolve_interpret",
+                        lambda interpret: bool(interpret))
+
+
 def _compile(fn, sharding, *shapes):
     """Compile ``fn`` for the described chip from shapes alone and return
     the optimized HLO text."""
@@ -103,7 +114,7 @@ def test_attention_fwd_bwd_compiles(one_chip, kernel, causal, shape):
     assert "tpu_custom_call" in hlo
 
 
-def test_train_step_holds_no_score_matrix(one_chip, monkeypatch):
+def test_train_step_holds_no_score_matrix(one_chip, as_on_the_chip):
     """``value_and_grad(lm_loss_local)`` of two BERT-base-width blocks,
     compiled for the chip with the kernel ``_block`` takes there: no array
     whose last two dimensions are both the sequence length (the scores are
@@ -115,12 +126,7 @@ def test_train_step_holds_no_score_matrix(one_chip, monkeypatch):
     from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                        init_params,
                                                        lm_loss_local)
-    from deeplearning4j_tpu.ops.pallas import registry
 
-    # code that asks for the backend still sees the CPU here: steer it
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(registry, "resolve_interpret",
-                        lambda interpret: bool(interpret))
     cfg = TransformerConfig(vocab_size=2048, d_model=D, n_heads=H,
                             n_layers=2, d_ff=F, max_len=T, causal=False,
                             remat=False)
@@ -191,7 +197,7 @@ def test_int8_matmul_compiles(one_chip, k, n):
     assert "tpu_custom_call" in hlo
 
 
-def test_hybrid_block_takes_the_kernel_at_4096(one_chip, monkeypatch):
+def test_hybrid_block_takes_the_kernel_at_4096(one_chip, as_on_the_chip):
     """One ZAYA1-width layer (CCA at 8 query heads over 2 KV heads of 128,
     8 of 16 experts held) at 2 x 4096, forward and backward, compiled for the
     chip as ``models/hybrid.py`` runs it there: attention is the fused kernel
@@ -203,11 +209,7 @@ def test_hybrid_block_takes_the_kernel_at_4096(one_chip, monkeypatch):
     from deeplearning4j_tpu.models import hybrid
     from deeplearning4j_tpu.models.transformer import TransformerConfig
     from deeplearning4j_tpu.observability import METRICS
-    from deeplearning4j_tpu.ops.pallas import registry
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(registry, "resolve_interpret",
-                        lambda interpret: bool(interpret))
     t = 4096
     base = TransformerConfig(vocab_size=2048, d_model=2048, n_heads=8,
                              n_kv_heads=2, n_layers=1, d_ff=2048, max_len=t,
@@ -232,7 +234,7 @@ def test_hybrid_block_takes_the_kernel_at_4096(one_chip, monkeypatch):
     assert "ragged-dot" in hlo
 
 
-def test_looped_step_compiles_each_layer_once_at_4096(one_chip, monkeypatch):
+def test_looped_step_compiles_each_layer_once_at_4096(one_chip, as_on_the_chip):
     """Two layers of the looped family at its published widths (16 heads of
     128, no KV repeat, gated FFN 5632, sandwich norms) run 4 times at 2 x 4096
     with the exit-weighted objective, forward and backward, compiled for the
@@ -246,11 +248,7 @@ def test_looped_step_compiles_each_layer_once_at_4096(one_chip, monkeypatch):
     from deeplearning4j_tpu.models import hybrid
     from deeplearning4j_tpu.models.transformer import TransformerConfig
     from deeplearning4j_tpu.observability import METRICS
-    from deeplearning4j_tpu.ops.pallas import registry
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(registry, "resolve_interpret",
-                        lambda interpret: bool(interpret))
     t = 4096
     base = TransformerConfig(vocab_size=4096, d_model=2048, n_heads=16,
                              n_kv_heads=16, n_layers=2, d_ff=5632, max_len=t,
@@ -315,15 +313,7 @@ def _sparse_layer(one_chip, t, **mixer):
         "dsa.index_path.kernel", "dsa.index_path.xla")}
 
 
-def _as_on_the_chip(monkeypatch):
-    from deeplearning4j_tpu.ops.pallas import registry
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(registry, "resolve_interpret",
-                        lambda interpret: bool(interpret))
-
-
-def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip, monkeypatch):
+def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip, as_on_the_chip):
     """The sparse layer at 1 x 16,384, the cell's length, compiled as
     ``models/hybrid.py`` runs it on the chip: Mosaic takes the three kernels
     that take the selection (forward once a span of chunks and NOT again in
@@ -339,7 +329,6 @@ def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip, monkeypatch)
     import re
 
     t, spans = 16384, 4
-    _as_on_the_chip(monkeypatch)
     hlo, moved = _sparse_layer(one_chip, t)
     assert moved == {"dsa.layers": 1, "attention.path.kernel": 1,
                      "attention.path.xla": 0, "dsa.index_path.kernel": 1,
@@ -383,10 +372,9 @@ def test_sparse_block_compiles_with_no_whole_score_matrix(one_chip, monkeypatch)
 
 
 def test_sparse_block_falls_back_where_the_kernel_does_not_take_the_shape(
-        one_chip, monkeypatch):
+        one_chip, as_on_the_chip):
     """Chunks of 192 queries are no whole blocks: the same layer at 1 x 3072
     runs the mixer's XLA path on the chip too, and counts it."""
-    _as_on_the_chip(monkeypatch)
     hlo, moved = _sparse_layer(one_chip, 3072, rows=192)
     assert moved == {"dsa.layers": 1, "attention.path.kernel": 0,
                      "attention.path.xla": 1, "dsa.index_path.kernel": 0,

@@ -20,7 +20,7 @@ def _qkv(b=2, t=256, h=3, d=16, seed=0):
 def _value_and_grads(fn, q, k, v, **kw):
     def loss(q, k, v):                        # non-trivial cotangent
         return jnp.sum(jnp.sin(fn(q, k, v, **kw).astype(jnp.float32)))
-    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
 
 def _assert_matches_reference(got, q, k, v, causal, dtype):

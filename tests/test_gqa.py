@@ -76,6 +76,11 @@ def test_kv_heads_must_divide_n_heads():
         gqa_cfg(n_kv_heads=4).kv_heads
 
 
+#: compiled once a configuration: the full-heads model's serves every case
+forward = jax.jit(forward_local, static_argnums=2)
+loss_and_grads = jax.jit(jax.value_and_grad(lm_loss_local), static_argnums=3)
+
+
 @pytest.mark.parametrize("n_kv", [1, 2, 6])
 def test_gqa_loss_matches_repeat_heads_reference(n_kv):
     """Forward + loss + grads of the GQA tree match the full-heads model
@@ -92,15 +97,12 @@ def test_gqa_loss_matches_repeat_heads_reference(n_kv):
         full = params                    # same packed tree by construction
     else:
         full = _expand_to_full_heads(params, cfg)
-    np.testing.assert_allclose(
-        np.asarray(forward_local(params, toks, cfg)),
-        np.asarray(forward_local(full, toks, cfg_full)),
-        atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(forward(params, toks, cfg)),
+                               np.asarray(forward(full, toks, cfg_full)),
+                               atol=1e-5, rtol=1e-5)
 
-    loss, grads = jax.value_and_grad(
-        lambda p: lm_loss_local(p, toks, tgts, cfg))(params)
-    loss_f, grads_f = jax.value_and_grad(
-        lambda p: lm_loss_local(p, toks, tgts, cfg_full))(full)
+    loss, grads = loss_and_grads(params, toks, tgts, cfg)
+    loss_f, grads_f = loss_and_grads(full, toks, tgts, cfg_full)
     np.testing.assert_allclose(float(loss), float(loss_f), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(grads["tok_embed"]),
                                np.asarray(grads_f["tok_embed"]), atol=1e-5)

@@ -6,7 +6,8 @@ weights, float32 at the highest matmul precision: objective, every loop
 step's loss, the exit distribution and every gradient leaf; a shared layer's
 gradient is the sum over its uses; one loop step without a gate is one plain
 pass; each part moves the output; any mixer goes with any ffn; the head's
-chunked loss takes token weights; the trainer steps it.
+chunked loss takes token weights; the trainer steps it.  What the tests read
+of the model's program is one compiled program (``case``).
 """
 
 import dataclasses
@@ -25,6 +26,7 @@ from deeplearning4j_tpu.observability import METRICS
 from deeplearning4j_tpu.optimize import transforms as T
 from deeplearning4j_tpu.parallel import DataParallelTrainer
 from deeplearning4j_tpu.parallel.mesh import local_mesh
+from hybrid_common import leaf_names, named, seeded_params
 
 E, H, G, D, F, V, SEQ, BATCH, LAYERS, LOOPS = 64, 4, 2, 16, 96, 512, 32, 3, 2, 4
 BETA = 0.1
@@ -47,22 +49,6 @@ def model(n_layers=LAYERS, n_loops=LOOPS, beta=BETA):
             "num_hidden_layers": n_layers, "total_ut_steps": n_loops}
 
 
-def seeded_params(cfg, seed=0):
-    """Init, with every vector and scalar (norms, the gate) moved off its
-    neutral value so that a dropped one would show."""
-    params = hybrid.init_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    return tree.unflatten([
-        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim <= 1 else a
-        for a, k in zip(leaves, keys)])
-
-
-def leaf_names(tree):
-    return ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
-
-
 def batch(seed=7, n=BATCH):
     toks = jax.random.randint(jax.random.key(seed), (n, SEQ), 0, V)
     return toks, jnp.roll(toks, -1, axis=1)
@@ -74,20 +60,24 @@ def mean_objective(params, toks, tgts, cfg):
 
 @pytest.fixture(scope="module")
 def case():
+    """The model beside the reference: what the tests read of the program is
+    ONE compiled program."""
     cfg = config()
     params = seeded_params(cfg)
     toks, tgts = batch()
+
+    def program(params):
+        return (jax.value_and_grad(mean_objective)(params, toks, tgts, cfg),
+                hybrid.looped_losses(params, toks, tgts, cfg)[1:])
+
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(mean_objective)(params, toks, tgts, cfg)
-        _, xent, log_p = hybrid.looped_losses(params, toks, tgts, cfg)
+        (loss, grads), (xent, log_p) = jax.jit(program)(params)
         ref_loss, ref_grads, ref_aux = ref.loss_and_grads(
             params, toks, tgts, model(), block_rows=8)
     return {"cfg": cfg, "params": params, "toks": toks, "tgts": tgts,
             "loss": loss, "xent": xent, "log_p": log_p,
-            "grads": dict(zip(leaf_names(grads), jax.tree_util.tree_leaves(grads))),
-            "ref_loss": ref_loss, "ref_aux": ref_aux,
-            "ref_grads": dict(zip(leaf_names(ref_grads),
-                                  jax.tree_util.tree_leaves(ref_grads)))}
+            "grads": named(grads), "ref_loss": ref_loss, "ref_aux": ref_aux,
+            "ref_grads": named(ref_grads)}
 
 
 LEAVES = leaf_names(jax.eval_shape(
@@ -136,8 +126,9 @@ def test_layer_by_layer_gradients_are_the_whole_models(case):
     ``loss``."""
     params, toks, tgts = case["params"], case["toks"][:2], case["tgts"][:2]
     total, grads, aux = ref.loss_and_grads(params, toks, tgts, model(), block_rows=8)
-    whole = [jax.value_and_grad(ref.loss, has_aux=True)(params, toks[i], tgts[i],
-                                                        model()) for i in range(2)]
+    whole_loss = jax.jit(jax.value_and_grad(
+        lambda p, x, y: ref.loss(p, x, y, model()), has_aux=True))
+    whole = [whole_loss(params, toks[i], tgts[i]) for i in range(2)]
     assert float(total) == pytest.approx(
         sum(float(v) for (v, _), _ in whole) / 2, rel=1e-6)
     np.testing.assert_allclose(
@@ -173,16 +164,23 @@ def test_bf16_compute_stays_near_the_reference(case):
     objective within a few hundredths of the float32 reference's after 8
     layer applications."""
     cfg = config(dtype=jnp.bfloat16)
-    loss = mean_objective(case["params"], case["toks"], case["tgts"], cfg)
+    loss = jax.jit(mean_objective, static_argnums=3)(
+        case["params"], case["toks"], case["tgts"], cfg)
     assert abs(float(loss) - float(case["ref_loss"])) < 0.03
 
 
+@pytest.fixture(scope="module")
+def exact(case):
+    """The first row's float32 reference gradients, for both precisions."""
+    return ref.loss_and_grads(case["params"], case["toks"][:1], case["tgts"][:1],
+                              model())[1]
+
+
 @pytest.mark.parametrize("operand,looser", [("bfloat16", 1e-4), ("float8_e4m3fn", 1e-3)])
-def test_lower_precision_operands_move_the_reference(case, operand, looser):
+def test_lower_precision_operands_move_the_reference(case, exact, operand, looser):
     """The reading that sets the benchmark's limits: the same mathematics
     with every matrix product's operands rounded."""
     params, toks, tgts = case["params"], case["toks"][:1], case["tgts"][:1]
-    _, exact, _ = ref.loss_and_grads(params, toks, tgts, model())
     _, rounded, _ = ref.loss_and_grads(params, toks, tgts, model(),
                                        operand_dtype=getattr(jnp, operand))
     err = ref.compare_grads(rounded, exact)
@@ -235,7 +233,7 @@ def test_the_loop_traces_each_layer_once(case):
     LOOPS``: attention asked its path once a layer, and the jaxpr has one
     scan over the loop steps with ``LAYERS`` checkpointed blocks in it."""
     cfg = config(n_layers=3)                 # a shape no other test has traced
-    params = hybrid.init_params(jax.random.key(0), cfg)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg))
     toks, tgts = batch()
     before = METRICS.snapshot()["counters"]
     jaxpr = jax.make_jaxpr(jax.grad(
@@ -280,14 +278,15 @@ def test_a_gate_forced_open_at_the_first_step_gives_its_loss(case):
     cfg, toks, tgts = case["cfg"], case["toks"], case["tgts"]
     params = dict(case["params"], exit_gate={"w": jnp.zeros((E,)),
                                              "b": jnp.asarray(1e4)})
-    objective, xent, log_p = hybrid.looped_losses(params, toks, tgts, cfg)
+    losses = jax.jit(hybrid.looped_losses, static_argnums=3)     # run twice
+    objective, xent, log_p = losses(params, toks, tgts, cfg)
     np.testing.assert_allclose(jnp.exp(log_p[0]), 1.0)
     np.testing.assert_allclose(objective, xent[0], rtol=1e-6)
-    grads = jax.grad(mean_objective)(params, toks, tgts, cfg)
+    grads = jax.jit(jax.grad(mean_objective), static_argnums=3)(params, toks, tgts, cfg)
     assert all(bool(jnp.isfinite(g).all()) for g in jax.tree_util.tree_leaves(grads))
     # shut until the last step: its loss alone
     params["exit_gate"]["b"] = jnp.asarray(-1e4)
-    objective, xent, _ = hybrid.looped_losses(params, toks, tgts, cfg)
+    objective, xent, _ = losses(params, toks, tgts, cfg)
     np.testing.assert_allclose(objective, xent[-1], rtol=1e-6)
 
 
@@ -296,16 +295,17 @@ def test_a_larger_beta_raises_the_entropy_after_a_step_of_descent(case):
 
     def entropy(p_, cfg):
         log_p = hybrid.looped_losses(p_, toks, tgts, cfg)[2]
-        return float(-(jnp.exp(log_p) * log_p).sum(axis=0).mean())
+        return -(jnp.exp(log_p) * log_p).sum(axis=0).mean()
 
-    after = {}
-    for beta in (0.0, 0.1, 1.0):
-        cfg = config(beta=beta)
+    def after_a_step(params, cfg):               # one program a beta
         g = jax.grad(mean_objective)(params, toks, tgts, cfg)
         # only the gate moves: what beta pulls on
         moved = dict(params, exit_gate=jax.tree_util.tree_map(
             lambda a, b: a - 0.5 * b, params["exit_gate"], g["exit_gate"]))
-        after[beta] = entropy(moved, cfg)
+        return entropy(moved, cfg)
+
+    after = {beta: float(jax.jit(after_a_step, static_argnums=1)(
+        params, config(beta=beta))) for beta in (0.0, 0.1, 1.0)}
     assert after[0.0] < after[0.1] < after[1.0], after
 
 
@@ -375,17 +375,19 @@ def test_any_mixer_goes_with_any_ffn(pair, n_loops, tied):
                                                          ffn.post_norm)
     assert ("lm_head" in params) == (not tied)
     toks, tgts = batch()
-    hs, choices = hybrid.encode_steps(params, toks, cfg)
-    assert hs.shape == (n_loops, BATCH, SEQ, E)
-    if isinstance(ffn, hybrid.MoE):
-        assert all(e.shape == (n_loops, BATCH, SEQ) for e in choices)
-        assert hybrid.routing_stats(params, toks, cfg).shape == (LAYERS, 8)
-    else:
-        assert choices == [None] * LAYERS
     fn = (hybrid.lm_loss_per_example if n_loops == 1
           else hybrid.looped_lm_loss_per_example)
-    loss, grads = jax.value_and_grad(
-        lambda p: fn(p, toks, tgts, cfg).mean())(params)
+    moe = isinstance(ffn, hybrid.MoE)
+    (hs, choices), stats, (loss, grads) = jax.jit(lambda p: (   # one program
+        hybrid.encode_steps(p, toks, cfg),
+        hybrid.routing_stats(p, toks, cfg) if moe else None,
+        jax.value_and_grad(lambda p: fn(p, toks, tgts, cfg).mean())(p)))(params)
+    assert hs.shape == (n_loops, BATCH, SEQ, E)
+    if moe:
+        assert all(e.shape == (n_loops, BATCH, SEQ) for e in choices)
+        assert stats.shape == (LAYERS, 8)
+    else:
+        assert choices == [None] * LAYERS
     assert np.isfinite(float(loss))
     norms = {n: float(jnp.linalg.norm(g)) for n, g in
              zip(leaf_names(grads), jax.tree_util.tree_leaves(grads))}
@@ -533,7 +535,7 @@ def test_loop_names_in_the_lowered_step():
     from benchmark.trace_spans import scope_of
 
     cfg = config(n_layers=1)
-    params = hybrid.init_params(jax.random.key(0), cfg)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg))
     x = jnp.zeros((2, SEQ), jnp.int32)
     text = jax.jit(jax.value_and_grad(
         lambda p: mean_objective(p, x, x, cfg))).lower(params).compiler_ir(

@@ -3,7 +3,8 @@ against the plain reference ``models/reference/zaya.py`` at a small size on
 the CPU, seeded random weights, float32 at the highest matmul precision:
 logits, loss and every gradient leaf; each part of CCA moves the output;
 routing loses no token under any imbalance; the shares add up to the uncut
-layer; the trainer's two ways of taking per-example losses agree.
+layer; the trainer's two ways of taking per-example losses agree.  What the
+tests read of the model's program is one compiled program (``case``).
 """
 
 import dataclasses
@@ -22,6 +23,7 @@ from deeplearning4j_tpu.observability import METRICS
 from deeplearning4j_tpu.optimize import transforms as T
 from deeplearning4j_tpu.parallel import DataParallelTrainer
 from deeplearning4j_tpu.parallel.mesh import local_mesh
+from hybrid_common import leaf_names, named, seeded_params
 
 E, H, G, D, R, F, V, SEQ, BATCH = 64, 4, 2, 16, 32, 48, 512, 32, 3
 N_EXPERTS, HELD = 8, (0, 4)
@@ -42,37 +44,29 @@ def model(held=HELD):
             "partial_rotary_factor": 0.5, "experts_held": list(held)}
 
 
-def seeded_params(cfg, seed=0):
-    """Init, with every vector (norms, temperatures, biases) moved off its
-    neutral value so that a dropped one would show."""
-    params = hybrid.init_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    return tree.unflatten([
-        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
-        for a, k in zip(leaves, keys)])
-
-
-def leaf_names(tree):
-    return ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
-
-
 @pytest.fixture(scope="module")
 def case():
+    """The model beside the reference: what the tests read of the program is
+    ONE compiled program."""
     cfg = config()
     params = seeded_params(cfg)
     toks = jax.random.randint(jax.random.key(7), (BATCH, SEQ), 0, V)
     tgts = jnp.roll(toks, -1, axis=1)
+
+    def program(params):
+        return (jax.value_and_grad(hybrid.lm_loss)(params, toks, tgts, cfg),
+                hybrid.forward(params, toks, cfg), hybrid.encode(params, toks, cfg)[1],
+                hybrid.lm_loss_per_example(params, toks, tgts, cfg),
+                hybrid.routing_stats(params, toks, cfg))
+
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(hybrid.lm_loss)(params, toks, tgts, cfg)
+        (loss, grads), logits, choices, rows, counts = jax.jit(program)(params)
         ref_loss, ref_grads, ref_e = ref.loss_and_grads(params, toks, tgts, model())
     return {"cfg": cfg, "params": params, "toks": toks, "tgts": tgts,
-            "loss": loss, "grads": dict(zip(leaf_names(grads),
-                                            jax.tree_util.tree_leaves(grads))),
-            "ref_loss": ref_loss, "ref_e": ref_e,
-            "ref_grads": dict(zip(leaf_names(ref_grads),
-                                  jax.tree_util.tree_leaves(ref_grads)))}
+            "loss": loss, "grads": named(grads), "logits": logits,
+            "choices": choices, "rows": rows, "counts": counts, "ref_loss": ref_loss,
+            "ref_e": ref_e,
+            "ref_grads": named(ref_grads)}
 
 
 LEAVES = leaf_names(jax.eval_shape(
@@ -81,17 +75,15 @@ LEAVES = leaf_names(jax.eval_shape(
 
 def test_logits_match_the_reference(case):
     with jax.default_matmul_precision("highest"):
-        got = hybrid.forward(case["params"], case["toks"], case["cfg"])
-        want = jnp.stack([ref.logits(case["params"], t, model())
-                          for t in case["toks"]])
-    np.testing.assert_allclose(got, want, atol=2e-5)
+        one = jax.jit(lambda p, t: ref.logits(p, t, model()))
+        want = jnp.stack([one(case["params"], t) for t in case["toks"]])
+    np.testing.assert_allclose(case["logits"], want, atol=2e-5)
     assert float(jnp.abs(want).max()) > 0.1
 
 
 def test_loss_and_routing_match_the_reference(case):
     assert abs(float(case["loss"]) - float(case["ref_loss"])) < 1e-5
-    _, choices = hybrid.encode(case["params"], case["toks"], case["cfg"])
-    assert bool((jnp.stack(choices, axis=1) == case["ref_e"]).all())
+    assert bool((jnp.stack(case["choices"], axis=1) == case["ref_e"]).all())
     # the comparison's own arithmetic: identical trees read 0 and 1
     same = ref.compare_grads(case["ref_grads"], case["ref_grads"])
     assert all(v["rel"] == 0 and abs(v["cos"] - 1) < 1e-6 for v in same.values())
@@ -120,27 +112,34 @@ def cca_case():
     return spec, p, u
 
 
+#: the mixer and the layer compiled once a spec (and a part left out)
+cca = jax.jit(hybrid.cca_mixer, static_argnums=(0, 3), static_argnames=(
+    "convs", "qk_mean", "value_shift", "rotary"))
+moe = jax.jit(hybrid.moe_ffn, static_argnums=(0, 3))
+
+
 def test_cca_matches_the_reference():
     spec, p, u = cca_case()
     with jax.default_matmul_precision("highest"):
-        got = hybrid.cca_mixer(spec, p, u, jnp.float32)
-        want = jnp.stack([ref.cca(p, x, model(), jnp.matmul) for x in u])
+        got = cca(spec, p, u, jnp.float32)
+        one = jax.jit(lambda p, x: ref.cca(p, x, model(), jnp.matmul))
+        want = jnp.stack([one(p, x) for x in u])
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
 @pytest.mark.parametrize("part", ["convs", "qk_mean", "value_shift", "rotary"])
 def test_each_part_of_cca_moves_the_output(part):
     spec, p, u = cca_case()
-    full = hybrid.cca_mixer(spec, p, u, jnp.float32)
-    without = hybrid.cca_mixer(spec, p, u, jnp.float32, **{part: False})
+    full = cca(spec, p, u, jnp.float32)
+    without = cca(spec, p, u, jnp.float32, **{part: False})
     assert float(jnp.abs(full - without).max()) > 1e-3
 
 
 def test_cca_is_causal():
     spec, p, u = cca_case()
-    out = hybrid.cca_mixer(spec, p, u, jnp.float32)
+    out = cca(spec, p, u, jnp.float32)
     later = u.at[:, SEQ // 2:].add(1.0)
-    moved = hybrid.cca_mixer(spec, p, later, jnp.float32)
+    moved = cca(spec, p, later, jnp.float32)
     np.testing.assert_allclose(out[:, :SEQ // 2], moved[:, :SEQ // 2], atol=1e-5)
     assert float(jnp.abs(out - moved)[:, SEQ // 2:].max()) > 1e-3
 
@@ -166,8 +165,9 @@ def test_forced_imbalance_loses_no_token(held, expert):
     p = forced_router(spec.init(jax.random.key(5), E, jnp.float32), expert)
     u = jax.random.normal(jax.random.key(6), (2, SEQ, E))
     with jax.default_matmul_precision("highest"):
-        got, e = hybrid.moe_ffn(spec, p, u, jnp.float32)
-        want = jnp.stack([ref.moe(p, x, model(held), jnp.matmul)[0] for x in u])
+        got, e = moe(spec, p, u, jnp.float32)
+        one = jax.jit(lambda p, x: ref.moe(p, x, model(held), jnp.matmul)[0])
+        want = jnp.stack([one(p, x) for x in u])
     assert bool((e == expert).all())
     np.testing.assert_allclose(got, want, atol=2e-5)
     local = held[0] <= expert < held[0] + held[1]
@@ -188,21 +188,18 @@ def test_shares_add_up_to_the_uncut_layer():
         for first in (0, half):
             share = dict(p, **{k: p[k][first:first + half]
                                for k in ("wg", "wu", "wdn")})
-            out, e = hybrid.moe_ffn(hybrid.MoE(N_EXPERTS, (first, half), R, F),
-                                    share, u, jnp.float32)
+            out, e = moe(hybrid.MoE(N_EXPERTS, (first, half), R, F), share, u,
+                         jnp.float32)
             assert 0 < int(((e >= first) & (e < first + half)).sum()) < e.size
             total = total + out
-        want = jnp.stack([ref.moe(p, x, model((0, N_EXPERTS)), jnp.matmul)[0]
-                          for x in u])
+        one = jax.jit(lambda p, x: ref.moe(p, x, model((0, N_EXPERTS)), jnp.matmul)[0])
+        want = jnp.stack([one(p, x) for x in u])
     np.testing.assert_allclose(total, want, atol=2e-5)
     assert bool((jnp.abs(want).sum(-1) > 0).all())
 
 
-def test_routing_stats_publish_counters():
-    cfg = config()
-    params = seeded_params(cfg)
-    toks = jax.random.randint(jax.random.key(7), (BATCH, SEQ), 0, V)
-    counts = np.asarray(hybrid.routing_stats(params, toks, cfg))
+def test_routing_stats_publish_counters(case):
+    cfg, counts = case["cfg"], np.asarray(case["counts"])
     assert counts.shape == (2, N_EXPERTS) and (counts.sum(axis=1) == BATCH * SEQ).all()
     METRICS.reset()
     out = hybrid.publish_routing_stats(counts, cfg)
@@ -337,7 +334,7 @@ def test_looped_objective_costs_three_head_products_a_chunk():
     cfg = hybrid.HybridConfig(
         base=base, layers=((hybrid.Attention(H, G, D, 1e6), hybrid.GatedMLP(F)),),
         norm_eps=1e-6, n_loops=4, exit_beta=0.1)
-    params = hybrid.init_params(jax.random.key(0), cfg)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg))
     toks = jnp.zeros((BATCH, SEQ), jnp.int32)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: hybrid.looped_lm_loss_per_example(p, toks, toks, cfg).mean()
@@ -378,8 +375,8 @@ def test_loss_is_the_same_row_by_row_and_as_a_whole_batch(case):
     example's tokens grouped alone; ``lax.ragged_dot`` itself has no batching
     rule for it) is what the whole batch grouped at once gives for that row."""
     cfg, params, toks, tgts = (case[k] for k in ("cfg", "params", "toks", "tgts"))
-    whole = hybrid.lm_loss_per_example(params, toks, tgts, cfg)
-    rows = jnp.stack([hybrid.lm_loss(params, toks[i:i + 1], tgts[i:i + 1], cfg)
+    whole, one = case["rows"], jax.jit(hybrid.lm_loss, static_argnums=3)
+    rows = jnp.stack([one(params, toks[i:i + 1], tgts[i:i + 1], cfg)
                       for i in range(BATCH)])
     np.testing.assert_allclose(whole, rows, rtol=2e-5)
     assert float(whole.mean()) == pytest.approx(float(case["loss"]), rel=1e-6)
@@ -439,7 +436,8 @@ def test_bf16_compute_stays_near_the_reference(case):
     """The configuration's precision (bf16 matmuls over f32 parameters): the
     loss within a few thousandths of the float32 reference's."""
     cfg = config(dtype=jnp.bfloat16)
-    loss = hybrid.lm_loss(case["params"], case["toks"], case["tgts"], cfg)
+    loss = jax.jit(hybrid.lm_loss, static_argnums=3)(
+        case["params"], case["toks"], case["tgts"], cfg)
     assert abs(float(loss) - float(case["ref_loss"])) < 0.02
 
 
@@ -461,7 +459,7 @@ def test_sublayer_names_in_the_lowered_step():
     cfg = config(n_layers=1)
     cfg = hybrid.HybridConfig(base=dataclasses.replace(cfg.base, remat=True),
                               layers=cfg.layers)
-    params = hybrid.init_params(jax.random.key(0), cfg)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg))
     x = jnp.zeros((2, SEQ), jnp.int32)
     text = jax.jit(jax.value_and_grad(
         lambda p: hybrid.lm_loss(p, x, x, cfg))).lower(params).compiler_ir(
@@ -502,9 +500,10 @@ def test_experts_are_placed_by_load():
     cfg = config()
     params = seeded_params(cfg, seed=11)
     toks = jax.random.randint(jax.random.key(12), (8, SEQ), 0, V)
-    before = np.asarray(hybrid.routing_stats(params, toks, cfg))
+    stats = jax.jit(hybrid.routing_stats, static_argnums=2)
+    before = np.asarray(stats(params, toks, cfg))
     placed = hybrid.place_experts(params, toks, cfg)
-    after = np.asarray(hybrid.routing_stats(placed, toks, cfg))
+    after = np.asarray(stats(placed, toks, cfg))
     share = after[:, :4].sum(axis=1) / after.sum(axis=1)
     worst = np.abs(before[:, :4].sum(axis=1) / before.sum(axis=1) - 0.5).max()
     assert np.abs(share - 0.5).max() < 0.1 < worst, (share, worst)

@@ -88,7 +88,7 @@ def test_resnet18_forward_and_grad():
     logits = model.predict_logits(x)
     assert logits.shape == (2, 5)
     y = jax.nn.one_hot(jnp.array([0, 3]), 5)
-    g = jax.grad(lambda p: model.loss_fn()(p, x, y))(model.params)
+    g = jax.jit(jax.grad(lambda p: model.loss_fn()(p, x, y)))(model.params)
     flat = jax.tree_util.tree_leaves(g)
     assert all(np.all(np.isfinite(np.asarray(l))) for l in flat)
     assert any(float(jnp.max(jnp.abs(l))) > 0 for l in flat)
@@ -164,10 +164,10 @@ def test_resnet_s2d_stem_matches_plain_stem():
     assert cfg.stem_space_to_depth
     params = init_params(jax.random.key(0), cfg)
     x = jax.random.normal(jax.random.key(1), (2, 32, 32, 3))
+    fwd = jax.jit(forward, static_argnums=2)
     with jax.default_matmul_precision("highest"):
-        y_s2d = forward(params, x, cfg)
-        y_plain = forward(params, x,
-                          dataclasses.replace(cfg, stem_space_to_depth=False))
+        y_s2d = fwd(params, x, cfg)
+        y_plain = fwd(params, x, dataclasses.replace(cfg, stem_space_to_depth=False))
     # stem outputs agree to ~1e-6; batch-norm rsqrt amplifies that
     # summation-order noise through the stack, hence the loose logit atol
     np.testing.assert_allclose(np.asarray(y_s2d), np.asarray(y_plain),

@@ -9,6 +9,7 @@ to the tolerances it declares against its reference.
 
 import ast
 import dataclasses
+import functools
 from pathlib import Path
 
 import jax
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning4j_tpu.models.transformer import lm_loss_local
 from deeplearning4j_tpu.ops.pallas import registry
 from deeplearning4j_tpu.ops.pallas.attention import (fused_attention,
                                                      reference_attention)
@@ -134,8 +136,8 @@ def _grad_err(fn, ref, *args, argnums=0):
             out = out[-1] if isinstance(out, tuple) else out
             return (out.astype(jnp.float32) ** 2).sum() / 2
         return l
-    ga = jax.grad(loss(fn), argnums)(*args)
-    gb = jax.grad(loss(ref), argnums)(*args)
+    ga = jax.jit(jax.grad(loss(fn), argnums))(*args)
+    gb = jax.jit(jax.grad(loss(ref), argnums))(*args)
     return max(_max_abs(a, b) for a, b in zip(
         jax.tree_util.tree_leaves(ga), jax.tree_util.tree_leaves(gb)))
 
@@ -264,8 +266,8 @@ def test_fused_attention_gradient_parity(causal):
     def loss(fn):
         return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v, causal=causal)))
 
-    g1 = jax.grad(loss(fused_attention), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss(fused_attention), argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(loss(reference_attention), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         _close(a, b, jnp.float32)
 
@@ -313,9 +315,9 @@ def test_fused_layernorm_gradient_parity_with_mask():
             return jnp.sum(jnp.sin(h)) + 0.1 * jnp.sum(y)
         return l
 
-    g1 = jax.grad(loss(fused_residual_layernorm), argnums=(0, 1, 2, 3))(
+    g1 = jax.jit(jax.grad(loss(fused_residual_layernorm), argnums=(0, 1, 2, 3)))(
         x, r, scale, bias)
-    g2 = jax.grad(loss(reference_residual_layernorm), argnums=(0, 1, 2, 3))(
+    g2 = jax.jit(jax.grad(loss(reference_residual_layernorm), argnums=(0, 1, 2, 3)))(
         x, r, scale, bias)
     for a, b in zip(g1, g2):
         _close(a, b, jnp.float32)
@@ -353,10 +355,10 @@ def test_blocked_xent_gradient_parity():
     head = jax.random.normal(ks[1], (16, 53)) * 0.3
     t = jax.random.randint(ks[2], (101,), 0, 53)
     w = jax.random.uniform(ks[3], (101,))
-    g1 = jax.grad(lambda h, hd, w: blocked_cross_entropy(
-        h, hd, t, w, block_t=32, block_v=16), argnums=(0, 1, 2))(h, head, w)
-    g2 = jax.grad(lambda h, hd, w: reference_xent_sum(h, hd, t, w),
-                  argnums=(0, 1, 2))(h, head, w)
+    g1 = jax.jit(jax.grad(lambda h, hd, w: blocked_cross_entropy(
+        h, hd, t, w, block_t=32, block_v=16), argnums=(0, 1, 2)))(h, head, w)
+    g2 = jax.jit(jax.grad(lambda h, hd, w: reference_xent_sum(h, hd, t, w),
+                          argnums=(0, 1, 2)))(h, head, w)
     for a, b in zip(g1, g2):
         _close(a, b, jnp.float32)
 
@@ -436,8 +438,9 @@ def test_quantized_tree_drops_f32_ffn_and_decode_agrees():
     assert "head_q" in qp
     cache = tf.init_decode_cache(cfg, 2)
     toks = jnp.array([3, 5], jnp.int32)
-    lg_f32, _ = tf.decode_step(params, cache, toks, 0, cfg)
-    lg_i8, _ = tf.decode_step(qp, cache, toks, 0, cfg)
+    step = jax.jit(tf.decode_step, static_argnums=(3, 4))
+    lg_f32, _ = step(params, cache, toks, 0, cfg)
+    lg_i8, _ = step(qp, cache, toks, 0, cfg)
     assert float(top1_agreement(lg_f32, lg_i8)) == 1.0
 
 
@@ -450,6 +453,9 @@ def _tiny_cfg(**kw):
                              dtype=jnp.float32, **kw)
 
 
+lm_value_and_grad = jax.jit(jax.value_and_grad(lm_loss_local), static_argnums=3)
+
+
 @pytest.mark.parametrize("variant", [
     {"attention": "fused"},
     {"fused_ln": True},
@@ -459,17 +465,14 @@ def test_transformer_kernel_variants_match_default(variant):
     """Each kernel opt-in computes the same loss and gradients
     as the default XLA path (vocab 101 is prime: the blocked variant runs
     the shape-independent streaming schedule, not a lucky divisor)."""
-    from deeplearning4j_tpu.models.transformer import (init_params,
-                                                       lm_loss_local)
+    from deeplearning4j_tpu.models.transformer import init_params
     cfg = _tiny_cfg()
     params = init_params(jax.random.key(22), cfg)
     toks = jax.random.randint(jax.random.key(23), (2, 128), 0, 101)
     tgts = jnp.roll(toks, -1, axis=1)
 
-    def run(c):
-        return jax.value_and_grad(
-            lambda p: lm_loss_local(p, toks, tgts, c))(params)
-
+    # one program a configuration: the default's compiled once for all three
+    run = functools.partial(lm_value_and_grad, params, toks, tgts)
     l0, g0 = run(cfg)
     l1, g1 = run(_tiny_cfg(**variant))
     assert abs(float(l0) - float(l1)) < 1e-5

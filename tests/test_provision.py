@@ -51,7 +51,7 @@ def test_render_script_is_wellformed(tmp_path):
     assert "\\$(curl" in text
     assert os.access(path, os.X_OK)
     # the script parses as shell
-    subprocess.run(["bash", "-n", str(path)], check=True)
+    subprocess.run(["bash", "-n", str(path)], check=True, timeout=60)
 
 
 def test_cli_scaleout_kill(tmp_path):

@@ -6,6 +6,7 @@ star implies it.)"""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deeplearning4j_tpu.models.resnet import (
     ResNet,
@@ -18,6 +19,8 @@ from deeplearning4j_tpu.models.resnet import (
 )
 from deeplearning4j_tpu.optimize import transforms as T
 
+#: compiled once a configuration (and with or without running statistics)
+fwd = jax.jit(forward, static_argnums=2)
 
 def _cfg():
     return ResNetConfig.resnet18(num_classes=5, width=8, dtype=jnp.float32)
@@ -30,29 +33,32 @@ def _data(n=8, size=32, seed=0):
     return jnp.asarray(x), jnp.asarray(y)
 
 
-def test_train_mode_with_stats_matches_stateless_path():
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.key(0), _cfg())
+
+
+def test_train_mode_with_stats_matches_stateless_path(params):
     """Threading the stats collection must not change the training math:
     logits and loss are identical to the r4 stat-less path."""
     cfg = _cfg()
-    params = init_params(jax.random.key(0), cfg)
     stats = init_batch_stats(cfg)
     x, y = _data()
-    logits0 = forward(params, x, cfg)
-    logits1, new_stats = forward(params, x, cfg, stats)
+    logits0 = fwd(params, x, cfg)
+    logits1, new_stats = fwd(params, x, cfg, stats)
     np.testing.assert_allclose(np.asarray(logits0), np.asarray(logits1),
                                atol=1e-6)
-    l0 = cross_entropy(params, x, y, cfg)
-    l1, _ = cross_entropy_with_stats(params, stats, x, y, cfg)
+    l0 = jax.jit(cross_entropy, static_argnums=3)(params, x, y, cfg)
+    l1, _ = jax.jit(cross_entropy_with_stats, static_argnums=4)(params, stats, x, y, cfg)
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
     # stats actually moved off their init toward the batch moments
     assert not np.allclose(np.asarray(new_stats["stem"]["bn"]["mean"]), 0.0)
 
 
-def test_running_stats_converge_to_batch_moments():
+def test_running_stats_converge_to_batch_moments(params):
     """Repeated train steps on one fixed batch EMA the running stats to
     that batch's moments (momentum 0.9 -> ~1 - 0.9^n of the way)."""
     cfg = _cfg()
-    params = init_params(jax.random.key(0), cfg)
     stats = init_batch_stats(cfg)
     x, _ = _data()
     fwd = jax.jit(lambda p, s, xx: forward(p, xx, cfg, s))
@@ -101,17 +107,16 @@ def test_eval_mode_is_batch_independent():
                            rtol=1e-4, atol=1e-4)
 
 
-def test_bn_fold_matches_unfolded():
+def test_bn_fold_matches_unfolded(params):
     """bn_fold applies the identical normalization as the f32 path (folded
     per-channel affine): exact at f32 compute, close at bf16; grads flow."""
     import dataclasses
 
     cfg = _cfg()                                  # f32 compute dtype
     fcfg = dataclasses.replace(cfg, bn_fold=True)
-    params = init_params(jax.random.key(0), cfg)
     x, y = _data()
-    l0 = forward(params, x, cfg)
-    l1 = forward(params, x, fcfg)
+    l0 = fwd(params, x, cfg)
+    l1 = fwd(params, x, fcfg)
     # folding reassociates the affine ((x-m)*inv*s+b vs x*(s*inv)+(b-m*inv*s));
     # f32 rounding differences compound slightly across 18 layers
     np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
@@ -134,13 +139,13 @@ def test_bn_fold_matches_unfolded():
                                rtol=0.05, atol=0.05)
 
     bfcfg = dataclasses.replace(cfg, dtype=jnp.bfloat16, bn_fold=True)
-    g = jax.grad(lambda pr: cross_entropy(pr, x, y, bfcfg))(params)
+    g = jax.jit(jax.grad(cross_entropy), static_argnums=3)(params, x, y, bfcfg)
     assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g))
 
     # running stats thread identically through the folded path
     stats = init_batch_stats(cfg)
-    _, ns0 = forward(params, x, cfg, stats)
-    _, ns1 = forward(params, x, fcfg, stats)
+    _, ns0 = fwd(params, x, cfg, stats)
+    _, ns1 = fwd(params, x, fcfg, stats)
     # deeper-layer stats inherit the upstream reassociation rounding
     for a, b in zip(jax.tree.leaves(ns0), jax.tree.leaves(ns1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -30,12 +30,11 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models import hybrid
-from deeplearning4j_tpu.models import transformer as tf
-from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.observability import scopemap
 from deeplearning4j_tpu.optimize import transforms as T
 from deeplearning4j_tpu.parallel import DataParallelTrainer
 from deeplearning4j_tpu.parallel.mesh import local_mesh
+from hybrid_common import dense_family, looped_family, tiny_base, zaya_like_family
 
 #: the sublayers ``benchmark/readers/scope_share.py`` knows, and PR 36's two
 OUTER = ("embed", "layernorm", "qkv_proj", "attention", "attn_out", "ffn",
@@ -51,36 +50,8 @@ WRAPPER = re.compile(r"^[A-Za-z_]\w*\((.*)\)$")
 SCANS_OWN = re.compile(r"(^|/)while/(body|cond)/(closed_call/add_any|[a-z_\-]+)$")
 
 
-def _tiny(**kw):
-    return TransformerConfig(**{**dict(
-        vocab_size=256, d_model=64, n_heads=4, n_layers=2, d_ff=128, max_len=256,
-        causal=True, remat=True, xent_chunk=64), **kw})
-
-
-def _dense():
-    cfg = _tiny(causal=False, remat=False)
-    return (cfg, tf.init_params,
-            lambda p, x, y, key=None: tf.lm_loss_local(p, x, y, cfg), False)
-
-
-def _zaya_like():
-    cfg = hybrid.HybridConfig(base=_tiny(n_kv_heads=2), layers=((
-        hybrid.CCA(4, 2, 16), hybrid.MoE(8, (0, 4), 32, 48)),) * 2)
-    return (cfg, hybrid.init_params,
-            lambda p, x, y, key=None: hybrid.lm_loss_per_example(p, x, y, cfg), True)
-
-
-def _looped():
-    cfg = hybrid.HybridConfig(
-        base=_tiny(tie_embeddings=False), norm_eps=1e-6, n_loops=3, exit_beta=0.1,
-        layers=((hybrid.Attention(4, 4, 16), hybrid.GatedMLP(96)),) * 2)
-    return (cfg, hybrid.init_params,
-            lambda p, x, y, key=None: hybrid.looped_lm_loss_per_example(
-                p, x, y, cfg), True)
-
-
 def _sparse(rows=32):
-    cfg = hybrid.HybridConfig(base=_tiny(tie_embeddings=False), layers=((
+    cfg = hybrid.HybridConfig(base=tiny_base(tie_embeddings=False), layers=((
         hybrid.SparseAttention(n_heads=4, n_kv_heads=2, head_dim=16, index_heads=2,
                                index_dim=8, top_k=48, q_chunk=rows, kv_chunk=rows,
                                rows=rows),
@@ -91,10 +62,10 @@ def _sparse(rows=32):
 
 #: id -> (family, zero_stage, the names its step must carry, tokens a row)
 CASES = {
-    "dense": (_dense, 0, ("residual", "loss_reduce"), 128),
-    "dense-zero1": (_dense, 1, ("residual", "loss_reduce", "zero.layout"), 128),
-    "zaya-like": (_zaya_like, 0, ("residual", "loss_reduce", "moe.combine"), 128),
-    "looped": (_looped, 0, ("residual", "loss_reduce"), 128),
+    "dense": (dense_family, 0, ("residual", "loss_reduce"), 128),
+    "dense-zero1": (dense_family, 1, ("residual", "loss_reduce", "zero.layout"), 128),
+    "zaya-like": (zaya_like_family, 0, ("residual", "loss_reduce", "moe.combine"), 128),
+    "looped": (looped_family, 0, ("residual", "loss_reduce"), 128),
     "sparse": (_sparse, 0, ("residual", "loss_reduce", "dsa.attend",
                             "moe.combine"), 128),
     "sparse-zero1": (_sparse, 1, ("residual", "loss_reduce", "dsa.attend",
@@ -118,7 +89,8 @@ def step_and_arguments(case, monkeypatch):
     trainer = DataParallelTrainer(
         loss, T.adamw(1e-3, weight_decay=0.01), mesh=local_mesh(2 if zero else 1),
         zero_stage=zero, per_example_loss=per_example)
-    state = trainer.init_state(init(jax.random.key(0), cfg))
+    state = trainer.init_state(                # values unread: one program
+        jax.jit(init, static_argnums=1)(jax.random.key(0), cfg))
     toks = jax.device_put(jnp.zeros((2, t), jnp.int32), trainer._batch_sh)
     i32 = jax.device_put(np.int32(0), trainer._rep_sh)
     return trainer._step_for(2), (state.params, state.tstate, toks, toks,
@@ -287,7 +259,7 @@ def test_fit_registers_shapes_and_never_asks_for_the_map(monkeypatch):
     monkeypatch.setattr(scopemap, "scope_map", lambda *a: asked.append(a))
     monkeypatch.setattr(scopemap, "parse", lambda *a: asked.append(a))
     scopemap.clear()
-    cfg, init, loss, _ = _dense()
+    cfg, init, loss, _ = dense_family()
     trainer = DataParallelTrainer(loss, T.adamw(1e-3), mesh=local_mesh(1))
     state = trainer.init_state(init(jax.random.key(0), cfg))
     toks = np.zeros((2, 128), np.int32)

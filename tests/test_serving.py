@@ -13,11 +13,10 @@ import threading
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models.transformer import TransformerConfig, TransformerLM
+from deeplearning4j_tpu.models.transformer import TransformerLM
 from deeplearning4j_tpu.observability import METRICS
 from deeplearning4j_tpu.parallel.checkpoint import CheckpointManager
 from deeplearning4j_tpu.serving import (BatchScorer, InferenceEngine,
@@ -25,18 +24,7 @@ from deeplearning4j_tpu.serving import (BatchScorer, InferenceEngine,
                                         ServingClient, ServingConfig,
                                         ServingError)
 from deeplearning4j_tpu.serving.batcher import DeadlineExceeded, GenerateRequest
-
-
-def tiny_cfg(**kw):
-    kw.setdefault("vocab_size", 64)
-    kw.setdefault("d_model", 32)
-    kw.setdefault("n_heads", 4)
-    kw.setdefault("n_layers", 2)
-    kw.setdefault("d_ff", 64)
-    kw.setdefault("max_len", 32)
-    kw.setdefault("dtype", jnp.float32)  # exact parity comparisons
-    kw.setdefault("remat", False)
-    return TransformerConfig(**kw)
+from test_transformer import PERIOD, cycle, tiny_cfg
 
 
 @pytest.fixture(scope="module")
@@ -54,14 +42,9 @@ def cycle_lm():
     token content, not just shapes."""
     from deeplearning4j_tpu.optimize import transforms as T
 
-    period = [3, 1, 4, 1, 5, 9, 2, 6]
+    period = PERIOD
     cfg = tiny_cfg(vocab_size=16, causal=True)
-    stream = np.array(period * 32, np.int32)
-    span = cfg.max_len + 1
-    n = len(stream) // span
-    blocks = stream[:n * span].reshape(n, span)
-    tokens = jnp.asarray(blocks[:, :-1])
-    targets = jnp.asarray(blocks[:, 1:])
+    tokens, targets = cycle(cfg)
     model = TransformerLM(cfg)
     tx = T.adamw(0.01)
     params = model.init(jax.random.key(0))

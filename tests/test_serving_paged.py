@@ -19,8 +19,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models import zoo
-from deeplearning4j_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM, decode_step,
+from deeplearning4j_tpu.models.transformer import (TransformerLM, decode_step,
                                                    decode_step_paged,
                                                    decode_window,
                                                    init_decode_cache,
@@ -30,18 +29,7 @@ from deeplearning4j_tpu.parallel.checkpoint import CheckpointManager
 from deeplearning4j_tpu.resilience import FaultSpec, inject_faults
 from deeplearning4j_tpu.serving import (InferenceEngine, PagePool,
                                         PagePoolExhausted, ServingConfig)
-
-
-def tiny_cfg(**kw):
-    kw.setdefault("vocab_size", 64)
-    kw.setdefault("d_model", 32)
-    kw.setdefault("n_heads", 4)
-    kw.setdefault("n_layers", 2)
-    kw.setdefault("d_ff", 64)
-    kw.setdefault("max_len", 32)
-    kw.setdefault("dtype", jnp.float32)  # exact parity comparisons
-    kw.setdefault("remat", False)
-    return TransformerConfig(**kw)
+from test_transformer import tiny_cfg
 
 
 @pytest.fixture(scope="module")

@@ -539,7 +539,7 @@ def test_crashing_rule_is_contained(monkeypatch):
 def _git(cwd, *args):
     subprocess.run(
         ["git", "-c", "user.email=t@t", "-c", "user.name=t", *args],
-        cwd=cwd, check=True, capture_output=True)
+        cwd=cwd, check=True, capture_output=True, timeout=60)
 
 
 @pytest.fixture

@@ -7,14 +7,14 @@ to a held accumulator); then ``models/hybrid``'s sparse mixer with the
 kernel forced by name against its own XLA path (output, index loss, the
 gradients of q, k, v and of the indexer's parameters), under ties in the
 index scores and where a first chunk selects every causal key, with the
-index scores in XLA and in their kernels; who chooses
-(``attention_candidate``); and the other families' training steps, which
-lower to the parent's text byte for byte.
+index scores in XLA and in their kernels.  A mixer's program on each path is
+compiled once a spec: the cases that share a spec share it.  The steps that
+run the mixer, and who chooses its path, are
+``test_sparse_attention_kernel_steps.py``.
 """
 
 import dataclasses
 import functools
-import re
 
 import jax
 import jax.numpy as jnp
@@ -22,10 +22,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models import hybrid
-from deeplearning4j_tpu.models import transformer as tf
-from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.observability import METRICS
-from deeplearning4j_tpu.ops.pallas import attention as pallas_attention
 from deeplearning4j_tpu.ops.pallas import sparse_attention as kernel
 
 C, L, H, G, D = 32, 384, 4, 2, 16          # 384 keys: three blocks of 128
@@ -208,67 +205,44 @@ def mixer(**kw):
         H, G, D, 1e7, True, J, DI, top_k=24, q_chunk=8, kv_chunk=8, rows=16), **kw)
 
 
-def mixer_case(spec, seed=3, tied=False):
+def mixer_case(spec, seed=3, tied=False, shape=(BATCH, SEQ)):
     p = spec.init(jax.random.key(seed), E, jnp.float32)
     if tied:        # no head weighs anything: every index score is 0.0
         p = dict(p, index=dict(p["index"], ww=jnp.zeros_like(p["index"]["ww"])))
-    return p, jax.random.normal(jax.random.key(seed + 1), (BATCH, SEQ, E))
+    return p, jax.random.normal(jax.random.key(seed + 1), (*shape, E))
 
 
-def both_paths(monkeypatch, fn, *args, index="xla"):
+@pytest.fixture(scope="module")
+def programs():
+    """``(compiled program, the counters its trace left)`` by (spec, path)."""
+    return {}
+
+
+def both_paths(programs, spec, fn, *args, index="xla"):
     """``fn(*args)`` with the mixer on its XLA path and on the kernel, whose
-    index scores take the ``index`` path."""
+    index scores take the ``index`` path: compiled once a ``spec``."""
     out = []
     for asked in ("ring", "selected"):
-        monkeypatch.setattr(hybrid, "sparse_attend", functools.partial(
-            _sparse_attend, asked=asked))
-        jax.clear_caches()               # a checkpointed block's trace is cached
-        METRICS.reset()
-        with jax.default_matmul_precision("highest"):
-            out.append(fn(*args))
-        c = METRICS.snapshot()["counters"]
+        if (spec, asked) not in programs:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(hybrid, "sparse_attend", functools.partial(
+                    _sparse_attend, asked=asked))
+                jax.clear_caches()       # a checkpointed block's trace is cached
+                METRICS.reset()
+                with jax.default_matmul_precision("highest"):
+                    program = jax.jit(fn).lower(*args).compile()
+            jax.clear_caches()
+            programs[spec, asked] = program, METRICS.snapshot()["counters"]
+        program, c = programs[spec, asked]
+        out.append(program(*args))
         on = asked == "selected"
         assert c.get("attention.path.kernel" if on else "attention.path.xla", 0) >= 1
         want = f"dsa.index_path.{index if on else 'xla'}"
         assert c.get(want, 0) == c["dsa.layers"] >= 1, (asked, c)
-    monkeypatch.undo()
-    jax.clear_caches()
     return out
 
 
 _sparse_attend = hybrid.sparse_attend
-
-
-@pytest.mark.parametrize("case", ["selection-bites", "tied-scores", "top-k-above-a-chunk",
-                                  "one-chunk", "one-kv-head"])
-def test_mixer_on_the_kernel_is_the_mixer_on_the_xla_path(monkeypatch, case):
-    """Output, index loss, and the gradients of every parameter of the mixer
-    (the indexer's too: they come from the index loss alone, through the
-    heads' mean the kernel writes) and of its input.  ``top_k = 24`` over
-    chunks of 16: the first chunk selects every causal key, the second some
-    rows all and some rows 24, the rest 24 of up to 64."""
-    spec = {"selection-bites": mixer(), "tied-scores": mixer(),
-            "top-k-above-a-chunk": mixer(top_k=40),
-            "one-chunk": mixer(rows=24),              # 24 does not divide 64
-            "one-kv-head": mixer(n_kv_heads=1)}[case]
-    p, u = mixer_case(spec, tied=case == "tied-scores")
-
-    def run(p, u):
-        def f(p, u):
-            out, loss = hybrid.sparse_attention_mixer(spec, p, u, jnp.float32)
-            return jnp.sum(jnp.sin(out)) + 2.0 * jnp.sum(loss), (out, loss)
-        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(p, u)
-
-    ((_, (want, want_loss)), want_g), ((_, (got, got_loss)), got_g) = both_paths(
-        monkeypatch, run, p, u)
-    np.testing.assert_allclose(got, want, atol=2e-5)
-    np.testing.assert_allclose(got_loss, want_loss, rtol=2e-5)
-    assert float(jnp.abs(want_g[0]["index"]["wq" if case != "tied-scores" else "ww"]
-                         ).max()) > 1e-6        # the indexer has a gradient
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
-                            jax.tree_util.tree_leaves(want_g)):
-        np.testing.assert_allclose(
-            a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())), err_msg=str(path))
 
 
 def index_mixer(**kw):
@@ -279,15 +253,32 @@ def index_mixer(**kw):
         **kw)
 
 
-@pytest.mark.parametrize("case", ["two-chunks", "one-chunk-of-256", "tied-scores"])
-def test_mixer_on_the_index_kernels_is_the_mixer_on_the_xla_path(monkeypatch, case):
-    """As above, with the index scores (and, backward, the indexer's
-    gradients) in the index kernels: 256 positions, ``top_k = 48``."""
-    spec = index_mixer(rows=256) if case == "one-chunk-of-256" else index_mixer()
-    p = spec.init(jax.random.key(5), E, jnp.float32)
-    if case == "tied-scores":
-        p = dict(p, index=dict(p["index"], ww=jnp.zeros_like(p["index"]["ww"])))
-    u = jax.random.normal(jax.random.key(6), (1, 256, E))
+#: case -> (the mixer, the seed of its weights, (examples, positions), the
+#: path its index scores take on the kernel's side)
+MIXER_CASES = {
+    "selection-bites": (mixer(), 3, (BATCH, SEQ), "xla"),
+    "tied-scores": (mixer(), 3, (BATCH, SEQ), "xla"),
+    "top-k-above-a-chunk": (mixer(top_k=40), 3, (BATCH, SEQ), "xla"),
+    "one-chunk": (mixer(rows=24), 3, (BATCH, SEQ), "xla"),   # 24 does not divide 64
+    "one-kv-head": (mixer(n_kv_heads=1), 3, (BATCH, SEQ), "xla"),
+    "index-kernels-two-chunks": (index_mixer(), 5, (1, 256), "kernel"),
+    "index-kernels-one-chunk-of-256": (index_mixer(rows=256), 5, (1, 256), "kernel"),
+    "index-kernels-tied-scores": (index_mixer(), 5, (1, 256), "kernel"),
+}
+
+
+@pytest.mark.parametrize("case", list(MIXER_CASES))
+def test_mixer_on_the_kernel_is_the_mixer_on_the_xla_path(programs, case):
+    """Output, index loss, and the gradients of every parameter of the mixer
+    (the indexer's too: they come from the index loss alone, through the
+    heads' mean the kernel writes) and of its input.  ``top_k = 24`` over
+    chunks of 16: the first chunk selects every causal key, the second some
+    rows all and some rows 24, the rest 24 of up to 64.  The ``index-kernels``
+    cases take the index scores (and, backward, the indexer's gradients) into
+    the index kernels too: 256 positions, ``top_k = 48``."""
+    spec, seed, shape, index = MIXER_CASES[case]
+    tied = case.endswith("tied-scores")
+    p, u = mixer_case(spec, seed, tied, shape)
 
     def run(p, u):
         def f(p, u):
@@ -296,203 +287,12 @@ def test_mixer_on_the_index_kernels_is_the_mixer_on_the_xla_path(monkeypatch, ca
         return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(p, u)
 
     ((_, (want, want_loss)), want_g), ((_, (got, got_loss)), got_g) = both_paths(
-        monkeypatch, run, p, u, index="kernel")
+        programs, spec, run, p, u, index=index)
     np.testing.assert_allclose(got, want, atol=2e-5)
     np.testing.assert_allclose(got_loss, want_loss, rtol=2e-5)
-    assert float(jnp.abs(want_g[0]["index"]["wq" if case != "tied-scores" else "ww"]
-                         ).max()) > 1e-6
+    assert float(jnp.abs(want_g[0]["index"]["ww" if tied else "wq"]
+                         ).max()) > 1e-6        # the indexer has a gradient
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
                             jax.tree_util.tree_leaves(want_g)):
         np.testing.assert_allclose(
             a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())), err_msg=str(path))
-
-
-def test_a_checkpointed_model_on_the_kernel_is_the_model_on_the_xla_path(monkeypatch):
-    """Two layers under ``remat``, bf16 compute: the objective's two parts and
-    every gradient leaf; the kernel's layers count ``attention.path.kernel``."""
-    base = TransformerConfig(
-        vocab_size=256, d_model=E, n_heads=H, n_kv_heads=G, n_layers=2, d_ff=32,
-        max_len=SEQ, causal=True, tie_embeddings=False, dtype=jnp.bfloat16,
-        param_dtype=jnp.float32, remat=True, xent_chunk=32)
-    cfg = hybrid.HybridConfig(base=base, norm_eps=1e-6, layers=((
-        mixer(), hybrid.MoE(8, (0, 4), 0, 32, top_k=2, renormalize=True)),) * 2)
-    params = hybrid.init_params(jax.random.key(0), cfg)
-    toks = jax.random.randint(jax.random.key(7), (BATCH, SEQ), 0, 256)
-
-    def run(params):
-        def f(p):
-            lm, own = hybrid.loss_parts(p, toks, jnp.roll(toks, -1, axis=1), cfg)
-            return (lm + own).mean(), (lm, own)
-        return jax.jit(jax.value_and_grad(f, has_aux=True))(params)
-
-    ((_, (lm, own)), want), ((_, (lm_k, own_k)), got) = both_paths(
-        monkeypatch, run, params)
-    np.testing.assert_allclose(lm_k, lm, rtol=2e-3)
-    np.testing.assert_allclose(own_k, own, rtol=2e-2)
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            jax.tree_util.tree_leaves(want)):
-        # bf16 near-ties in the index scores may select another key: a few
-        # entries move, the leaf as a whole does not
-        err = float(jnp.linalg.norm(a - b) / max(float(jnp.linalg.norm(b)), 1e-12))
-        assert err < 0.05, (path, err)
-
-
-def test_a_checkpointed_block_runs_the_forward_kernel_once(monkeypatch):
-    """``out`` and ``lse`` are kept by name: the compiled gradient of a
-    checkpointed layer holds the forward pass once a span of chunks, the
-    backward once, and the heads' mean twice (forward, and again where the
-    index loss is differentiated)."""
-    monkeypatch.setattr(hybrid, "sparse_attend", functools.partial(
-        _sparse_attend, asked="selected"))
-    jax.clear_caches()
-    base = TransformerConfig(
-        vocab_size=256, d_model=E, n_heads=H, n_kv_heads=G, n_layers=1, d_ff=32,
-        max_len=SEQ, causal=True, tie_embeddings=False, remat=True, xent_chunk=32)
-    cfg = hybrid.HybridConfig(base=base, norm_eps=1e-6, layers=((
-        mixer(), hybrid.GatedMLP(32)),))
-    params = hybrid.init_params(jax.random.key(0), cfg)
-    toks = jnp.zeros((1, SEQ), jnp.int32)
-    text = jax.jit(jax.value_and_grad(lambda p: hybrid.lm_loss(p, toks, toks, cfg))
-                   ).lower(params).as_text()
-    jax.clear_caches()
-    spans = len(hybrid._key_spans(mixer(), SEQ)[1])
-    calls = {name: len(re.findall(rf"call @{name}(_\d+)?\(", text)) for name in (
-        "_sparse_fwd", "_sparse_bwd", "_sparse_headsum")}
-    assert calls == {"_sparse_fwd": spans, "_sparse_bwd": spans,
-                     "_sparse_headsum": 2 * spans}, calls
-
-
-def test_a_checkpointed_block_scores_the_index_twice(monkeypatch):
-    """On the index kernels the compiled gradient of a checkpointed layer
-    holds the index forward twice a span of chunks (the forward pass, and
-    the backward's selection made again) and the index backward once: the
-    block's recomputed forward makes no index score."""
-    monkeypatch.setattr(hybrid, "sparse_attend", functools.partial(
-        _sparse_attend, asked="selected"))
-    jax.clear_caches()
-    base = TransformerConfig(
-        vocab_size=256, d_model=E, n_heads=H, n_kv_heads=G, n_layers=1, d_ff=32,
-        max_len=256, causal=True, tie_embeddings=False, remat=True, xent_chunk=32)
-    cfg = hybrid.HybridConfig(base=base, norm_eps=1e-6, layers=((
-        index_mixer(), hybrid.GatedMLP(32)),))
-    params = hybrid.init_params(jax.random.key(0), cfg)
-    toks = jnp.zeros((1, 256), jnp.int32)
-    text = jax.jit(jax.value_and_grad(lambda p: hybrid.lm_loss(p, toks, toks, cfg))
-                   ).lower(params).as_text()
-    jax.clear_caches()
-    spans = len(hybrid._key_spans(index_mixer(), 256)[1])
-    calls = {name: len(re.findall(rf"call @{name}(_\d+)?\(", text)) for name in (
-        "_index_fwd", "_index_bwd", "_sparse_fwd")}
-    assert calls == {"_index_fwd": 2 * spans, "_index_bwd": spans,
-                     "_sparse_fwd": spans}, calls
-
-
-# ------------------------------------------------------------------- who chooses
-
-@pytest.mark.parametrize("backend,asked,shape,want", [
-    ("tpu", "auto", (16384, 32, 128, (4, 256)), "selected"),
-    ("tpu", "auto", (4096, 32, 128, (4, 256)), "selected"),
-    ("cpu", "auto", (16384, 32, 128, (4, 256)), None),
-    ("tpu", "auto", (16384, 32, 64, (4, 256)), None),       # head width
-    ("tpu", "auto", (16384, 30, 128, (4, 256)), None),      # broken groups
-    ("tpu", "auto", (16384, 32, 128, (4, 192)), None),      # chunks of 1.5 blocks
-    ("tpu", "auto", (16640, 32, 128, (4, 16640)), None),    # no whole chunks
-    ("tpu", "auto", (1920, 32, 128, (4, 1920)), None),      # one chunk, too tall
-    ("tpu", "auto", (32768, 32, 128, (4, 256)), None),      # a second chip's
-    ("tpu", "ring", (16384, 32, 128, (4, 256)), None),
-    ("cpu", "selected", (64, 4, 16, (2, 16)), "selected"),  # a parity test's
-], ids=["cell", "4096", "cpu", "width-64", "30-heads", "rows-192", "ragged", "1920",
-        "32768", "forced-ring", "forced-kernel"])
-def test_a_mixer_with_a_selection_gets_the_kernel_where_it_compiles(
-        monkeypatch, backend, asked, shape, want):
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    METRICS.reset()
-    t, h, d, selection = shape
-    assert pallas_attention.attention_candidate(
-        t, h, d, asked=asked, selection=selection) == want
-    c = METRICS.snapshot()["counters"]
-    assert (c.get("attention.path.kernel", 0), c.get("attention.path.xla", 0)) == (
-        (1, 0) if want else (0, 1))
-
-
-def test_without_a_selection_the_answers_are_what_they_were(monkeypatch):
-    for backend in ("tpu", "cpu"):
-        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
-        for t, h, d in ((512, 12, 64), (4096, 8, 128), (8192, 8, 128), (448, 12, 64),
-                        (16384, 32, 128)):
-            for asked in ("auto", "ring", "fused"):
-                for n_sp in (1, 2):
-                    assert pallas_attention.attention_candidate(
-                        t, h, d, n_sp=n_sp, asked=asked) == parents_attention_candidate(
-                            t, h, d, n_sp=n_sp, asked=asked)
-
-
-# ------------------------------------------- the other families' steps are the parent's
-
-def parents_attention_candidate(t, h, d, *, n_sp=1, asked="auto"):
-    """``attention_candidate`` as the parent commit (e64259f) had it."""
-    if asked == "ring" or n_sp != 1:
-        name = None
-    elif asked == "auto":
-        on = jax.default_backend() == "tpu" and pallas_attention.kernel_takes(t, h, d)
-        name = "fused" if on else None
-    else:
-        name = asked if t % 128 == 0 else None
-    METRICS.increment(
-        "attention.path.kernel" if name else "attention.path.xla")
-    return name
-
-
-def _tiny(**kw):
-    return TransformerConfig(**{**dict(
-        vocab_size=256, d_model=64, n_heads=4, n_layers=2, d_ff=128, max_len=128,
-        causal=True, remat=True, xent_chunk=64), **kw})
-
-
-def _dense():
-    cfg = _tiny(causal=False, remat=False)
-    return cfg, tf.init_params, lambda p, x, y: tf.lm_loss_local(p, x, y, cfg)
-
-
-def _zaya_like():
-    cfg = hybrid.HybridConfig(base=_tiny(n_kv_heads=2), layers=((
-        hybrid.CCA(4, 2, 16), hybrid.MoE(8, (0, 4), 32, 48)),) * 2)
-    return cfg, hybrid.init_params, lambda p, x, y: hybrid.lm_loss_per_example(
-        p, x, y, cfg).mean()
-
-
-def _looped():
-    cfg = hybrid.HybridConfig(
-        base=_tiny(tie_embeddings=False), norm_eps=1e-6, n_loops=3, exit_beta=0.1,
-        layers=((hybrid.Attention(4, 4, 16), hybrid.GatedMLP(96)),) * 2)
-    return cfg, hybrid.init_params, lambda p, x, y: hybrid.looped_lm_loss_per_example(
-        p, x, y, cfg).mean()
-
-
-@pytest.mark.parametrize("family", [_dense, _zaya_like, _looped],
-                         ids=["dense", "zaya-like", "looped"])
-def test_other_families_training_steps_lower_to_the_parents_text(monkeypatch, family):
-    """What this PR changed on the code these families run: the one predicate
-    (a new keyword, a new branch) and the names a checkpointed block keeps.
-    With the parent's of both put back, an SGD step's lowered text is the
-    same, byte for byte: nothing else they trace was touched."""
-    cfg, init, loss = family()
-    params = init(jax.random.key(0), cfg)
-    toks = jnp.zeros((2, 128), jnp.int32)
-
-    def step(p, x, y):
-        value, grads = jax.value_and_grad(loss)(p, x, y)
-        return value, jax.tree.map(lambda a, g: a - 0.1 * g.astype(a.dtype), p, grads)
-
-    def lowered():
-        jax.clear_caches()
-        return jax.jit(step).lower(params, toks, toks).as_text()
-
-    mine = lowered()
-    monkeypatch.setattr(pallas_attention, "attention_candidate",
-                        parents_attention_candidate)
-    monkeypatch.setattr(hybrid, "KEPT", ("dsa.out", "dsa.loss"))
-    parents = lowered()
-    monkeypatch.undo()
-    jax.clear_caches()
-    assert len(mine) > 10_000 and mine == parents
